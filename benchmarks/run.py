@@ -784,7 +784,6 @@ def bench_compile_warmup(smoke: bool = False, impl: str = "ref") -> None:
     ``--calibrate`` least-squares-fits CostWeights from them."""
     import shutil
     import subprocess
-    import tempfile
 
     from repro.analysis.signatures import step_signatures
     from repro.core.plan_cost import CompileCacheSim
@@ -865,10 +864,18 @@ def bench_compile_warmup(smoke: bool = False, impl: str = "ref") -> None:
          f"(cold baseline: "
          f"{eng_c.compile_wait_s / max(cold_wall, 1e-9):.1%})")
 
-    # ---- restart: persistent compile cache across fresh processes
-    cache_dir = tempfile.mkdtemp(prefix="jax-compile-cache-")
+    # ---- restart: persistent compile cache across fresh processes.  The
+    # probes count cache files, not times, so they run on the CPU: this
+    # process may already hold the chip.  Their cache sits at a fixed
+    # path in the checkout (a cache hit needs the same path), emptied
+    # first so probe 1 starts cold.
+    cache_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache_probe")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
     env.pop("XLA_FLAGS", None)
     probes = []
@@ -909,6 +916,9 @@ def bench_comms_table() -> None:
     os.close(fd)
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the audit counts bytes, not times, and this process may already
+    # hold the chip: run it on the CPU
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
     env.pop("XLA_FLAGS", None)     # the audit sets its own fake devices
     t0 = time.perf_counter()

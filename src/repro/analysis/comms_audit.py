@@ -21,8 +21,8 @@ dtype wire bytes, and check each entrypoint's declared
 Pass 5 (sharding-propagation lint): every ≥2-D param must match a
 ``sharding._RULES`` entry and must not silently lower fully replicated
 when a dim divides the model axis; ``shard_activation`` annotations must
-survive into the lowered StableHLO (``@Sharding`` custom calls with the
-expected tile factors); every requested non-replicated param sharding
+survive into the lowered StableHLO (``sdy.sharding_constraint`` ops with
+the expected tile factors); every requested non-replicated param sharding
 must appear in the lowering.  Coverage is closed: every registered
 entrypoint needs a ``CommContract`` or a ``COMM_ALLOWED`` reason.
 
@@ -251,35 +251,53 @@ def rule_lint(cfg, msize: int = 16, rules=None) -> list[str]:
 # Pass 5b — annotation survival in the lowered StableHLO
 # ---------------------------------------------------------------------------
 
-_DEVICES_RE = re.compile(r"devices=\[([0-9,]+)\]")
+# Shardy spells a sharding ``<@mesh, [{"data"}, {}, {"model"}]>``: one
+# brace group of axis names per tensor dim
+_SDY_SPEC_RE = re.compile(r"<@[\w]+, \[((?:\{[^}]*\}(?:, )?)*)\]")
+
+
+def _sdy_tiles(spec: str, desc: MeshDescriptor) -> list[int]:
+    """Per-dim tile counts of one Shardy dim list."""
+    tiles = []
+    for dim in re.findall(r"\{([^}]*)\}", spec):
+        n = 1
+        for axis in re.findall(r'"(\w+)"', dim):
+            n *= desc.axis_size(axis)
+        tiles.append(n)
+    return tiles
 
 
 def annotation_findings(stablehlo: str, desc: MeshDescriptor,
                         seq_parallel: bool,
                         n_sharded_params: int) -> list[str]:
     msgs = []
-    ann = [ln for ln in stablehlo.splitlines() if "@Sharding" in ln]
     tiled_dims = []
-    for ln in ann:
-        m = _DEVICES_RE.search(ln)
-        if m:
-            tiled_dims.append([int(x) for x in m.group(1).split(",")])
+    n_got = 0
+    for ln in stablehlo.splitlines():
+        constraint = "sdy.sharding_constraint" in ln
+        for spec in _SDY_SPEC_RE.findall(ln):
+            tiles = _sdy_tiles(spec, desc)
+            if not any(t > 1 for t in tiles):
+                continue
+            if constraint:
+                tiled_dims.append(tiles)
+            else:
+                n_got += 1
     if not tiled_dims:
         msgs.append(
-            "no tiled @Sharding annotation survived lowering — "
+            "no tiled sharding constraint survived lowering — "
             "shard_activation/shard_logits silently no-opped (divisibility "
             "fallback?) and the whole activation path runs replicated")
     if seq_parallel:
         msize = desc.axis_size(desc.model_axis)
         if not any(len(d) >= 3 and d[1] == msize for d in tiled_dims):
             msgs.append(
-                f"seq_parallel: no rank≥3 @Sharding annotation shards "
+                f"seq_parallel: no rank≥3 sharding constraint shards "
                 f"dim 1 (sequence) {msize}-way over the model axis — the "
                 f"S-sharded boundary activations fell back to replicated")
-    n_got = stablehlo.count('mhlo.sharding = "{devices=')
     if n_got < n_sharded_params:
         msgs.append(
-            f"only {n_got} non-replicated mhlo.sharding annotations in "
+            f"only {n_got} non-replicated sdy.sharding annotations in "
             f"the lowering but {n_sharded_params} params requested "
             f"non-replicated NamedShardings — propagation dropped some")
     return msgs

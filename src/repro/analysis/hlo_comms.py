@@ -44,6 +44,7 @@ _COLL_RE = re.compile(
 _TYPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _SOURCE_RE = re.compile(r'source_file="([^"]*)"\s+source_line=(\d+)')
+_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
 _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
@@ -97,6 +98,38 @@ def _parse_pairs(line: str) -> Optional[list[tuple[int, int]]]:
             re.findall(r"\{(\d+),(\d+)\}", m.group(1))]
 
 
+def _frame_sources(hlo: str) -> dict[int, tuple[str, int]]:
+    """stack_frame_id → (file, line) of the frame itself, from the
+    module's ``FileNames`` / ``FileLocations`` / ``StackFrames`` tables
+    (newer XLA keeps source locations there instead of inline
+    ``source_file``/``source_line`` metadata)."""
+    tables: dict[str, dict[int, str]] = {}
+    section = None
+    for line in hlo.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            section = tables.setdefault(line, {})
+            continue
+        m = re.match(r"(\d+) (.*)", line) if section is not None else None
+        if m is None:
+            section = None
+            continue
+        section[int(m.group(1))] = m.group(2)
+    files = {i: v.strip('"') for i, v in tables.get("FileNames", {}).items()}
+    locs = {}
+    for i, v in tables.get("FileLocations", {}).items():
+        f = re.search(r"file_name_id=(\d+)", v)
+        ln = re.search(r"\bline=(\d+)", v)
+        if f and ln:
+            locs[i] = (files.get(int(f.group(1)), ""), int(ln.group(1)))
+    out = {}
+    for i, v in tables.get("StackFrames", {}).items():
+        loc = re.search(r"file_location_id=(\d+)", v)
+        if loc and int(loc.group(1)) in locs:
+            out[i] = locs[int(loc.group(1))]
+    return out
+
+
 def parse_collectives(hlo: str) -> list[dict]:
     """Every collective op in a post-SPMD HLO dump, with result bytes,
     wire bytes (the module-docstring model), replica groups, source
@@ -110,6 +143,7 @@ def parse_collectives(hlo: str) -> list[dict]:
     computation; the nesting depth (≥2 = inside the per-layer scan's inner
     chunk scan) is recorded for the trip-count multiplier.
     """
+    frames = _frame_sources(hlo)
     comp = "entry"
     comp_of_line: list[tuple[str, str]] = []
     edges: dict[str, set] = {}
@@ -176,14 +210,18 @@ def parse_collectives(hlo: str) -> list[dict]:
         d_cg = depth.get(comp, 0)
         d_final = max(d_meta, d_cg)
         ms = _SOURCE_RE.search(line)
+        src = (ms.group(1), int(ms.group(2))) if ms else ("", -1)
+        mf = None if ms else _FRAME_RE.search(line)
+        if mf:
+            src = frames.get(int(mf.group(1)), src)
         out.append({"op": op, "dtype": dt,
                     "bytes": n_bytes,
                     "elems": n_elems,
                     "wire_bytes": wire,
                     "comp": comp,
                     "op_name": op_name,
-                    "source_file": ms.group(1) if ms else "",
-                    "source_line": int(ms.group(2)) if ms else -1,
+                    "source_file": src[0],
+                    "source_line": src[1],
                     "replica_groups": groups,
                     "source_target_pairs": pairs,
                     "loop_depth": d_final,

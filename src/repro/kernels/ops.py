@@ -16,10 +16,11 @@ position-based sliding-window term to the visibility predicate.  The
 backward emits dk/dv for the FULL KV length, so the ancestor cotangents
 (d_extra_k/d_extra_v) flow out through the caller's concatenation — XLA's
 concat transpose slices them back apart for the fp32 child→parent routing
-in core/gateway.py.  Awkward KV lengths (real ancestor depths are not
-MXU-aligned) are back-padded here with invisible keys (kv_last = −1) to
-the TPU sublane multiple; the padding lives outside the custom_vjp, so
-its cotangent slice-off is free and automatic.
+in core/gateway.py.  Awkward lengths (real ancestor depths are not
+MXU-aligned) are back-padded here to a multiple of the block: keys with
+invisible ones (kv_last = −1), queries with rows that are sliced off.
+The padding lives outside the custom_vjp, so its cotangent slice-off is
+free and automatic.
 """
 from __future__ import annotations
 
@@ -28,7 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro import sharding as sh
 from repro.kernels.tree_attention import tree_attention as _pallas_fwd
 from repro.kernels.tree_attention_bwd import tree_attention_bwd as _pallas_bwd
 
@@ -37,56 +40,93 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _fit_block(S: int, want: int) -> int:
-    """Largest block ≤ ``want`` dividing S (kernels require S % block == 0);
-    halves until it fits.  Refuses pathological fits: a block below the
-    TPU sublane multiple of 8 (unless the whole row is one block) would
-    silently compile a thousands-of-programs grid — pad S instead."""
-    want = min(want, S)
-    while want > 1 and S % want:
-        want //= 2
-    if want % 8 and want != S:
-        raise ValueError(
-            f"no usable block for S={S} (fitted {want}); pad the sequence "
-            f"to a multiple of 8 for the pallas impl")
-    return want
+def platform_impl() -> str:
+    """The attention implementation this platform trains with: the fused
+    Pallas kernels on a TPU, the dense ``ref`` oracle elsewhere (where the
+    kernels could only run interpreted)."""
+    return "pallas" if _on_tpu() else "ref"
+
+
+def _fit_block(n: int, want: int) -> int:
+    """Block for a length-``n`` axis: ``want``, or the whole axis when it
+    is shorter.  A TPU block must span whole (8, 128) tiles or the whole
+    array, so ``tree_attention`` pads a longer axis to a multiple of the
+    block instead of shrinking the block to a divisor of ``n``."""
+    return min(want, n)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _tree_attention(q, k, v, kv_last, pos_q, pos_k,
                     scale, window, q_off, block_q, block_k):
-    S, Skv = q.shape[1], k.shape[1]
-    return _pallas_fwd(q, k, v, kv_last, scale,
-                       block_q=_fit_block(S, block_q),
-                       block_k=_fit_block(Skv, block_k),
-                       q_off=q_off, window=window, pos_q=pos_q, pos_k=pos_k,
-                       interpret=not _on_tpu())
+    return _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
+                       block_k=block_k, q_off=q_off, window=window,
+                       pos_q=pos_q, pos_k=pos_k, interpret=not _on_tpu())
 
 
 def _fwd(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
          block_q, block_k):
-    S, Skv = q.shape[1], k.shape[1]
-    o, lse = _pallas_fwd(q, k, v, kv_last, scale,
-                         block_q=_fit_block(S, block_q),
-                         block_k=_fit_block(Skv, block_k),
-                         q_off=q_off, window=window, pos_q=pos_q,
-                         pos_k=pos_k, save_residuals=True,
+    o, lse = _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
+                         block_k=block_k, q_off=q_off, window=window,
+                         pos_q=pos_q, pos_k=pos_k, save_residuals=True,
                          interpret=not _on_tpu())
     return o, (q, k, v, kv_last, pos_q, pos_k, o, lse)
 
 
 def _bwd(scale, window, q_off, block_q, block_k, res, do):
     q, k, v, kv_last, pos_q, pos_k, o, lse = res
-    S, Skv = q.shape[1], k.shape[1]
     dq, dk, dv = _pallas_bwd(q, k, v, kv_last, o, lse, do, scale,
-                             block_q=_fit_block(S, block_q),
-                             block_k=_fit_block(Skv, block_k),
+                             block_q=block_q, block_k=block_k,
                              q_off=q_off, window=window, pos_q=pos_q,
                              pos_k=pos_k, interpret=not _on_tpu())
     return dq, dk, dv, None, None, None
 
 
 _tree_attention.defvjp(_fwd, _bwd)
+
+
+def _partitioned(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
+                 block_q, block_k):
+    """``_tree_attention`` under the active mesh.  XLA cannot partition a
+    Pallas TPU kernel, so on a mesh of several devices each runs it on its
+    own rows (batch over the data axes) and, where both head counts
+    divide the model axis, its own heads; rows are independent, and so
+    are heads (a kv head's query group stays on its device)."""
+    mesh = sh.current_mesh()
+    if mesh is None or mesh.size == 1:
+        return _tree_attention(q, k, v, kv_last, pos_q, pos_k, scale,
+                               window, q_off, block_q, block_k)
+    daxes, m = sh._CTX.data_axes, sh._CTX.model_axis
+    dsize = 1
+    for a in daxes:
+        dsize *= mesh.shape[a]
+    bx = daxes if q.shape[0] % dsize == 0 else None
+    hx = m if (q.shape[2] % mesh.shape[m] == 0
+               and k.shape[2] % mesh.shape[m] == 0) else None
+    heads = P(bx, None, hx, None)
+    rows = P(bx, None)
+    windowed = window is not None
+
+    def local(q, k, v, kv_last, *pos):
+        pq, pk = pos if windowed else (None, None)
+        return _tree_attention(q, k, v, kv_last, pq, pk, scale, window,
+                               q_off, block_q, block_k)
+
+    pos = (pos_q, pos_k) if windowed else ()
+    # check_vma=False: pallas_call output shapes carry no varying-axes
+    # annotation; every operand is split the same way, so none is needed
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(heads, heads, heads, rows,
+                                   *([rows] * len(pos))),
+                         out_specs=heads, check_vma=False)(
+        q, k, v, kv_last, *pos)
+
+
+def _pad_axis1(x, pad, value=0):
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[1] = (0, pad)
+    return jnp.pad(x, widths, constant_values=value)
 
 
 def tree_attention(q, k, v, kv_last, scale: float,
@@ -101,21 +141,24 @@ def tree_attention(q, k, v, kv_last, scale: float,
     — the k/v cotangents cover the ancestor rows too."""
     if window is None:
         pos_q = pos_k = None          # unused: keep them out of residuals
-    Skv = k.shape[1]
-    try:
-        _fit_block(Skv, block_k)
-    except ValueError:
-        # gateway-extended KV lengths need not be MXU-aligned: back-pad
-        # with invisible keys (kv_last = −1) to the sublane multiple; the
-        # pad sits outside the custom_vjp so dk/dv slice back automatically
-        pad = -Skv % 8
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kv_last = jnp.pad(kv_last, ((0, 0), (0, pad)), constant_values=-1)
-        if pos_k is not None:
-            pos_k = jnp.pad(pos_k, ((0, 0), (0, pad)))
-    return _tree_attention(q, k, v, kv_last, pos_q, pos_k,
-                           scale, window, q_off, block_q, block_k)
+    S, Skv = q.shape[1], k.shape[1]
+    # back-pad both axes to whole blocks: extra queries are sliced off (a
+    # zero cotangent, so they add nothing to dk/dv), extra keys are
+    # invisible (kv_last = −1); the pads sit outside the custom_vjp, so
+    # the cotangents slice back automatically
+    bq = _fit_block(S, block_q)
+    pad_q = -S % bq
+    need = max(Skv, q_off + S + pad_q)
+    bk = _fit_block(need, block_k)
+    pad_k = need - Skv + (-need % bk)
+    q = _pad_axis1(q, pad_q)
+    k, v = _pad_axis1(k, pad_k), _pad_axis1(v, pad_k)
+    kv_last = _pad_axis1(kv_last, pad_k, -1)
+    if window is not None:
+        pos_q, pos_k = _pad_axis1(pos_q, pad_q), _pad_axis1(pos_k, pad_k)
+    o = _partitioned(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
+                     bq, bk)
+    return o[:, :S] if pad_q else o
 
 
 def prefill_attention(q, k, v, scale: float, *,

@@ -29,12 +29,15 @@ kernel (App. A.1).  The TPU-native equivalent built here:
     *positions*, not DFS indices, so the window applies along the path and
     across partition gateways (ancestor positions travel in ``pos_k``).
   - ``save_residuals=True`` additionally emits the per-row logsumexp
-    ``lse[b, h, i] = m_i + log(l_i)`` (``NEG_INF`` for fully-masked rows),
+    ``lse[b, h, 0, i] = m_i + log(l_i)`` (``NEG_INF`` for fully-masked rows),
     the O(S) statistic the fused backward (tree_attention_bwd.py) needs to
     regenerate softmax probabilities without the O(S²) matrix.
 
 GQA: q head h reads kv head h // (H/Kh) via the BlockSpec index map.
-Validated on CPU with interpret=True against kernels/ref.py.
+Operands are re-laid out head-major (``head_major``) so that every
+block's last two dimensions are (8, 128)-aligned or whole, as the TPU
+compiler requires.  Validated on CPU with interpret=True against
+kernels/ref.py; ``tests/test_tpu_compile.py`` compiles it for a v5e.
 """
 from __future__ import annotations
 
@@ -109,6 +112,18 @@ def block_live_mask(kv_last, S: int, block_q: int, block_k: int,
                       ki * block_k, kmax[None, :], qpmin, kpmax, window)
 
 
+def head_major(q, k, v, kv_last, pos_q=None, pos_k=None):
+    """The kernels' operand layout: q [B,H,S,hd], k/v [B,Kh,Skv,hd] and
+    the per-token int arrays as [B,1,len] rows.  Every block then spans
+    whole (8, 128) tiles or a whole dimension, which the TPU compiler
+    requires; the per-query rows are turned into columns inside the
+    kernels.  Shared by the forward and both backward kernels."""
+    ints = [None if a is None else a.astype(jnp.int32)[:, None, :]
+            for a in (kv_last, pos_q, pos_k)]
+    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), *ints)
+
+
 def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    kv_last: jax.Array, scale: float, *,
                    block_q: int = 128, block_k: int = 128,
@@ -125,7 +140,8 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     marked kv_last = −1).  ``window``: static sliding-window size over
     *positions*; requires pos_q [B,S] / pos_k [B,Skv].
 
-    With ``save_residuals`` returns ``(o, lse)`` where lse is [B,H,S] f32.
+    With ``save_residuals`` returns ``(o, lse)`` where lse is [B,H,1,S]
+    f32 (one row of per-query statistics per head).
     """
     B, S, H, hd = q.shape
     Skv, Kh = k.shape[1], k.shape[2]
@@ -142,6 +158,7 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kv_last = kv_last.astype(jnp.int32)
     skip = skip_scalars(kv_last, B, nq, nk, block_q, block_k,
                         pos_q, pos_k, window)
+    qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
 
     def kernel(skip_ref, *refs):
         q_ref, k_ref, v_ref, kl_ref = refs[:4]
@@ -179,10 +196,10 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
         @pl.when(live)
         def _compute():
-            qq = q_ref[0, :, 0, :].astype(jnp.float32)       # [BQ, hd]
-            kk = k_ref[0, :, 0, :].astype(jnp.float32)       # [BK, hd]
-            vv = v_ref[0, :, 0, :].astype(jnp.float32)
-            kl = kl_ref[0, :]                                # [BK]
+            qq = q_ref[...].astype(jnp.float32)              # [BQ, hd]
+            kk = k_ref[...].astype(jnp.float32)              # [BK, hd]
+            vv = v_ref[...].astype(jnp.float32)
+            kl = kl_ref[...]                                 # [1, BK]
             logits = jax.lax.dot_general(
                 qq, kk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -190,17 +207,17 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 jnp.int32, (block_q, block_k), 0)
             j_idx = kv_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            vis = (j_idx <= i_idx) & (kl[None, :] >= i_idx)
+            vis = (j_idx <= i_idx) & (kl >= i_idx)
             if windowed:
-                vis = vis & ((pq_ref[0, :][:, None]
-                              - pk_ref[0, :][None, :]) < window)
+                vis = vis & ((pq_ref[...].reshape(block_q, 1)
+                              - pk_ref[...]) < window)
             lg = jnp.where(vis, logits, NEG_INF)
-            m_prev = m_scr[...]
-            m_new = jnp.maximum(m_prev, lg.max(axis=1))
-            p = jnp.where(vis, jnp.exp(lg - m_new[:, None]), 0.0)
+            m_prev = m_scr[...]                              # [BQ, 1]
+            m_new = jnp.maximum(m_prev, lg.max(axis=1, keepdims=True))
+            p = jnp.where(vis, jnp.exp(lg - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-            acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+            l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
                 p, vv, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[...] = m_new
@@ -208,42 +225,43 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         @pl.when(ki == num_kv - 1)
         def _finalize():
             l = l_scr[...]
-            o = acc_scr[...] / jnp.maximum(l, 1e-37)[:, None]
-            o = jnp.where((l > 0)[:, None], o, 0.0)
-            o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
+            o = acc_scr[...] / jnp.maximum(l, 1e-37)
+            o = jnp.where(l > 0, o, 0.0)
+            o_ref[...] = o.astype(o_ref.dtype)
             if save_residuals:
                 m = m_scr[...]
                 lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-37)),
                                 NEG_INF)
-                lse_ref[0, 0, :] = lse
+                lse_ref[...] = lse.reshape(1, block_q)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, qi, h, 0)),
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k),
-                     lambda b, h, qi, ki, skip: (b, ki)),
+        pl.BlockSpec((None, None, block_q, hd),
+                     lambda b, h, qi, ki, skip: (b, h, qi, 0)),
+        pl.BlockSpec((None, None, block_k, hd),
+                     lambda b, h, qi, ki, skip: (b, h // G, ki, 0)),
+        pl.BlockSpec((None, None, block_k, hd),
+                     lambda b, h, qi, ki, skip: (b, h // G, ki, 0)),
+        pl.BlockSpec((None, 1, block_k),
+                     lambda b, h, qi, ki, skip: (b, 0, ki)),
     ]
-    inputs = [q, k, v, kv_last]
+    inputs = [qh, kh, vh, kl]
     if windowed:
         in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda b, h, qi, ki, skip: (b, qi)),
-            pl.BlockSpec((1, block_k),
-                         lambda b, h, qi, ki, skip: (b, ki)),
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b, h, qi, ki, skip: (b, 0, qi)),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda b, h, qi, ki, skip: (b, 0, ki)),
         ]
-        inputs += [pos_q.astype(jnp.int32), pos_k.astype(jnp.int32)]
+        inputs += [pq, pk]
 
-    out_shape = [jax.ShapeDtypeStruct((B, S, H, hd), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, 1, hd),
-                              lambda b, h, qi, ki, skip: (b, qi, h, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B, H, S, hd), q.dtype)]
+    out_specs = [pl.BlockSpec((None, None, block_q, hd),
+                              lambda b, h, qi, ki, skip: (b, h, qi, 0))]
     if save_residuals:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, S), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, block_q),
-                                      lambda b, h, qi, ki, skip: (b, h, qi)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (None, None, 1, block_q),
+            lambda b, h, qi, ki, skip: (b, h, 0, qi)))
 
     out = pl.pallas_call(
         kernel,
@@ -253,14 +271,15 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, hd), jnp.float32),
             ],
         ),
         out_shape=out_shape,
         interpret=interpret,
     )(skip, *inputs)
+    o = out[0].transpose(0, 2, 1, 3)
     if save_residuals:
-        return out[0], out[1]
-    return out[0]
+        return o, out[1]
+    return o

@@ -2,7 +2,7 @@
 
 Flash-style recomputation: the forward (tree_attention.py with
 ``save_residuals=True``) saves only the per-row logsumexp
-``lse[b,h,i] = m_i + log l_i`` — O(S) instead of the O(S²) probability
+``lse[b,h,0,i] = m_i + log l_i`` — O(S) instead of the O(S²) probability
 matrix — and the backward regenerates ``p_ij = exp(s_ij − lse_i)`` block
 by block on the fly.  With ``Δ_i = Σ_d do_id·o_id`` (precomputed XLA-side,
 one elementwise reduction):
@@ -34,8 +34,10 @@ TPU output revisiting must be consecutive:
     in-kernel in the same VMEM accumulator (no [B,S,H,hd] staging buffer
     + XLA reduction afterwards).
 
-Validated on CPU with interpret=True against jax.vjp through
-kernels/ref.py (tests/test_kernels_bwd.py).
+Both run on the forward's head-major operand layout
+(``tree_attention.head_major``).  Validated on CPU with interpret=True
+against jax.vjp through kernels/ref.py (tests/test_kernels_bwd.py);
+``tests/test_tpu_compile.py`` compiles them for a v5e.
 """
 from __future__ import annotations
 
@@ -46,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tree_attention import block_live, skip_scalars
+from repro.kernels.tree_attention import (block_live, head_major,
+                                         skip_scalars)
 
 NEG_INF = -1e30
 
@@ -54,7 +57,8 @@ NEG_INF = -1e30
 def _vis_and_p(qq, kk, kl, lse, scale, q_start, kv_start, block_q, block_k,
                pq=None, pk=None, window=None):
     """Recompute the masked probability block p_ij = exp(s_ij − lse_i).
-    ``q_start`` is the GLOBAL query index of the block's first row."""
+    ``q_start`` is the GLOBAL query index of the block's first row; kl/pk
+    are [1, BK] rows, lse/pq [BQ, 1] columns."""
     logits = jax.lax.dot_general(
         qq, kk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -62,20 +66,20 @@ def _vis_and_p(qq, kk, kl, lse, scale, q_start, kv_start, block_q, block_k,
         jnp.int32, (block_q, block_k), 0)
     j_idx = kv_start + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    vis = (j_idx <= i_idx) & (kl[None, :] >= i_idx)
+    vis = (j_idx <= i_idx) & (kl >= i_idx)
     if window is not None:
-        vis = vis & ((pq[:, None] - pk[None, :]) < window)
+        vis = vis & ((pq - pk) < window)
     # clamp the exponent: invisible entries are discarded by the select but
     # must not overflow to inf first (inf is fine for select, but keep the
     # VPU in normal range); visible entries satisfy s ≤ m ≤ lse + log l.
-    expo = jnp.where(vis, logits - lse[:, None], NEG_INF)
+    expo = jnp.where(vis, logits - lse, NEG_INF)
     return jnp.where(vis, jnp.exp(expo), 0.0)
 
 
-def _bwd_dq(q, k, v, kv_last, lse, delta, do, scale, skip,
-            block_q, block_k, q_off, window, pos_q, pos_k, interpret):
-    B, S, H, hd = q.shape
-    Skv, Kh = k.shape[1], k.shape[2]
+def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
+            block_q, block_k, q_off, window, pq, pk, interpret):
+    B, H, S, hd = qh.shape
+    Kh, Skv = kh.shape[1], kh.shape[2]
     G = max(1, H // Kh)
     nq, nk = S // block_q, Skv // block_k
     windowed = window is not None
@@ -110,54 +114,48 @@ def _bwd_dq(q, k, v, kv_last, lse, delta, do, scale, skip,
 
         @pl.when(live)
         def _compute():
-            qq = q_ref[0, :, 0, :].astype(jnp.float32)      # [BQ, hd]
-            kk = k_ref[0, :, 0, :].astype(jnp.float32)      # [BK, hd]
-            vv = v_ref[0, :, 0, :].astype(jnp.float32)
-            kl = kl_ref[0, :]
-            lse = lse_ref[0, 0, :]                          # [BQ]
-            dlt = dl_ref[0, 0, :]                           # [BQ]
-            dd = do_ref[0, :, 0, :].astype(jnp.float32)     # [BQ, hd]
-            pq = pq_ref[0, :] if windowed else None
-            pk = pk_ref[0, :] if windowed else None
+            qq = q_ref[...].astype(jnp.float32)             # [BQ, hd]
+            kk = k_ref[...].astype(jnp.float32)             # [BK, hd]
+            vv = v_ref[...].astype(jnp.float32)
+            kl = kl_ref[...]                                # [1, BK]
+            lse = lse_ref[...].reshape(block_q, 1)
+            dlt = dl_ref[...].reshape(block_q, 1)
+            dd = do_ref[...].astype(jnp.float32)            # [BQ, hd]
+            pqc = pq_ref[...].reshape(block_q, 1) if windowed else None
+            pkr = pk_ref[...] if windowed else None
             p = _vis_and_p(qq, kk, kl, lse, scale, q_start, kv_start,
-                           block_q, block_k, pq, pk, window)
+                           block_q, block_k, pqc, pkr, window)
             dp = jax.lax.dot_general(                        # do·vᵀ [BQ,BK]
                 dd, vv, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ds = p * (dp - dlt[:, None]) * scale
+            ds = p * (dp - dlt) * scale
             dq_scr[...] += jax.lax.dot_general(              # ds·k [BQ,hd]
                 ds, kk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         @pl.when(ki == num_kv - 1)
         def _finalize():
-            dq_ref[0, :, 0, :] = dq_scr[...].astype(dq_ref.dtype)
+            dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, qi, h, 0)),
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k),
-                     lambda b, h, qi, ki, skip: (b, ki)),
-        pl.BlockSpec((1, 1, block_q),
-                     lambda b, h, qi, ki, skip: (b, h, qi)),
-        pl.BlockSpec((1, 1, block_q),
-                     lambda b, h, qi, ki, skip: (b, h, qi)),
-        pl.BlockSpec((1, block_q, 1, hd),
-                     lambda b, h, qi, ki, skip: (b, qi, h, 0)),
-    ]
-    inputs = [q, k, v, kv_last, lse, delta, do]
+    qspec = pl.BlockSpec((None, None, block_q, hd),
+                         lambda b, h, qi, ki, skip: (b, h, qi, 0))
+    kspec = pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, qi, ki, skip: (b, h // G, ki, 0))
+    rowq = pl.BlockSpec((None, None, 1, block_q),
+                        lambda b, h, qi, ki, skip: (b, h, 0, qi))
+    in_specs = [qspec, kspec, kspec,
+                pl.BlockSpec((None, 1, block_k),
+                             lambda b, h, qi, ki, skip: (b, 0, ki)),
+                rowq, rowq, qspec]
+    inputs = [qh, kh, vh, kl, lse, delta, doh]
     if windowed:
         in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda b, h, qi, ki, skip: (b, qi)),
-            pl.BlockSpec((1, block_k),
-                         lambda b, h, qi, ki, skip: (b, ki)),
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b, h, qi, ki, skip: (b, 0, qi)),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda b, h, qi, ki, skip: (b, 0, ki)),
         ]
-        inputs += [pos_q.astype(jnp.int32), pos_k.astype(jnp.int32)]
+        inputs += [pq, pk]
 
     return pl.pallas_call(
         kernel,
@@ -165,19 +163,18 @@ def _bwd_dq(q, k, v, kv_last, lse, delta, do, scale, skip,
             num_scalar_prefetch=1,
             grid=(B, H, nq, nk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                                   lambda b, h, qi, ki, skip: (b, qi, h, 0)),
+            out_specs=qspec,
             scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), qh.dtype),
         interpret=interpret,
     )(skip, *inputs)
 
 
-def _bwd_dkv(q, k, v, kv_last, lse, delta, do, scale, skip,
-             block_q, block_k, q_off, window, pos_q, pos_k, interpret):
-    B, S, H, hd = q.shape
-    Skv, Kh = k.shape[1], k.shape[2]
+def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
+             block_q, block_k, q_off, window, pq, pk, interpret):
+    B, H, S, hd = qh.shape
+    Kh, Skv = kh.shape[1], kh.shape[2]
     G = max(1, H // Kh)
     nq, nk = S // block_q, Skv // block_k
     windowed = window is not None
@@ -215,65 +212,55 @@ def _bwd_dkv(q, k, v, kv_last, lse, delta, do, scale, skip,
 
         @pl.when(live)
         def _compute():
-            qq = q_ref[0, :, 0, :].astype(jnp.float32)      # [BQ, hd]
-            kk = k_ref[0, :, 0, :].astype(jnp.float32)      # [BK, hd]
-            vv = v_ref[0, :, 0, :].astype(jnp.float32)
-            kl = kl_ref[0, :]
-            lse = lse_ref[0, 0, :]
-            dlt = dl_ref[0, 0, :]
-            dd = do_ref[0, :, 0, :].astype(jnp.float32)     # [BQ, hd]
-            pq = pq_ref[0, :] if windowed else None
-            pk = pk_ref[0, :] if windowed else None
+            qq = q_ref[...].astype(jnp.float32)             # [BQ, hd]
+            kk = k_ref[...].astype(jnp.float32)             # [BK, hd]
+            vv = v_ref[...].astype(jnp.float32)
+            kl = kl_ref[...]                                # [1, BK]
+            lse = lse_ref[...].reshape(block_q, 1)
+            dlt = dl_ref[...].reshape(block_q, 1)
+            dd = do_ref[...].astype(jnp.float32)            # [BQ, hd]
+            pqc = pq_ref[...].reshape(block_q, 1) if windowed else None
+            pkr = pk_ref[...] if windowed else None
             p = _vis_and_p(qq, kk, kl, lse, scale, q_start, kv_start,
-                           block_q, block_k, pq, pk, window)
+                           block_q, block_k, pqc, pkr, window)
             dv_scr[...] += jax.lax.dot_general(              # pᵀ·do [BK,hd]
                 p, dd, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(
                 dd, vv, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ds = p * (dp - dlt[:, None]) * scale
+            ds = p * (dp - dlt) * scale
             dk_scr[...] += jax.lax.dot_general(              # dsᵀ·q [BK,hd]
                 ds, qq, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         @pl.when((g == num_g - 1) & (qi == num_q - 1))
         def _finalize():
-            dk_ref[0, :, 0, :] = dk_scr[...].astype(dk_ref.dtype)
-            dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+            dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec(
-            (1, block_q, 1, hd),
-            lambda b, kh, ki, g, qi, skip: (b, qi, kh * G + g, 0)),
-        pl.BlockSpec(
-            (1, block_k, 1, hd),
-            lambda b, kh, ki, g, qi, skip: (b, ki, kh, 0)),
-        pl.BlockSpec(
-            (1, block_k, 1, hd),
-            lambda b, kh, ki, g, qi, skip: (b, ki, kh, 0)),
-        pl.BlockSpec(
-            (1, block_k),
-            lambda b, kh, ki, g, qi, skip: (b, ki)),
-        pl.BlockSpec(
-            (1, 1, block_q),
-            lambda b, kh, ki, g, qi, skip: (b, kh * G + g, qi)),
-        pl.BlockSpec(
-            (1, 1, block_q),
-            lambda b, kh, ki, g, qi, skip: (b, kh * G + g, qi)),
-        pl.BlockSpec(
-            (1, block_q, 1, hd),
-            lambda b, kh, ki, g, qi, skip: (b, qi, kh * G + g, 0)),
-    ]
-    inputs = [q, k, v, kv_last, lse, delta, do]
+    qspec = pl.BlockSpec(
+        (None, None, block_q, hd),
+        lambda b, kh, ki, g, qi, skip: (b, kh * G + g, qi, 0))
+    kspec = pl.BlockSpec(
+        (None, None, block_k, hd),
+        lambda b, kh, ki, g, qi, skip: (b, kh, ki, 0))
+    rowq = pl.BlockSpec(
+        (None, None, 1, block_q),
+        lambda b, kh, ki, g, qi, skip: (b, kh * G + g, 0, qi))
+    in_specs = [qspec, kspec, kspec,
+                pl.BlockSpec((None, 1, block_k),
+                             lambda b, kh, ki, g, qi, skip: (b, 0, ki)),
+                rowq, rowq, qspec]
+    inputs = [qh, kh, vh, kl, lse, delta, doh]
     if windowed:
         in_specs += [
-            pl.BlockSpec((1, block_q),
-                         lambda b, kh, ki, g, qi, skip: (b, qi)),
-            pl.BlockSpec((1, block_k),
-                         lambda b, kh, ki, g, qi, skip: (b, ki)),
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b, kh, ki, g, qi, skip: (b, 0, qi)),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda b, kh, ki, g, qi, skip: (b, 0, ki)),
         ]
-        inputs += [pos_q.astype(jnp.int32), pos_k.astype(jnp.int32)]
+        inputs += [pq, pk]
 
     out = pl.pallas_call(
         kernel,
@@ -281,22 +268,15 @@ def _bwd_dkv(q, k, v, kv_last, lse, delta, do, scale, skip,
             num_scalar_prefetch=1,
             grid=(B, Kh, nk, G, nq),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec(
-                    (1, block_k, 1, hd),
-                    lambda b, kh, ki, g, qi, skip: (b, ki, kh, 0)),
-                pl.BlockSpec(
-                    (1, block_k, 1, hd),
-                    lambda b, kh, ki, g, qi, skip: (b, ki, kh, 0)),
-            ],
+            out_specs=[kspec, kspec],
             scratch_shapes=[
                 pltpu.VMEM((block_k, hd), jnp.float32),
                 pltpu.VMEM((block_k, hd), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Skv, Kh, hd), k.dtype),
-            jax.ShapeDtypeStruct((B, Skv, Kh, hd), v.dtype),
+            jax.ShapeDtypeStruct((B, Kh, Skv, hd), kh.dtype),
+            jax.ShapeDtypeStruct((B, Kh, Skv, hd), vh.dtype),
         ],
         interpret=interpret,
     )(skip, *inputs)
@@ -314,7 +294,7 @@ def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     """Fused dq/dk/dv for tree attention.
 
     q/o/do: [B,S,H,hd]; k/v: [B,Skv,Kh,hd]; kv_last: [B,Skv] int32;
-    lse: [B,H,S] f32 from the forward's ``save_residuals=True``.
+    lse: [B,H,1,S] f32 from the forward's ``save_residuals=True``.
     q_off/window/pos_q/pos_k: same gateway/window layout as the forward.
     Returns (dq, dk, dv) in the input dtypes; dk/dv cover the full Skv,
     including the ancestor rows [0, q_off) (d_extra_k / d_extra_v).
@@ -327,15 +307,17 @@ def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         (S, Skv, block_q, block_k)
     assert Skv >= q_off + S, (Skv, q_off, S)
     kv_last = kv_last.astype(jnp.int32)
-    # Δ_i = Σ_d do_id o_id, [B,H,S] — cheap elementwise reduce, XLA-side.
+    # Δ_i = Σ_d do_id o_id, [B,H,1,S] — cheap elementwise reduce, XLA-side.
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)
-             ).sum(-1).transpose(0, 2, 1)
+             ).sum(-1).transpose(0, 2, 1)[:, :, None, :]
     # one shared prefetch array for both kernels (same blocks, same skip)
     skip = skip_scalars(kv_last, B, S // block_q, Skv // block_k,
                         block_q, block_k, pos_q, pos_k, window)
-    dq = _bwd_dq(q, k, v, kv_last, lse, delta, do, scale, skip,
-                 block_q, block_k, q_off, window, pos_q, pos_k, interpret)
-    dk, dv = _bwd_dkv(q, k, v, kv_last, lse, delta, do, scale, skip,
-                      block_q, block_k, q_off, window, pos_q, pos_k,
-                      interpret)
-    return dq, dk, dv
+    qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
+    doh = do.transpose(0, 2, 1, 3)
+    args = (qh, kh, vh, kl, lse, delta, doh, scale, skip, block_q, block_k,
+            q_off, window, pq, pk, interpret)
+    dq = _bwd_dq(*args)
+    dk, dv = _bwd_dkv(*args)
+    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
+            dv.transpose(0, 2, 1, 3))

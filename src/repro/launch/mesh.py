@@ -31,8 +31,9 @@ class MeshDescriptor:
     interconnect; collectives spanning only the remaining (``ici_axes``)
     stay on the pod's ICI.  ``build()`` materializes the jax Mesh (needs
     that many devices — real or ``--xla_force_host_platform_device_count``
-    fakes); ``abstract()`` needs zero devices and supports host-side spec
-    math only (jax 0.4 AbstractMesh cannot lower)."""
+    fakes); ``abstract()`` needs zero devices (host-side spec math).
+    Both carry Auto axis types, so the ``with_sharding_constraint`` rules
+    in ``repro.sharding`` apply as written."""
     name: str
     shape: tuple[int, ...]
     axis_names: tuple[str, ...]
@@ -61,12 +62,18 @@ class MeshDescriptor:
             n *= self.axis_size(a)
         return n
 
+    @property
+    def axis_types(self) -> tuple:
+        return (jax.sharding.AxisType.Auto,) * len(self.shape)
+
     def build(self) -> jax.sharding.Mesh:
-        return jax.make_mesh(self.shape, self.axis_names)
+        return jax.make_mesh(self.shape, self.axis_names,
+                             axis_types=self.axis_types)
 
     def abstract(self):
         from jax.sharding import AbstractMesh
-        return AbstractMesh(tuple(zip(self.axis_names, self.shape)))
+        return AbstractMesh(self.shape, self.axis_names,
+                            axis_types=self.axis_types)
 
 
 def production_descriptor(multi_pod: bool = False,

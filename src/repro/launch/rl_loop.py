@@ -27,11 +27,17 @@ weight versions — the run fails loudly if the observed lag ever exceeds
 verifies the online plan path reproduces the offline ``loss_mode="rl"``
 gradients to ≤1e-6 max-rel.  ``--ckpt-every``/``--resume`` give the
 long-running service a mid-stream restart point.
+
+``--impl`` defaults to the platform's attention (Pallas kernels on a TPU,
+``ref`` elsewhere) and compiled programs persist as in
+``repro.launch.train``.  ``main(argv)`` runs in-process and returns the
+run's engine and per-step metrics.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +46,7 @@ import numpy as np
 from repro import sharding as sh
 from repro.configs import get_config
 from repro.data.loader import LoaderConfig
+from repro.kernels.ops import platform_impl
 from repro.launch.mesh import data_axis_size, make_host_mesh
 from repro.models.model import init_params
 from repro.serve.rollout import RolloutConfig, rollout_group
@@ -80,7 +87,7 @@ def check_frozen_grads(cfg, lc, pcfg, params, trees, impl) -> float:
     return max_rel_err(grads, ref)
 
 
-def main() -> None:
+def main(argv: Optional[list[str]] = None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -103,23 +110,23 @@ def main() -> None:
                          "ahead of the weights it samples (the staleness "
                          "bound)")
     ap.add_argument("--temperature", type=float, default=1.0)
-    ap.add_argument("--impl", default="ref",
-                    choices=["ref", "chunked", "pallas"])
+    ap.add_argument("--impl", default=None,
+                    choices=["ref", "chunked", "pallas"],
+                    help="attention implementation (default: the "
+                         "platform's — pallas on TPU, ref elsewhere)")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check-grads", action="store_true",
                     help="verify online vs offline RL gradients at exit")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent jax compilation cache: a restarted "
-                         "service re-loads its compiled modules from disk")
     ap.add_argument("--save", default=None)
     ap.add_argument("--ckpt-every", type=int, default=None)
     ap.add_argument("--resume", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.ckpt_every is not None and not args.save:
         ap.error("--ckpt-every needs --save (the checkpoint directory)")
 
     cfg = get_config(args.arch, smoke=args.smoke)
+    args.impl = args.impl or platform_impl()
     tree_cap = args.prompt_len + args.k * args.max_new
     if tree_cap > args.seq_len:
         ap.error(f"a rollout tree can reach {tree_cap} unique tokens "
@@ -127,9 +134,9 @@ def main() -> None:
                  f"> --seq-len {args.seq_len}: raise --seq-len to "
                  f"guarantee zero drops")
     lag_bound = args.max_ahead + args.lookahead - 1
-    print(f"[rl] arch={cfg.name} k={args.k} groups={args.groups} "
-          f"steps={args.steps} max_ahead={args.max_ahead} "
-          f"(lag bound {lag_bound})")
+    print(f"[rl] arch={cfg.name} impl={args.impl} k={args.k} "
+          f"groups={args.groups} steps={args.steps} "
+          f"max_ahead={args.max_ahead} (lag bound {lag_bound})")
 
     mesh, daxes = make_host_mesh(), ("data",)
     ndata = data_axis_size(mesh, daxes)
@@ -149,18 +156,14 @@ def main() -> None:
                        max_ahead_steps=args.max_ahead, rollout=rc,
                        seed=args.seed)
 
-    if args.compile_cache_dir:
-        from repro.train.warmup import configure_compile_cache
-        d = configure_compile_cache(args.compile_cache_dir)
-        print(f"[rl] persistent compile cache: {d}")
+    from repro.train.warmup import configure_compile_cache
+    print(f"[rl] persistent compile cache: {configure_compile_cache()}")
 
     with sh.use_mesh(mesh, data_axes=daxes):
         params = init_params(cfg, jax.random.key(args.seed))
-        opt_state = init_opt_state(params)
         done = 0
         if args.resume:
-            params, opt_state = load_checkpoint(args.resume, params,
-                                                opt_state)
+            params = load_checkpoint(args.resume, params)
             done = int(load_meta(args.resume).get("steps", 0))
             print(f"[rl] resumed {args.resume} @ step {done}")
 
@@ -176,9 +179,12 @@ def main() -> None:
         # engine can consume it, so the loop never blocks on a compile.
         from repro.core.plan_cost import CompileCacheSim
         from repro.train.warmup import AOTWarmupService
+        # The warm passes run on a throwaway copy of the training state,
+        # made before the trainer's own optimizer state exists: at
+        # published widths one chip cannot hold two fp32 AdamW states.
         warm = AOTWarmupService(cfg, lc, pcfg, params=params,
-                                opt_cfg=opt_cfg, opt_state=opt_state,
-                                impl=args.impl, sim=CompileCacheSim())
+                                opt_cfg=opt_cfg, impl=args.impl,
+                                sim=CompileCacheSim())
         wtrees = [rollout_group(cfg, params,
                                 np.zeros(args.prompt_len, np.int32) + g,
                                 rc, jax.random.key(g))[0]
@@ -187,6 +193,7 @@ def main() -> None:
                   if not ps.is_empty]
         for ps in wsteps:
             warm.prewarm(step=ps)
+        warm.raise_errors("rl")
         if wsteps:
             # run the warm window through the SHARED cache twice: the
             # update's donated inputs switch to its own committed output
@@ -196,7 +203,7 @@ def main() -> None:
                                    exec_cache=warm.cache,
                                    universe=warm.universe)
             p2 = jax.tree.map(jnp.copy, params)
-            o2 = jax.tree.map(jnp.copy, opt_state)
+            o2 = init_opt_state(p2)
             for _ in range(2):
                 p2, o2 = weng.warmup(p2, o2, wsteps[0].execution_plan())
             assert weng.host_syncs == 0, "warmup must not sync"
@@ -212,6 +219,9 @@ def main() -> None:
         print(f"[rl] aot-warmup: {len(warm.cache)} executables "
               f"({warm.cache.compile_s:.1f}s compile) over "
               f"{len(warm.cache.signatures())} signatures")
+        opt_state = init_opt_state(params)
+        if args.resume:
+            _, opt_state = load_checkpoint(args.resume, params, opt_state)
 
         store = WeightStore(params, version=done)
         engine = TreeTrainEngine(cfg, opt_cfg, impl=args.impl,
@@ -233,11 +243,12 @@ def main() -> None:
                 continue
             ts = time.time()
             params, opt_state, m = engine.step(params, opt_state, plan)
+            m["sec"] = time.time() - ts
             history.append(m)
             print(f"step {engine.steps_done - 1:4d} "
                   f"loss {m['loss']:10.4f} nll/tok {m['nll']:7.4f} "
                   f"lag {m.get('max_lag', 0)} "
-                  f"{(time.time() - ts) * 1e3:7.1f}ms", flush=True)
+                  f"{m['sec'] * 1e3:7.1f}ms", flush=True)
             if args.ckpt_every and engine.steps_done % args.ckpt_every == 0:
                 save_checkpoint(args.save, params, opt_state,
                                 meta={"arch": cfg.name,
@@ -269,7 +280,10 @@ def main() -> None:
               f"{pipe.build_s * 1e3:.0f}ms built")
         print(f"[rl] aot: {engine.retraces} mid-loop retraces, "
               f"{engine.compile_wait_s * 1e3:.0f}ms exposed compile "
-              f"wait, {warm.prewarmed} executables prewarmed in-stream")
+              f"wait, {warm.prewarmed} executables prewarmed in-stream"
+              + (f", {len(warm.errors)} warmup errors"
+                 if warm.errors else ""))
+        warm.raise_errors("rl")
         assert dropped == 0, f"{dropped} trees dropped"
         if args.smoke:
             # the loop's whole signature stream was prewarmed on the
@@ -305,6 +319,7 @@ def main() -> None:
                             meta={"arch": cfg.name,
                                   "steps": engine.steps_done})
             print(f"[rl] saved → {args.save}")
+    return {"args": args, "engine": engine, "history": history}
 
 
 if __name__ == "__main__":

@@ -38,18 +38,27 @@ advantages≡1 it reproduces SFT exactly).
 ``multi`` builds the production mesh (requires the dry-run's fake-device
 env when not on a real pod — intended for lowering checks; real training
 on hardware uses the same code path).
+
+``--impl`` defaults to the platform's attention: the fused Pallas kernels
+on a TPU, the dense ``ref`` oracle elsewhere.  Compiled programs persist
+in ``$JAX_COMPILATION_CACHE_DIR``, or in ``.jax_cache`` at the checkout
+root when that is unset (``train/warmup.configure_compile_cache``).
+``main(argv)`` runs in-process and returns the run's config, mesh, step
+history and first executed plan (``chip_smoke.py`` drives it that way).
 """
 from __future__ import annotations
 
 import argparse
 import json
 import time
+from typing import Optional
 
 import jax
 
 from repro import sharding as sh
 from repro.configs import get_config
 from repro.data.loader import LoaderConfig
+from repro.kernels.ops import platform_impl
 from repro.launch.mesh import data_axes, data_axis_size, make_host_mesh, \
     make_production_mesh
 from repro.models.model import init_params
@@ -60,7 +69,7 @@ from repro.train.optimizer import OptimizerConfig, init_opt_state
 from repro.train.planner import PlannerConfig, plans
 
 
-def main() -> None:
+def main(argv: Optional[list[str]] = None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -80,8 +89,10 @@ def main() -> None:
                     help="background plan-builder threads (double-"
                          "buffered against engine.step; 0 = synchronous)")
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--impl", default="ref",
-                    choices=["ref", "chunked", "pallas"])
+    ap.add_argument("--impl", default=None,
+                    choices=["ref", "chunked", "pallas"],
+                    help="attention implementation (default: the "
+                         "platform's — pallas on TPU, ref elsewhere)")
     ap.add_argument("--loss-mode", default="sep_avg",
                     choices=["sep_avg", "uniform", "rl"],
                     help="sep_avg: λ_t = g_t/K (SFT, Eq. 4); uniform: "
@@ -118,10 +129,6 @@ def main() -> None:
                          "blocks on a cold jit bucket (train/warmup)")
     ap.add_argument("--warmup-threads", type=int, default=1,
                     help="background AOT compile threads for --aot-warmup")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent jax compilation cache directory: a "
-                         "restarted run re-loads every compiled module "
-                         "from disk instead of recompiling")
     ap.add_argument("--mesh", default="host",
                     choices=["host", "single", "multi"])
     ap.add_argument("--seed", type=int, default=0)
@@ -133,11 +140,12 @@ def main() -> None:
                     help="checkpoint dir to resume from (replays the "
                          "deterministic plan stream up to the saved step)")
     ap.add_argument("--log-every", type=int, default=1)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.ckpt_every is not None and not args.save:
         ap.error("--ckpt-every needs --save (the checkpoint directory)")
 
     cfg = get_config(args.arch, smoke=args.smoke)
+    args.impl = args.impl or platform_impl()
     if args.kind is None:
         args.kind = "grpo" if args.loss_mode == "rl" else "agentic"
     print(f"[train] arch={cfg.name} family={cfg.family} mode={args.mode} "
@@ -203,12 +211,10 @@ def main() -> None:
                       auto_capacity=auto_capacity,
                       gen_kwargs=gen_kwargs)
 
-    if args.compile_cache_dir:
-        # before ANY compile (param init included) so every module the
-        # run produces lands in — or loads from — the persistent cache
-        from repro.train.warmup import configure_compile_cache
-        d = configure_compile_cache(args.compile_cache_dir)
-        print(f"[train] persistent compile cache: {d}")
+    # before ANY compile (param init included) so every module the run
+    # produces lands in — or loads from — the persistent cache
+    from repro.train.warmup import configure_compile_cache
+    print(f"[train] persistent compile cache: {configure_compile_cache()}")
 
     with sh.use_mesh(mesh, data_axes=daxes):
         params = init_params(cfg, jax.random.key(args.seed))
@@ -251,6 +257,7 @@ def main() -> None:
         dropped_total = 0
         t0 = time.time()
         history = []
+        first_plan = None
         # THE training loop: every step — packed rows, partition waves,
         # SFT or RL — is one engine.step over its ExecutionPlan; the
         # planner builds the NEXT plan on background threads meanwhile
@@ -263,6 +270,7 @@ def main() -> None:
             executed += 1
             if executed <= done:    # resume: replay the plan stream
                 continue
+            first_plan = first_plan or plan
             ts = time.time()
             params, opt_state, m = engine.step(params, opt_state, plan)
             dt = time.time() - ts
@@ -272,7 +280,9 @@ def main() -> None:
             if plan.partition is not None and plan.partition.waves:
                 part_tokens += plan.partition.info["unique_tokens"]
             history.append({"step": i, "loss": m["loss"], "nll": m["nll"],
-                            "sec": dt,
+                            "grad_norm": m["grad_norm"], "sec": dt,
+                            "waves": (0 if plan.partition is None
+                                      else len(plan.partition.waves)),
                             "oversized": plan.num_oversized,
                             "dropped": plan.dropped})
             if i % args.log_every == 0:
@@ -319,6 +329,10 @@ def main() -> None:
             with open(args.save + "/history.json", "w") as f:
                 json.dump(history, f)
             print(f"[train] saved → {args.save}")
+        if svc is not None:
+            svc.raise_errors("train")
+    return {"cfg": cfg, "args": args, "mesh": mesh, "data_axes": daxes,
+            "history": history, "first_plan": first_plan}
 
 
 if __name__ == "__main__":

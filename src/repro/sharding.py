@@ -105,7 +105,6 @@ def tp_out_proj(h: jax.Array, w: jax.Array) -> jax.Array:
             or h.shape[-1] % mesh.shape[m] != 0
             or w.shape[0] % mesh.shape[m] != 0):
         return h @ w
-    from jax.experimental.shard_map import shard_map
     daxes = _CTX.data_axes
     dsize = 1
     for a in daxes:
@@ -116,10 +115,10 @@ def tp_out_proj(h: jax.Array, w: jax.Array) -> jax.Array:
         return jax.lax.psum_scatter(hl @ wl, m, scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(local, mesh,
-                     in_specs=(P(bspec, None, m), P(m, None)),
-                     out_specs=P(bspec, m, None),
-                     check_rep=False)(h, w)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(bspec, None, m), P(m, None)),
+                         out_specs=P(bspec, m, None),
+                         check_vma=False)(h, w)
 
 
 def shard_spec(x: jax.Array, *axes) -> jax.Array:

@@ -70,13 +70,30 @@ _sds = jax.ShapeDtypeStruct
 # Persistent compilation cache
 # ---------------------------------------------------------------------------
 
-def configure_compile_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if missing) and drop the min-compile-time / min-entry-size floors —
-    the defaults skip exactly the small, fast CPU modules this repo's
-    shape buckets produce, which would leave a restarted run recompiling
-    everything.  Idempotent; call before the first compile."""
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+# the checkout root (src/repro/train/warmup.py → three levels up)
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def compile_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Where the persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (nothing overrides it), else
+    ``cache_dir``, else ``.jax_cache`` at the checkout root.  The path is
+    part of what a cache hit needs, so it is fixed — never temporary,
+    per-process or time-stamped."""
+    d = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+         or os.path.join(_CHECKOUT, ".jax_cache"))
+    return os.path.abspath(os.path.expanduser(d))
+
+
+def configure_compile_cache(cache_dir: Optional[str] = None) -> str:
+    """Point jax's persistent compilation cache at
+    :func:`compile_cache_dir` (created if missing) and drop the
+    min-compile-time / min-entry-size floors — the defaults skip exactly
+    the small, fast CPU modules this repo's shape buckets produce, which
+    would leave a restarted run recompiling everything.  Idempotent; call
+    before the first compile.  Returns the directory."""
+    cache_dir = compile_cache_dir(cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -599,6 +616,13 @@ class AOTWarmupService:
                 self.sim.commit(sigs)
         self.prewarmed += new
         return new
+
+    def raise_errors(self, who: str) -> None:
+        """A compile this service could not do is a failed run, not a log
+        line: exit non-zero naming the first failure."""
+        if self.errors:
+            raise SystemExit(f"[{who}] {len(self.errors)} warmup compile "
+                             f"errors; first: {self.errors[0]}")
 
     def stats(self) -> dict:
         s = self.cache.stats()

@@ -1,0 +1,165 @@
+"""Compile-only checks of the tree-attention kernels for a TPU v5e.
+
+Interpret mode (every other kernel test) runs the kernel bodies in
+Python and never asks the TPU compiler about block shapes, tiling or
+VMEM.  These tests compile the forward, dq and dk/dv kernels for a
+described v5e (no chip needed) at qwen1.5-0.5B attention widths — H=16,
+hd=64, S=2048, bf16 — and assert the Pallas kernel is in the compiled
+program (``tpu_custom_call``).  Layouts: packed rows, a partition gateway
+(``q_off=256`` front-concatenated ancestors, some marked kv_last = −1),
+and a sliding window.  One more compiles the whole differentiable op
+(``kernels/ops.tree_attention``, padding included) at an ancestor length
+that is not a block multiple, and another compiles it on a four-chip
+data-parallel mesh, where XLA would refuse to partition the kernel
+itself.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and the test workers all import this
+file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.tree_attention import head_major, skip_scalars
+from repro.kernels.tree_attention import tree_attention as fwd_kernel
+from repro.kernels.tree_attention_bwd import _bwd_dkv, _bwd_dq
+
+B, S, H, HD = 1, 2048, 16, 64
+BLOCK = 128
+LAYOUTS = {            # name → (q_off, window)
+    "packed": (0, None),
+    "gateway": (256, None),
+    "window": (0, 512),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        # the TPU library writes logs under /tmp unless told otherwise
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure: "cannot"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A described-device compile is written to the persistent cache but
+    cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shapes(one_chip, q_off, window):
+    Skv = q_off + S
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = [sds((B, S, H, HD), jnp.bfloat16),          # q
+            sds((B, Skv, H, HD), jnp.bfloat16),        # k
+            sds((B, Skv, H, HD), jnp.bfloat16),        # v
+            sds((B, Skv), jnp.int32)]                  # kv_last
+    if window is not None:
+        args += [sds((B, S), jnp.int32), sds((B, Skv), jnp.int32)]
+    return args, sds
+
+
+def _assert_kernel(fn, args, n_kernels=1):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= n_kernels, \
+        "the Pallas kernel is not in the compiled program"
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_forward_compiles_for_v5e(one_chip, layout):
+    q_off, window = LAYOUTS[layout]
+    args, _ = _shapes(one_chip, q_off, window)
+
+    def f(q, k, v, kv_last, pos_q=None, pos_k=None):
+        return fwd_kernel(q, k, v, kv_last, HD ** -0.5, block_q=BLOCK,
+                          block_k=BLOCK, q_off=q_off, window=window,
+                          pos_q=pos_q, pos_k=pos_k, save_residuals=True)
+    _assert_kernel(f, args)
+
+
+@pytest.mark.parametrize("kernel", ["dq", "dkv"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_backward_compiles_for_v5e(one_chip, layout, kernel):
+    q_off, window = LAYOUTS[layout]
+    body = _bwd_dq if kernel == "dq" else _bwd_dkv
+    Skv = q_off + S
+
+    def f(q, k, v, kv_last, *rest):
+        pos_q = pos_k = None
+        if window is not None:
+            pos_q, pos_k, *rest = rest
+        lse, delta, do = rest
+        skip = skip_scalars(kv_last, B, S // BLOCK, Skv // BLOCK, BLOCK,
+                            BLOCK, pos_q, pos_k, window)
+        qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
+        return body(qh, kh, vh, kl, lse, delta, do.transpose(0, 2, 1, 3),
+                    HD ** -0.5, skip, BLOCK, BLOCK, q_off, window, pq, pk,
+                    False)
+    args, sds = _shapes(one_chip, q_off, window)
+    args += [sds((B, H, 1, S), jnp.float32),           # lse
+             sds((B, H, 1, S), jnp.float32),           # delta
+             sds((B, S, H, HD), jnp.bfloat16)]         # do
+    _assert_kernel(f, args)
+
+
+def test_op_with_unaligned_ancestors_compiles_for_v5e(one_chip,
+                                                      monkeypatch):
+    """The differentiable op pads an ancestor-extended KV axis
+    (A = 200: Skv = 2248, no block multiple) up to whole blocks; forward
+    and both backward kernels compile."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    A = 200
+    args, _ = _shapes(one_chip, A, None)
+
+    def loss(q, k, v, kv_last):
+        o = ops.tree_attention(q, k, v, kv_last, HD ** -0.5, q_off=A)
+        return o.astype(jnp.float32).sum()
+    _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)), args, n_kernels=3)
+
+
+def test_op_on_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
+    """On the (4, 1) host mesh each chip runs the kernels on its own rows;
+    forward and both backward kernels compile for the 2x2 v5e host."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import sharding as sh
+    from repro.launch.mesh import host_descriptor
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    desc = host_descriptor(4)
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(desc.shape),
+                             desc.axis_names, axis_types=desc.axis_types)
+    rows = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((4, S, H, HD), jnp.bfloat16,
+                                 sharding=rows) for _ in range(3)]
+    args.append(jax.ShapeDtypeStruct((4, S), jnp.int32, sharding=rows))
+
+    def loss(q, k, v, kv_last):
+        o = ops.tree_attention(q, k, v, kv_last, HD ** -0.5)
+        return o.astype(jnp.float32).sum()
+    with sh.use_mesh(mesh):
+        _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)), args,
+                       n_kernels=3)

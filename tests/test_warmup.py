@@ -212,7 +212,7 @@ _PERSIST_SNIPPET = """
 import json, sys
 import jax, jax.numpy as jnp
 from repro.train.warmup import configure_compile_cache, compile_cache_files
-d = configure_compile_cache(sys.argv[1])
+d = configure_compile_cache()
 before = compile_cache_files(d)
 f = jax.jit(lambda x: (x @ x.T).sum() + 1.0)
 out = float(f(jnp.arange(48.0 * 16).reshape(48, 16)))
@@ -221,19 +221,21 @@ print(json.dumps({"new": compile_cache_files(d) - before, "out": out}))
 
 
 def test_persistent_cache_roundtrip(tmp_path):
-    """configure_compile_cache wires jax's persistent compilation cache:
-    a second FRESH process compiling the same computation writes zero
-    new cache modules and reproduces the same value."""
+    """configure_compile_cache wires jax's persistent compilation cache
+    to $JAX_COMPILATION_CACHE_DIR: a second FRESH process compiling the
+    same computation writes zero new cache modules and reproduces the
+    same value."""
     cache_dir = str(tmp_path / "jax-cache")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
     env.pop("XLA_FLAGS", None)
     outs = []
     for _ in range(2):
-        r = subprocess.run([sys.executable, "-c", _PERSIST_SNIPPET,
-                            cache_dir], env=env, capture_output=True,
-                           text=True, timeout=300)
+        r = subprocess.run([sys.executable, "-c", _PERSIST_SNIPPET],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
         assert r.returncode == 0, r.stderr[-2000:]
         outs.append(json.loads(r.stdout.strip().splitlines()[-1]))
     assert outs[0]["new"] > 0, "first process persisted nothing"
@@ -241,3 +243,49 @@ def test_persistent_cache_roundtrip(tmp_path):
         f"warm restart recompiled {outs[1]['new']} modules"
     assert outs[1]["out"] == outs[0]["out"]
     assert compile_cache_files(cache_dir) == outs[0]["new"]
+
+
+def test_compile_cache_dir_resolution(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR wins over any directory the code names;
+    without it the cache sits at a fixed path inside the checkout."""
+    from repro.train.warmup import compile_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert compile_cache_dir() == str(tmp_path / "env")
+    assert compile_cache_dir(str(tmp_path / "arg")) == str(tmp_path / "env")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir(str(tmp_path / "arg")) == str(tmp_path / "arg")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache_dir() == os.path.join(root, ".jax_cache")
+
+
+_FAILING_WARMUP = """
+import importlib, sys
+from repro.train import warmup
+
+def broken(self, sig):
+    self.errors.append(f"{sig}: injected failure")
+    return 0
+
+warmup.AOTWarmupService.warm_signature = broken
+importlib.import_module("repro.launch." + sys.argv[1]).main(sys.argv[2:])
+"""
+
+
+@pytest.mark.parametrize("launcher,argv", [
+    ("train", ["--arch", "qwen1.5-0.5b", "--smoke", "--steps", "1",
+               "--seq-len", "512", "--aot-warmup"]),
+    ("rl_loop", ["--arch", "qwen1.5-0.5b", "--smoke", "--steps", "1"]),
+])
+def test_launcher_fails_on_warmup_errors(tmp_path, launcher, argv):
+    """A compile the warmup service could not do is a failed run: both
+    launchers exit non-zero instead of printing the error count."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax-cache")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _FAILING_WARMUP, launcher,
+                        *argv], env=env, capture_output=True, text=True,
+                       timeout=300, cwd=root)
+    assert r.returncode != 0, r.stdout[-2000:]
+    assert "warmup compile errors" in r.stderr, r.stderr[-2000:]
