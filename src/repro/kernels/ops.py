@@ -58,17 +58,20 @@ def _fit_block(n: int, want: int) -> int:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _tree_attention(q, k, v, kv_last, pos_q, pos_k,
                     scale, window, q_off, block_q, block_k):
-    return _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
-                       block_k=block_k, q_off=q_off, window=window,
-                       pos_q=pos_q, pos_k=pos_k, interpret=not _on_tpu())
+    with jax.named_scope("tree_attn_fwd"):
+        return _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
+                           block_k=block_k, q_off=q_off, window=window,
+                           pos_q=pos_q, pos_k=pos_k,
+                           interpret=not _on_tpu())
 
 
 def _fwd(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
          block_q, block_k):
-    o, lse = _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
-                         block_k=block_k, q_off=q_off, window=window,
-                         pos_q=pos_q, pos_k=pos_k, save_residuals=True,
-                         interpret=not _on_tpu())
+    with jax.named_scope("tree_attn_fwd"):
+        o, lse = _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
+                             block_k=block_k, q_off=q_off, window=window,
+                             pos_q=pos_q, pos_k=pos_k, save_residuals=True,
+                             interpret=not _on_tpu())
     return o, (q, k, v, kv_last, pos_q, pos_k, o, lse)
 
 

@@ -278,6 +278,7 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="tree_attn_fwd",
     )(skip, *inputs)
     o = out[0].transpose(0, 2, 1, 3)
     if save_residuals:

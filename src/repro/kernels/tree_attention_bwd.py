@@ -168,6 +168,7 @@ def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), qh.dtype),
         interpret=interpret,
+        name="tree_attn_dq",
     )(skip, *inputs)
 
 
@@ -279,6 +280,7 @@ def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
             jax.ShapeDtypeStruct((B, Kh, Skv, hd), vh.dtype),
         ],
         interpret=interpret,
+        name="tree_attn_dkv",
     )(skip, *inputs)
     return out[0], out[1]
 
@@ -317,7 +319,9 @@ def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     doh = do.transpose(0, 2, 1, 3)
     args = (qh, kh, vh, kl, lse, delta, doh, scale, skip, block_q, block_k,
             q_off, window, pq, pk, interpret)
-    dq = _bwd_dq(*args)
-    dk, dv = _bwd_dkv(*args)
+    with jax.named_scope("tree_attn_dq"):
+        dq = _bwd_dq(*args)
+    with jax.named_scope("tree_attn_dkv"):
+        dk, dv = _bwd_dkv(*args)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))

@@ -489,20 +489,24 @@ def partition_loss(cfg: ModelConfig, params: dict, batch: dict, gw_in,
     w = jnp.where(prev >= 0, batch["weight"], 0.0)
     h_prev = jnp.take_along_axis(hidden, jnp.maximum(prev, 0)[..., None],
                                  axis=1)
-    logits = shard_logits(logits_from_hidden(params["embed"], head, h_prev))
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    lab = jnp.take_along_axis(logits, batch["tokens"][..., None], axis=-1)
-    nll = lse - lab[..., 0]
-    loss = jnp.sum(w * nll)
+    with jax.named_scope("lm_head"):
+        logits = shard_logits(logits_from_hidden(params["embed"], head,
+                                                 h_prev))
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        lab = jnp.take_along_axis(logits, batch["tokens"][..., None],
+                                  axis=-1)
+        nll = lse - lab[..., 0]
+        loss = jnp.sum(w * nll)
 
     if "extra_pos" in batch and batch["extra_pos"].shape[-1] > 0:
         h_b = jnp.take_along_axis(hidden, batch["extra_pos"][..., None],
                                   axis=1)
-        lg = logits_from_hidden(params["embed"], head, h_b)
-        lse_b = jax.nn.logsumexp(lg, axis=-1)
-        lab_b = jnp.take_along_axis(lg, batch["extra_label"][..., None],
-                                    axis=-1)[..., 0]
-        loss = loss + jnp.sum(batch["extra_weight"] * (lse_b - lab_b))
+        with jax.named_scope("lm_head"):
+            lg = logits_from_hidden(params["embed"], head, h_b)
+            lse_b = jax.nn.logsumexp(lg, axis=-1)
+            lab_b = jnp.take_along_axis(lg, batch["extra_label"][..., None],
+                                        axis=-1)[..., 0]
+            loss = loss + jnp.sum(batch["extra_weight"] * (lse_b - lab_b))
 
     metrics = {"weight_sum": jnp.sum(w)
                + (jnp.sum(batch["extra_weight"])
@@ -526,12 +530,13 @@ def loss_and_metrics(cfg: ModelConfig, params: dict, batch: dict,
     h_prev = jnp.take_along_axis(hidden, jnp.maximum(prev, 0)[..., None],
                                  axis=1)
     head = params.get("lm_head")
-    logits = logits_from_hidden(params["embed"], head, h_prev)
-    logits = shard_logits(logits)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    lab = jnp.take_along_axis(logits, batch["tokens"][..., None].astype(
-        jnp.int32), axis=-1)[..., 0]
-    nll = lse - lab
+    with jax.named_scope("lm_head"):
+        logits = logits_from_hidden(params["embed"], head, h_prev)
+        logits = shard_logits(logits)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        lab = jnp.take_along_axis(logits, batch["tokens"][..., None].astype(
+            jnp.int32), axis=-1)[..., 0]
+        nll = lse - lab
     denom = jnp.asarray(batch.get("num_trees", 1), jnp.float32)
     loss = jnp.sum(w * nll) / denom
     total = loss + aux

@@ -37,6 +37,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.gateway import (PartitionPlan, _cut_caps_view,
@@ -426,13 +427,14 @@ class TreeTrainEngine:
         dict (loss, nll, grad_norm, lr, …) pulled in a single transfer."""
         assert self.opt_cfg is not None, \
             "TreeTrainEngine.step needs an OptimizerConfig"
-        grads, scal = self.accumulate(params, plan)
-        upd = jitted_update(self.opt_cfg, self.donate)
-        params, opt_state, om = self._run("update", ("update",), upd,
-                                          (params, grads, opt_state))
-        vec = jnp.concatenate(
-            [scal, jnp.stack([om["grad_norm"], om["lr"]]
-                             ).astype(jnp.float32)])
+        with TraceAnnotation("repro.engine.dispatch"):
+            grads, scal = self.accumulate(params, plan)
+            upd = jitted_update(self.opt_cfg, self.donate)
+            params, opt_state, om = self._run("update", ("update",), upd,
+                                              (params, grads, opt_state))
+            vec = jnp.concatenate(
+                [scal, jnp.stack([om["grad_norm"], om["lr"]]
+                                 ).astype(jnp.float32)])
         host = self._sync(vec)
         metrics = dict(zip(self.METRIC_NAMES, host.tolist()))
         metrics["nll"] = metrics["nll_sum"] / max(metrics["weight_sum"],
@@ -467,4 +469,5 @@ class TreeTrainEngine:
         """THE host sync: every device→host read the engine performs
         funnels through here so the count is auditable."""
         self.host_syncs += 1
-        return np.asarray(vec)
+        with TraceAnnotation("repro.engine.sync"):
+            return np.asarray(vec)

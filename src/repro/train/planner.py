@@ -60,6 +60,7 @@ from itertools import islice
 from typing import Any, Callable, Iterable, Iterator, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.forest import graft_trees
@@ -753,7 +754,8 @@ class PlanPipeline:
                     self.schedule_s += dt
             t0 = time.perf_counter()
             try:
-                out = ("ok", self._build(item))
+                with TraceAnnotation("repro.plan.build"):
+                    out = ("ok", self._build(item))
             except BaseException as e:
                 out = ("err", e)
             dt = time.perf_counter() - t0
@@ -773,14 +775,16 @@ class PlanPipeline:
     def __iter__(self) -> Iterator:
         if self._workers == 0:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    item = self._source.__next__()
-                except StopIteration:
-                    return
-                t1 = time.perf_counter()
-                plan = self._build(item)
-                t2 = time.perf_counter()
+                with TraceAnnotation("repro.plan.wait"):
+                    t0 = time.perf_counter()
+                    try:
+                        item = self._source.__next__()
+                    except StopIteration:
+                        return
+                    t1 = time.perf_counter()
+                    with TraceAnnotation("repro.plan.build"):
+                        plan = self._build(item)
+                    t2 = time.perf_counter()
                 self.schedule_s += t1 - t0
                 self.build_s += t2 - t1
                 self.exposed_s += t2 - t0
@@ -788,18 +792,19 @@ class PlanPipeline:
                 yield plan
         try:
             while True:
-                t0 = time.perf_counter()
-                with self._cv:
-                    while (self._next_out not in self._results
-                           and not (self._exhausted
-                                    and self._next_pull <= self._next_out)):
-                        self._cv.wait()
-                    self.exposed_s += time.perf_counter() - t0
-                    if self._next_out not in self._results:
-                        return                      # stream exhausted
-                    kind, val = self._results.pop(self._next_out)
-                    self._next_out += 1
-                    self._cv.notify_all()
+                with TraceAnnotation("repro.plan.wait"):
+                    t0 = time.perf_counter()
+                    with self._cv:
+                        while (self._next_out not in self._results
+                               and not (self._exhausted and self._next_pull
+                                        <= self._next_out)):
+                            self._cv.wait()
+                        self.exposed_s += time.perf_counter() - t0
+                        if self._next_out not in self._results:
+                            return                  # stream exhausted
+                        kind, val = self._results.pop(self._next_out)
+                        self._next_out += 1
+                        self._cv.notify_all()
                 if kind == "err":
                     raise val
                 yield val

@@ -14,7 +14,7 @@ OptimizerConfig below.
 """
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import lru_cache
 
 import jax
 
@@ -56,8 +56,11 @@ def jitted_update(opt_cfg: OptimizerConfig, donate: bool = False):
 
     Signature of the returned fn: ``(params, grads, opt_state) →
     (new_params, new_opt_state, metrics)``."""
-    fn = partial(adamw_update, opt_cfg)
-    return jax.jit(fn, donate_argnums=(0, 1, 2) if donate else ())
+    def update(params, grads, opt_state):
+        with jax.named_scope("adamw"):
+            return adamw_update(opt_cfg, params, grads, opt_state)
+
+    return jax.jit(update, donate_argnums=(0, 1, 2) if donate else ())
 
 
 def apply_grads(opt_cfg: OptimizerConfig, params, opt_state, grads):

@@ -406,7 +406,6 @@ class AOTWarmupService:
                                ctx.seq_parallel)
         self.errors: list[str] = []
         self.prewarmed = 0
-        self.background_s = 0.0
         self._threads: list[threading.Thread] = []
         self._stop = threading.Event()
 
@@ -500,11 +499,9 @@ class AOTWarmupService:
     def warm_all(self) -> int:
         """Synchronously compile the update + the whole (budgeted)
         universe; returns the number of new executables."""
-        t0 = time.perf_counter()
         new = self.warm_signature(("update",))
         for sig in self._budgeted(self.signature_list()):
             new += self.warm_signature(sig)
-        self.background_s += time.perf_counter() - t0
         return new
 
     def start(self, threads: int = 1) -> "AOTWarmupService":
@@ -516,14 +513,12 @@ class AOTWarmupService:
         lock = threading.Lock()
 
         def run():
-            t0 = time.perf_counter()
             while not self._stop.is_set():
                 with lock:
                     sig = next(it, None)
                 if sig is None:
                     break
                 self.warm_signature(sig)
-            self.background_s += time.perf_counter() - t0
 
         self._threads = [threading.Thread(target=run, daemon=True,
                                           name=f"aot-warmup-{i}")
@@ -626,8 +621,7 @@ class AOTWarmupService:
 
     def stats(self) -> dict:
         s = self.cache.stats()
-        s.update(prewarmed=self.prewarmed, errors=len(self.errors),
-                 background_s=self.background_s)
+        s.update(prewarmed=self.prewarmed, errors=len(self.errors))
         return s
 
 

@@ -95,13 +95,13 @@ def _check_window_invariants(cfg, lc, pc, window):
     gen_tokens = sum(t.num_unique_tokens() for b in window for t in b)
     gen_trees = sum(len(b) for b in window)
     got_tokens = dropped = seen_trees = 0
+    R = pc.num_replicas
     for ps in steps:
         sb = ps.step_batch()
         dropped += sb.dropped
         seen_trees += sb.num_trees
         if sb.tb is not None:
             B = sb.tb.tokens.shape[0]
-            R = pc.num_replicas
             # replica-balanced rows: divisible count, non-empty-row
             # imbalance ≤ 1 across contiguous shards
             assert B % max(R, 1) == 0
