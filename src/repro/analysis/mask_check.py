@@ -1,11 +1,12 @@
 """Kernel mask-soundness checker: prove ``block_live`` never skips work.
 
-The Pallas tree-attention kernels (fwd and bwd) skip a (q-block,
-kv-block) pair when the scalar-prefetch predicate
-``kernels/tree_attention.block_live`` says no visible (query, key) pair
-can exist inside it.  An unsound predicate silently zeroes attention —
-gradients stay finite and training "works", just wrong.  This pass
-verifies soundness *statically*, with no kernel launch:
+The Pallas tree-attention kernels (fwd and bwd) run a (q-block,
+kv-block) pair only when ``kernels/tree_attention.block_live`` (through
+``block_live_mask`` and the kernels' ``live_table``) says a visible
+(query, key) pair can exist inside it.  An unsound predicate silently
+zeroes attention — gradients stay finite and training "works", just
+wrong.  This pass verifies soundness *statically*, with no kernel
+launch:
 
   boundary sweep    for every bucketed (block shape, q_off, window)
                     combination the configs can reach, enumerate the
@@ -26,9 +27,9 @@ verifies soundness *statically*, with no kernel launch:
                     ``block_live_mask`` ⊇ the dense per-pair visibility,
                     reporting the proven block-skip rate.
 
-Also pins the fwd/bwd kernels to the SAME predicate object — a fork of
-the skip logic between them is exactly the drift this file exists to
-prevent.
+Also pins the fwd/bwd kernels to the SAME tables, built in one call
+from one mask — a fork of the skip logic between them is exactly the
+drift this file exists to prevent.
 """
 from __future__ import annotations
 
@@ -165,16 +166,20 @@ def check_predicate(live_fn: Callable = block_live, *,
 
 
 def check_bwd_shares_predicate() -> list:
-    """The backward kernels must use THE SAME predicate object — proven
-    by identity, so the skip logic cannot fork."""
+    """The backward kernels must walk THE SAME live blocks as the forward
+    — the op builds all three kernels' tables in one call, from one mask
+    (``tree_attention.live_tables``); proven by identity, so the skip
+    logic cannot fork."""
     from repro.analysis.jaxpr_audit import Finding
-    from repro.kernels import tree_attention_bwd as bwd
+    from repro.kernels import ops
+    from repro.kernels import tree_attention as fwd
     out = []
-    if getattr(bwd, "block_live", None) is not block_live:
+    if getattr(ops, "live_tables", None) is not fwd.live_tables:
         out.append(Finding(
-            "kernels.tree_attention_bwd", "mask",
-            "backward kernel does not share tree_attention.block_live — "
-            "fwd/bwd skip predicates can drift apart"))
+            "kernels.ops", "mask",
+            "the op does not build its tables with "
+            "tree_attention.live_tables — fwd/bwd skip predicates can "
+            "drift apart"))
     return out
 
 

@@ -4,8 +4,11 @@ Dispatches to the Pallas TPU kernels on TPU backends and to interpret mode
 on CPU (kernel body executed in Python — bit-level semantics identical).
 The custom_vjp is fully fused: the forward saves only O(S) logsumexp
 residuals and the backward runs the flash-style recomputation kernels in
-kernels/tree_attention_bwd.py (dq, dk, dv) with the same visibility
-predicate and block-skip rule as the forward.  The dense jnp reference
+kernels/tree_attention_bwd.py (dq, dk, dv) over the same live blocks as
+the forward: the op builds the three kernels' live-block tables once per
+call, from one mask (``live_tables``), and names them ``LIVE_TABLES``
+so that a checkpointed layer stack keeps them instead of rebuilding them
+in its backward.  The dense jnp reference
 (kernels/ref.py) is no longer on the training path — it survives purely
 as the test oracle.
 
@@ -29,11 +32,19 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding as sh
+from repro.kernels.tree_attention import block_live_mask, live_tables
 from repro.kernels.tree_attention import tree_attention as _pallas_fwd
 from repro.kernels.tree_attention_bwd import tree_attention_bwd as _pallas_bwd
+
+
+# the name of the kernels' live-block tables in a checkpoint policy
+LIVE_TABLES = "tree_attn_live_tables"
+BLOCK = 128                      # the op's default q and kv block
 
 
 def _on_tpu() -> bool:
@@ -55,36 +66,70 @@ def _fit_block(n: int, want: int) -> int:
     return min(want, n)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _tree_attention(q, k, v, kv_last, pos_q, pos_k,
+def block_counts(kv_last, pos_ids=None,
+                 window: Optional[int] = None) -> tuple[int, int]:
+    """(live, dense): the (q-block, kv-block) pairs that ``tree_attention``
+    computes for host rows kv_last [B, S] (numpy), and all pairs of its
+    grid, with the op's default blocks, fit and padding.  Per head and
+    per call; the planner's ``attn_blocks_live`` / ``attn_blocks_dense``."""
+    kv_last = np.asarray(kv_last)
+    S = kv_last.shape[-1]
+    bq = _fit_block(S, BLOCK)
+    pad = -S % bq
+    kv_last = np.pad(kv_last, ((0, 0), (0, pad)), constant_values=-1)
+    if window is not None:
+        pos_ids = np.pad(np.asarray(pos_ids), ((0, 0), (0, pad)))
+    live = block_live_mask(kv_last, S + pad, bq, bq, pos_q=pos_ids,
+                           pos_k=pos_ids, window=window)
+    return int(live.sum()), int(live.size)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+def _tree_attention(q, k, v, kv_last, pos_q, pos_k, tables,
                     scale, window, q_off, block_q, block_k):
     with jax.named_scope("tree_attn_fwd"):
         return _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
                            block_k=block_k, q_off=q_off, window=window,
-                           pos_q=pos_q, pos_k=pos_k,
+                           pos_q=pos_q, pos_k=pos_k, table=tables[0],
                            interpret=not _on_tpu())
 
 
-def _fwd(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
+def _fwd(q, k, v, kv_last, pos_q, pos_k, tables, scale, window, q_off,
          block_q, block_k):
     with jax.named_scope("tree_attn_fwd"):
         o, lse = _pallas_fwd(q, k, v, kv_last, scale, block_q=block_q,
                              block_k=block_k, q_off=q_off, window=window,
                              pos_q=pos_q, pos_k=pos_k, save_residuals=True,
-                             interpret=not _on_tpu())
-    return o, (q, k, v, kv_last, pos_q, pos_k, o, lse)
+                             table=tables[0], interpret=not _on_tpu())
+    return o, (q, k, v, kv_last, pos_q, pos_k, tables, o, lse)
 
 
 def _bwd(scale, window, q_off, block_q, block_k, res, do):
-    q, k, v, kv_last, pos_q, pos_k, o, lse = res
+    q, k, v, kv_last, pos_q, pos_k, tables, o, lse = res
     dq, dk, dv = _pallas_bwd(q, k, v, kv_last, o, lse, do, scale,
                              block_q=block_q, block_k=block_k,
                              q_off=q_off, window=window, pos_q=pos_q,
-                             pos_k=pos_k, interpret=not _on_tpu())
-    return dq, dk, dv, None, None, None
+                             pos_k=pos_k, tables=tables,
+                             interpret=not _on_tpu())
+    return dq, dk, dv, None, None, None, None
 
 
 _tree_attention.defvjp(_fwd, _bwd)
+
+
+def _with_tables(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
+                 block_q, block_k):
+    """``_tree_attention`` with the kernels' live-block tables, built from
+    kv_last and named ``LIVE_TABLES``.  A layer stack's checkpoint policy
+    saves them, so its backward reads them where it would rebuild them
+    in every layer; kv_last is the same in every layer of a step."""
+    with jax.named_scope("tree_attn_fwd"):
+        tables = live_tables(kv_last, q.shape[1], block_q, block_k,
+                             q_off=q_off, pos_q=pos_q, pos_k=pos_k,
+                             window=window)
+    tables = jax.tree.map(lambda t: checkpoint_name(t, LIVE_TABLES), tables)
+    return _tree_attention(q, k, v, kv_last, pos_q, pos_k, tables, scale,
+                           window, q_off, block_q, block_k)
 
 
 def _partitioned(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
@@ -96,8 +141,8 @@ def _partitioned(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
     are heads (a kv head's query group stays on its device)."""
     mesh = sh.current_mesh()
     if mesh is None or mesh.size == 1:
-        return _tree_attention(q, k, v, kv_last, pos_q, pos_k, scale,
-                               window, q_off, block_q, block_k)
+        return _with_tables(q, k, v, kv_last, pos_q, pos_k, scale, window,
+                            q_off, block_q, block_k)
     daxes, m = sh._CTX.data_axes, sh._CTX.model_axis
     dsize = 1
     for a in daxes:
@@ -111,8 +156,8 @@ def _partitioned(q, k, v, kv_last, pos_q, pos_k, scale, window, q_off,
 
     def local(q, k, v, kv_last, *pos):
         pq, pk = pos if windowed else (None, None)
-        return _tree_attention(q, k, v, kv_last, pq, pk, scale, window,
-                               q_off, block_q, block_k)
+        return _with_tables(q, k, v, kv_last, pq, pk, scale, window,
+                            q_off, block_q, block_k)
 
     pos = (pos_q, pos_k) if windowed else ()
     # check_vma=False: pallas_call output shapes carry no varying-axes
@@ -133,7 +178,7 @@ def _pad_axis1(x, pad, value=0):
 
 
 def tree_attention(q, k, v, kv_last, scale: float,
-                   block_q: int = 128, block_k: int = 128, *,
+                   block_q: int = BLOCK, block_k: int = BLOCK, *,
                    q_off: int = 0, window: Optional[int] = None,
                    pos_q: Optional[jax.Array] = None,
                    pos_k: Optional[jax.Array] = None):
@@ -171,7 +216,7 @@ def prefill_attention(q, k, v, scale: float, *,
                       window: Optional[int] = None,
                       pos_q: Optional[jax.Array] = None,
                       ctx_pos: Optional[jax.Array] = None,
-                      block_q: int = 128, block_k: int = 128):
+                      block_q: int = BLOCK, block_k: int = BLOCK):
     """Shared-prefix prefill through the fused tree kernel.
 
     The decode-session prefill shape: S new chain tokens (q: [B,S,H,hd],

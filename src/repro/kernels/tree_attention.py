@@ -4,20 +4,23 @@ The paper implements its tree mask as a FlashAttention-V3 + FlashMask GPU
 kernel (App. A.1).  The TPU-native equivalent built here:
 
   - visibility is one int per key: visible(i,j) ⇔ j ≤ i ∧ kv_last[j] ≥ i;
-  - grid (batch, q_head, q_blocks, kv_blocks); the innermost dim is
-    sequential on TPU, so online-softmax accumulators live in VMEM scratch
-    across kv steps;
   - MXU-aligned blocks (default 128×128), fp32 accumulation;
-  - **block skipping**: a kv block is skipped when it is entirely
-    anti-causal (kv_start > q_end), entirely invisible
+  - **live blocks only**: a (q-block, kv-block) pair is dead when it is
+    entirely anti-causal (kv_start > q_end), entirely invisible
     (max_j kv_last[j] < q_start — every key's subtree ends before this
     query block), or — with sliding-window attention — entirely out of
-    window (min_i pos_q[i] − max_j pos_k[j] ≥ window).  Per-block extrema
-    are precomputed XLA-side and prefetched as scalars, so the predicate
-    is resolved before any MXU work.  This is the FlashMask block-sparsity
-    analogue; skipped blocks still have their DMA issued by the pipeline
-    (removing it needs a data-dependent grid — logged as a §Perf follow-up
-    in EXPERIMENTS.md).
+    window (min_i pos_q[i] − max_j pos_k[j] ≥ window).  ``block_live`` is
+    that predicate.  XLA-side, ``live_table`` packs the live pairs of
+    every row into one int32 array, prefetched to SMEM, and the grid is
+    (q_head, n) with ``n`` the table's live count, a dynamic grid bound:
+    the kernel runs a step, and its pipeline issues a DMA, only for a
+    live pair.  This is the FlashMask block-sparsity analogue;
+  - the table's entries run in (batch, q-block, kv-block) order, kv
+    blocks increasing, so the online-softmax accumulators live in VMEM
+    scratch across one q block's consecutive entries; each entry's
+    first/last flags open and close them.  A q block with no live kv
+    block (padding, an empty row) gets one write-only entry, so every
+    output block is written (zeros, ``NEG_INF`` lse);
   - **partition gateways** (paper §3.3): queries may attend a KV sequence
     longer than themselves — ``q_off`` ancestor keys are front-concatenated
     (k/v: [B, q_off + S, ...]).  Query i's global DFS index is
@@ -33,6 +36,11 @@ kernel (App. A.1).  The TPU-native equivalent built here:
     the O(S) statistic the fused backward (tree_attention_bwd.py) needs to
     regenerate softmax probabilities without the O(S²) matrix.
 
+SMEM bound: a row's output block gets at most as many entries as it has
+reduction blocks, so the table holds at most B·nq·nk int32 entries —
+4·B·nq·nk bytes of the scalar memory (1 MiB on a v5e), 2 KiB at B=2,
+S=2048 and 128×128 blocks, 256 KiB at B=1, S=32768.
+
 GQA: q head h reads kv head h // (H/Kh) via the BlockSpec index map.
 Operands are re-laid out head-major (``head_major``) so that every
 block's last two dimensions are (8, 128)-aligned or whole, as the TPU
@@ -41,38 +49,26 @@ kernels/ref.py; ``tests/test_tpu_compile.py`` compiles it for a v5e.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-
-def block_kmax_flat(kv_last, B: int, nk: int, block_k: int):
-    """Per-(batch, kv-block) max of kv_last, flattened to 1-D for scalar
-    prefetch; indexed with b*nk + ki inside the kernels.  Shared by the
-    forward and both backward kernels so the skip inputs cannot drift."""
-    return kv_last.reshape(B, nk, block_k).max(-1).reshape(B * nk)
-
-
-def skip_scalars(kv_last, B: int, nq: int, nk: int, block_q: int,
-                 block_k: int, pos_q=None, pos_k=None, window=None):
-    """The flat int32 scalar-prefetch array driving ``block_live``.
-
-    Layout: ``[kmax (B·nk)]`` and, when windowed, additionally
-    ``[kpos_max (B·nk), qpos_min (B·nq)]`` — indexed with the static
-    offsets B·nk and 2·B·nk inside the kernels.  One array (not three)
-    keeps ``num_scalar_prefetch=1`` and the index-map signatures stable."""
-    kmax = block_kmax_flat(kv_last, B, nk, block_k)
-    if window is None:
-        return kmax
-    kpmax = pos_k.astype(jnp.int32).reshape(B, nk, block_k).max(-1)
-    qpmin = pos_q.astype(jnp.int32).reshape(B, nq, block_q).min(-1)
-    return jnp.concatenate(
-        [kmax, kpmax.reshape(B * nk), qpmin.reshape(B * nq)])
+# One live-table entry, packed into an int32: the reduction block
+# (kv block in the forward and dq, q block in dk/dv), the output block,
+# the batch row, and three flags.
+_INNER_BITS = _OUTER_BITS = 10
+_ROW_BITS = 8
+ENTRY_LIVE = 1 << 28           # compute this pair (else write-only)
+ENTRY_FIRST = 1 << 29          # first entry of its output block
+ENTRY_LAST = 1 << 30           # last entry of its output block
 
 
 def block_live(q_start, q_end, kv_start, block_max,
@@ -82,7 +78,7 @@ def block_live(q_start, q_end, kv_start, block_max,
     entirely invisible (block_max = max_j kv_last[j] < q_start), or —
     windowed — entirely out of window (min_i pos_q − max_j pos_k ≥ window).
     q_start/q_end are *global* query indices (ancestor offset applied).
-    Works on traced kernel scalars and on numpy arrays alike."""
+    Works on numpy and jax arrays alike."""
     live = (kv_start <= q_end) & (block_max >= q_start)
     if window is not None:
         live = live & ((qp_min - kp_max) < window)
@@ -92,24 +88,90 @@ def block_live(q_start, q_end, kv_start, block_max,
 def block_live_mask(kv_last, S: int, block_q: int, block_k: int,
                     *, q_off: int = 0, pos_q=None, pos_k=None,
                     window: Optional[int] = None):
-    """[nq, nk] bool per batch row: which (q-block, kv-block) pairs the
-    kernel actually computes.  ``S`` is the query length; the kv length is
-    ``kv_last``'s (= q_off + S for gateway layouts).  Used by benchmarks
-    to report block sparsity."""
-    import numpy as np
-    kv_last = np.asarray(kv_last)
-    Skv = kv_last.shape[-1]
+    """[..., nq, nk] bool: which (q-block, kv-block) pairs the kernels
+    compute, for kv_last [..., Skv] (one row or a batch of rows).  ``S``
+    is the query length; the kv length is ``kv_last``'s (= q_off + S for
+    gateway layouts).  numpy in, numpy out (the planner's counters and
+    the mask checks); a jax array in builds the kernels' live tables."""
+    xp = jnp if isinstance(kv_last, jax.Array) else np
+    kv_last = xp.asarray(kv_last)
+    lead, Skv = kv_last.shape[:-1], kv_last.shape[-1]
     nq, nk = S // block_q, Skv // block_k
-    kmax = kv_last.reshape(nk, block_k).max(-1)
-    qi = np.arange(nq)[:, None]
-    ki = np.arange(nk)[None, :]
+    kmax = kv_last.reshape(*lead, nk, block_k).max(-1)[..., None, :]
+    qi = xp.arange(nq)[:, None]
+    ki = xp.arange(nk)[None, :]
     qpmin = kpmax = None
     if window is not None:
-        qpmin = np.asarray(pos_q).reshape(nq, block_q).min(-1)[:, None]
-        kpmax = np.asarray(pos_k).reshape(nk, block_k).max(-1)[None, :]
+        qpmin = xp.asarray(pos_q).reshape(*lead, nq, block_q).min(-1)
+        kpmax = xp.asarray(pos_k).reshape(*lead, nk, block_k).max(-1)
+        qpmin, kpmax = qpmin[..., :, None], kpmax[..., None, :]
     return block_live(q_off + qi * block_q,
                       q_off + qi * block_q + block_q - 1,
-                      ki * block_k, kmax[None, :], qpmin, kpmax, window)
+                      ki * block_k, kmax, qpmin, kpmax, window)
+
+
+def live_table(live):
+    """Pack a live mask [B, X, Y] (X output blocks, Y reduction blocks
+    each) into the kernels' entry table, in (b, x, y) order.
+
+    Returns ``(table, n)``: int32 [B·X·Y] and the int32 live count ``n``.
+    Entry e < n holds ``y | x << 10 | b << 20`` and the flags
+    ``ENTRY_LIVE`` (a live pair), ``ENTRY_FIRST`` / ``ENTRY_LAST`` (first
+    and last entry of its (b, x) output block).  An output block with no
+    live pair gets one entry at y = 0 with both flags and no
+    ``ENTRY_LIVE``, so it is still written.  A (b, x) run has at most Y
+    entries, so n ≤ B·X·Y; the grid never reads the zeros beyond n."""
+    B, X, Y = live.shape
+    assert B <= 1 << _ROW_BITS and max(X, Y) <= 1 << _INNER_BITS, \
+        (B, X, Y)
+    y = jnp.arange(Y, dtype=jnp.int32)
+    emit = live | ((y == 0) & ~live.any(-1, keepdims=True))
+    run = jnp.cumsum(emit, -1, dtype=jnp.int32)
+    code = (y + (jnp.arange(X, dtype=jnp.int32)[:, None] << _INNER_BITS)
+            + (jnp.arange(B, dtype=jnp.int32)[:, None, None]
+               << (_INNER_BITS + _OUTER_BITS))
+            + jnp.where(live, ENTRY_LIVE, 0)
+            + jnp.where(emit & (run == 1), ENTRY_FIRST, 0)
+            + jnp.where(emit & (run == run[..., -1:]), ENTRY_LAST, 0))
+    emit, code = emit.reshape(-1), code.reshape(-1)
+    L = emit.shape[0]
+    pos = jnp.cumsum(emit, dtype=jnp.int32) - 1
+    n = pos[-1] + 1
+    table = jnp.zeros((L,), jnp.int32).at[jnp.where(emit, pos, L)].set(
+        code, mode="drop", unique_indices=True)
+    return table, n
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3),
+                   static_argnames=("q_off", "window"))
+def live_tables(kv_last, S: int, block_q: int, block_k: int, *,
+                q_off: int = 0, pos_q=None, pos_k=None,
+                window: Optional[int] = None):
+    """The three kernels' tables from one ``block_live_mask`` of kv_last
+    [B, Skv]: ``(fwd, dkv)``, each a ``live_table`` pair.  ``fwd`` is in
+    (b, q-block, kv-block) order, for the forward and dq; ``dkv`` in
+    (b, kv-block, q-block) order.  Jitted, so a program that calls it
+    again with the same shapes reuses one trace."""
+    live = block_live_mask(kv_last.astype(jnp.int32), S, block_q, block_k,
+                           q_off=q_off, pos_q=pos_q, pos_k=pos_k,
+                           window=window)
+    return live_table(live), live_table(live.transpose(0, 2, 1))
+
+
+def entry_blocks(code):
+    """(b, x, y) of a table entry: batch row, output block, reduction
+    block.  Scalar integer ops, for index maps and kernel bodies; lax,
+    not jnp, since every index map of every kernel call traces them."""
+    mask = (1 << _INNER_BITS) - 1
+    shr, band = lax.shift_right_logical, lax.bitwise_and
+    return (band(shr(code, _INNER_BITS + _OUTER_BITS), (1 << _ROW_BITS) - 1),
+            band(shr(code, _INNER_BITS), mask), band(code, mask))
+
+
+def entry_flags(code):
+    """(live, first, last) of a table entry, as scalar booleans."""
+    return tuple(lax.bitwise_and(code, f) != 0
+                 for f in (ENTRY_LIVE, ENTRY_FIRST, ENTRY_LAST))
 
 
 def head_major(q, k, v, kv_last, pos_q=None, pos_k=None):
@@ -124,6 +186,9 @@ def head_major(q, k, v, kv_last, pos_q=None, pos_k=None):
             v.transpose(0, 2, 1, 3), *ints)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_q", "block_k", "q_off", "window", "save_residuals",
+    "interpret"))
 def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    kv_last: jax.Array, scale: float, *,
                    block_q: int = 128, block_k: int = 128,
@@ -131,9 +196,10 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    pos_q: Optional[jax.Array] = None,
                    pos_k: Optional[jax.Array] = None,
                    save_residuals: bool = False,
-                   interpret: bool = False):
+                   interpret: bool = False, table):
     """q: [B,S,H,hd]; k/v: [B,Skv,Kh,hd]; kv_last: [B,Skv] int32
-    → [B,S,H,hd].
+    → [B,S,H,hd].  ``table``: the ``fwd`` pair of ``live_tables`` for
+    the same kv_last, blocks and layout.
 
     ``q_off``: static ancestor offset — query i has global DFS index
     q_off + i (Skv ≥ q_off + S; any key beyond that is padding the caller
@@ -142,6 +208,10 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     With ``save_residuals`` returns ``(o, lse)`` where lse is [B,H,1,S]
     f32 (one row of per-query statistics per head).
+
+    Jitted, like the backward: the programs of a run (packed steps,
+    partition waves, their remat recomputations) call the kernels with
+    few distinct shapes, and each shape is traced once.
     """
     B, S, H, hd = q.shape
     Skv, Kh = k.shape[1], k.shape[2]
@@ -154,13 +224,10 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     windowed = window is not None
     if windowed:
         assert pos_q is not None and pos_k is not None
-    nq, nk = S // block_q, Skv // block_k
-    kv_last = kv_last.astype(jnp.int32)
-    skip = skip_scalars(kv_last, B, nq, nk, block_q, block_k,
-                        pos_q, pos_k, window)
+    table, n = table
     qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
 
-    def kernel(skip_ref, *refs):
+    def kernel(tab_ref, *refs):
         q_ref, k_ref, v_ref, kl_ref = refs[:4]
         rest = refs[4:]
         if windowed:
@@ -171,28 +238,17 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             lse_ref, m_scr, l_scr, acc_scr = rest[1:]
         else:
             m_scr, l_scr, acc_scr = rest[1:]
-        b = pl.program_id(0)
-        qi = pl.program_id(2)
-        ki = pl.program_id(3)
-        num_kv = pl.num_programs(3)
+        code = tab_ref[pl.program_id(1)]
+        _, qi, ki = entry_blocks(code)
+        live, first, last = entry_flags(code)
         q_start = q_off + qi * block_q          # global DFS index
-        q_end = q_start + block_q - 1
         kv_start = ki * block_k
 
-        @pl.when(ki == 0)
+        @pl.when(first)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
-
-        if windowed:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki],
-                              skip_ref[2 * B * nk + b * nq + qi],
-                              skip_ref[B * nk + b * nk + ki], window)
-        else:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki])
 
         @pl.when(live)
         def _compute():
@@ -222,7 +278,7 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 preferred_element_type=jnp.float32)
             m_scr[...] = m_new
 
-        @pl.when(ki == num_kv - 1)
+        @pl.when(last)
         def _finalize():
             l = l_scr[...]
             o = acc_scr[...] / jnp.maximum(l, 1e-37)
@@ -234,40 +290,44 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                 NEG_INF)
                 lse_ref[...] = lse.reshape(1, block_q)
 
+    def at(fn):
+        """An index map over grid (h, e) from (b, h, qi, ki)."""
+        return lambda h, e, tab: fn(h, *entry_blocks(tab[e]))
+
     in_specs = [
         pl.BlockSpec((None, None, block_q, hd),
-                     lambda b, h, qi, ki, skip: (b, h, qi, 0)),
+                     at(lambda h, b, qi, ki: (b, h, qi, 0))),
         pl.BlockSpec((None, None, block_k, hd),
-                     lambda b, h, qi, ki, skip: (b, h // G, ki, 0)),
+                     at(lambda h, b, qi, ki: (b, h // G, ki, 0))),
         pl.BlockSpec((None, None, block_k, hd),
-                     lambda b, h, qi, ki, skip: (b, h // G, ki, 0)),
+                     at(lambda h, b, qi, ki: (b, h // G, ki, 0))),
         pl.BlockSpec((None, 1, block_k),
-                     lambda b, h, qi, ki, skip: (b, 0, ki)),
+                     at(lambda h, b, qi, ki: (b, 0, ki))),
     ]
     inputs = [qh, kh, vh, kl]
     if windowed:
         in_specs += [
             pl.BlockSpec((None, 1, block_q),
-                         lambda b, h, qi, ki, skip: (b, 0, qi)),
+                         at(lambda h, b, qi, ki: (b, 0, qi))),
             pl.BlockSpec((None, 1, block_k),
-                         lambda b, h, qi, ki, skip: (b, 0, ki)),
+                         at(lambda h, b, qi, ki: (b, 0, ki))),
         ]
         inputs += [pq, pk]
 
     out_shape = [jax.ShapeDtypeStruct((B, H, S, hd), q.dtype)]
     out_specs = [pl.BlockSpec((None, None, block_q, hd),
-                              lambda b, h, qi, ki, skip: (b, h, qi, 0))]
+                              at(lambda h, b, qi, ki: (b, h, qi, 0)))]
     if save_residuals:
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32))
         out_specs.append(pl.BlockSpec(
             (None, None, 1, block_q),
-            lambda b, h, qi, ki, skip: (b, h, 0, qi)))
+            at(lambda h, b, qi, ki: (b, h, 0, qi))))
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
+            grid=(H, n),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
@@ -279,7 +339,7 @@ def tree_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=out_shape,
         interpret=interpret,
         name="tree_attn_fwd",
-    )(skip, *inputs)
+    )(table, *inputs)
     o = out[0].transpose(0, 2, 1, 3)
     if save_residuals:
         return o, out[1]

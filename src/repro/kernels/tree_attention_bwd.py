@@ -14,8 +14,9 @@ one elementwise reduction):
 
 Both kernels reuse the forward's visibility predicate — global query
 index ``i = q_off + i_local``, ``j ≤ i ∧ kv_last[j] ≥ i``, and (windowed)
-``pos_q[i] − pos_k[j] < window`` — and its block-skip rule via the shared
-``skip_scalars`` prefetch array, so gateway-extended KV layouts
+``pos_q[i] − pos_k[j] < window`` — and walk only the live blocks of the
+forward's ``block_live_mask``, through ``live_table``, so gateway-extended
+KV layouts
 (front-concatenated ancestors, paper §3.3) and sliding-window configs
 backprop through exactly the visibility the forward computed.  dk/dv are
 produced for the FULL KV length: rows [0, q_off) are the ancestor
@@ -27,12 +28,20 @@ entry in their row.
 Two kernels because the two reductions run along opposite grid axes and
 TPU output revisiting must be consecutive:
 
-  - **dq**: grid (B, H, nq, nk) — innermost over kv blocks, dq accumulated
-    in VMEM scratch, written once at the last kv step (mirrors forward).
-  - **dk/dv**: grid (B, Kh, nk, G, nq) — innermost over q blocks *and* the
-    G query heads of the group, so the GQA head-group reduction happens
-    in-kernel in the same VMEM accumulator (no [B,S,H,hd] staging buffer
-    + XLA reduction afterwards).
+  - **dq**: grid (H, n) over the forward's table — (b, q-block, kv-block)
+    entries, kv blocks increasing; dq accumulated in VMEM scratch from a
+    q block's first entry and written at its last (mirrors forward).
+  - **dk/dv**: grid (Kh, n, G) over the transposed table — (b, kv-block,
+    q-block) entries — with the G query heads of a group as the static
+    innermost axis, so the GQA head-group reduction happens in-kernel in
+    the same VMEM accumulator (no [B,S,H,hd] staging buffer + XLA
+    reduction afterwards).  A kv block's sum runs over q blocks, and
+    within each over the group's heads.  A kv block with no live q block
+    gets one write-only entry: zero dk/dv.
+
+``n`` is each table's live count, a dynamic grid bound; both tables come
+from one ``block_live_mask`` (``live_tables``) and share its SMEM bound
+(B·nq·nk entries).
 
 Both run on the forward's head-major operand layout
 (``tree_attention.head_major``).  Validated on CPU with interpret=True
@@ -41,6 +50,7 @@ against jax.vjp through kernels/ref.py (tests/test_kernels_bwd.py);
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -48,8 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tree_attention import (block_live, head_major,
-                                         skip_scalars)
+from repro.kernels.tree_attention import (entry_blocks, entry_flags,
+                                         head_major)
 
 NEG_INF = -1e30
 
@@ -76,41 +86,31 @@ def _vis_and_p(qq, kk, kl, lse, scale, q_start, kv_start, block_q, block_k,
     return jnp.where(vis, jnp.exp(expo), 0.0)
 
 
-def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
+def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, tab,
             block_q, block_k, q_off, window, pq, pk, interpret):
+    """dq over ``tab`` = ``live_table(mask)``: (b, q-block, kv-block)."""
     B, H, S, hd = qh.shape
-    Kh, Skv = kh.shape[1], kh.shape[2]
+    Kh = kh.shape[1]
     G = max(1, H // Kh)
-    nq, nk = S // block_q, Skv // block_k
     windowed = window is not None
+    table, n = tab
 
-    def kernel(skip_ref, *refs):
+    def kernel(tab_ref, *refs):
         q_ref, k_ref, v_ref, kl_ref, lse_ref, dl_ref, do_ref = refs[:7]
         rest = refs[7:]
         if windowed:
             pq_ref, pk_ref = rest[:2]
             rest = rest[2:]
         dq_ref, dq_scr = rest
-        b = pl.program_id(0)
-        qi = pl.program_id(2)
-        ki = pl.program_id(3)
-        num_kv = pl.num_programs(3)
+        code = tab_ref[pl.program_id(1)]
+        _, qi, ki = entry_blocks(code)
+        live, first, last = entry_flags(code)
         q_start = q_off + qi * block_q          # global DFS index
-        q_end = q_start + block_q - 1
         kv_start = ki * block_k
 
-        @pl.when(ki == 0)
+        @pl.when(first)
         def _init():
             dq_scr[...] = jnp.zeros_like(dq_scr)
-
-        if windowed:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki],
-                              skip_ref[2 * B * nk + b * nq + qi],
-                              skip_ref[B * nk + b * nk + ki], window)
-        else:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki])
 
         @pl.when(live)
         def _compute():
@@ -133,27 +133,31 @@ def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
                 ds, kk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        @pl.when(ki == num_kv - 1)
+        @pl.when(last)
         def _finalize():
             dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
+    def at(fn):
+        """An index map over grid (h, e) from (b, h, qi, ki)."""
+        return lambda h, e, tab: fn(h, *entry_blocks(tab[e]))
+
     qspec = pl.BlockSpec((None, None, block_q, hd),
-                         lambda b, h, qi, ki, skip: (b, h, qi, 0))
+                         at(lambda h, b, qi, ki: (b, h, qi, 0)))
     kspec = pl.BlockSpec((None, None, block_k, hd),
-                         lambda b, h, qi, ki, skip: (b, h // G, ki, 0))
+                         at(lambda h, b, qi, ki: (b, h // G, ki, 0)))
     rowq = pl.BlockSpec((None, None, 1, block_q),
-                        lambda b, h, qi, ki, skip: (b, h, 0, qi))
+                        at(lambda h, b, qi, ki: (b, h, 0, qi)))
     in_specs = [qspec, kspec, kspec,
                 pl.BlockSpec((None, 1, block_k),
-                             lambda b, h, qi, ki, skip: (b, 0, ki)),
+                             at(lambda h, b, qi, ki: (b, 0, ki))),
                 rowq, rowq, qspec]
     inputs = [qh, kh, vh, kl, lse, delta, doh]
     if windowed:
         in_specs += [
             pl.BlockSpec((None, 1, block_q),
-                         lambda b, h, qi, ki, skip: (b, 0, qi)),
+                         at(lambda h, b, qi, ki: (b, 0, qi))),
             pl.BlockSpec((None, 1, block_k),
-                         lambda b, h, qi, ki, skip: (b, 0, ki)),
+                         at(lambda h, b, qi, ki: (b, 0, ki))),
         ]
         inputs += [pq, pk]
 
@@ -161,7 +165,7 @@ def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
+            grid=(H, n),
             in_specs=in_specs,
             out_specs=qspec,
             scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
@@ -169,47 +173,37 @@ def _bwd_dq(qh, kh, vh, kl, lse, delta, doh, scale, skip,
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), qh.dtype),
         interpret=interpret,
         name="tree_attn_dq",
-    )(skip, *inputs)
+    )(table, *inputs)
 
 
-def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
+def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, tab,
              block_q, block_k, q_off, window, pq, pk, interpret):
+    """dk/dv over ``tab`` = ``live_table`` of the transposed mask:
+    (b, kv-block, q-block)."""
     B, H, S, hd = qh.shape
     Kh, Skv = kh.shape[1], kh.shape[2]
     G = max(1, H // Kh)
-    nq, nk = S // block_q, Skv // block_k
     windowed = window is not None
+    table, n = tab
 
-    def kernel(skip_ref, *refs):
+    def kernel(tab_ref, *refs):
         q_ref, k_ref, v_ref, kl_ref, lse_ref, dl_ref, do_ref = refs[:7]
         rest = refs[7:]
         if windowed:
             pq_ref, pk_ref = rest[:2]
             rest = rest[2:]
         dk_ref, dv_ref, dk_scr, dv_scr = rest
-        b = pl.program_id(0)
-        ki = pl.program_id(2)
-        g = pl.program_id(3)
-        qi = pl.program_id(4)
-        num_g = pl.num_programs(3)
-        num_q = pl.num_programs(4)
+        code = tab_ref[pl.program_id(1)]
+        _, ki, qi = entry_blocks(code)
+        live, first, last = entry_flags(code)
+        g = pl.program_id(2)
         q_start = q_off + qi * block_q          # global DFS index
-        q_end = q_start + block_q - 1
         kv_start = ki * block_k
 
-        @pl.when((g == 0) & (qi == 0))
+        @pl.when(first & (g == 0))
         def _init():
             dk_scr[...] = jnp.zeros_like(dk_scr)
             dv_scr[...] = jnp.zeros_like(dv_scr)
-
-        if windowed:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki],
-                              skip_ref[2 * B * nk + b * nq + qi],
-                              skip_ref[B * nk + b * nk + ki], window)
-        else:
-            live = block_live(q_start, q_end, kv_start,
-                              skip_ref[b * nk + ki])
 
         @pl.when(live)
         def _compute():
@@ -235,31 +229,35 @@ def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
                 ds, qq, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        @pl.when((g == num_g - 1) & (qi == num_q - 1))
+        @pl.when(last & (g == G - 1))
         def _finalize():
             dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
+    def at(fn):
+        """An index map over grid (kh, e, g) from (b, kh, g, ki, qi)."""
+        return lambda kh, e, g, tab: fn(kh, g, *entry_blocks(tab[e]))
+
     qspec = pl.BlockSpec(
         (None, None, block_q, hd),
-        lambda b, kh, ki, g, qi, skip: (b, kh * G + g, qi, 0))
+        at(lambda kh, g, b, ki, qi: (b, kh * G + g, qi, 0)))
     kspec = pl.BlockSpec(
         (None, None, block_k, hd),
-        lambda b, kh, ki, g, qi, skip: (b, kh, ki, 0))
+        at(lambda kh, g, b, ki, qi: (b, kh, ki, 0)))
     rowq = pl.BlockSpec(
         (None, None, 1, block_q),
-        lambda b, kh, ki, g, qi, skip: (b, kh * G + g, 0, qi))
+        at(lambda kh, g, b, ki, qi: (b, kh * G + g, 0, qi)))
     in_specs = [qspec, kspec, kspec,
                 pl.BlockSpec((None, 1, block_k),
-                             lambda b, kh, ki, g, qi, skip: (b, 0, ki)),
+                             at(lambda kh, g, b, ki, qi: (b, 0, ki))),
                 rowq, rowq, qspec]
     inputs = [qh, kh, vh, kl, lse, delta, doh]
     if windowed:
         in_specs += [
             pl.BlockSpec((None, 1, block_q),
-                         lambda b, kh, ki, g, qi, skip: (b, 0, qi)),
+                         at(lambda kh, g, b, ki, qi: (b, 0, qi))),
             pl.BlockSpec((None, 1, block_k),
-                         lambda b, kh, ki, g, qi, skip: (b, 0, ki)),
+                         at(lambda kh, g, b, ki, qi: (b, 0, ki))),
         ]
         inputs += [pq, pk]
 
@@ -267,7 +265,7 @@ def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, Kh, nk, G, nq),
+            grid=(Kh, n, G),
             in_specs=in_specs,
             out_specs=[kspec, kspec],
             scratch_shapes=[
@@ -281,10 +279,12 @@ def _bwd_dkv(qh, kh, vh, kl, lse, delta, doh, scale, skip,
         ],
         interpret=interpret,
         name="tree_attn_dkv",
-    )(skip, *inputs)
+    )(table, *inputs)
     return out[0], out[1]
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_q", "block_k", "q_off", "window", "interpret"))
 def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                        kv_last: jax.Array, o: jax.Array, lse: jax.Array,
                        do: jax.Array, scale: float, *,
@@ -292,12 +292,13 @@ def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
                        q_off: int = 0, window: Optional[int] = None,
                        pos_q: Optional[jax.Array] = None,
                        pos_k: Optional[jax.Array] = None,
-                       interpret: bool = False):
+                       interpret: bool = False, tables):
     """Fused dq/dk/dv for tree attention.
 
     q/o/do: [B,S,H,hd]; k/v: [B,Skv,Kh,hd]; kv_last: [B,Skv] int32;
     lse: [B,H,1,S] f32 from the forward's ``save_residuals=True``.
-    q_off/window/pos_q/pos_k: same gateway/window layout as the forward.
+    q_off/window/pos_q/pos_k: same gateway/window layout as the forward;
+    ``tables``: the forward's ``live_tables``, ``(fwd, dkv)``.
     Returns (dq, dk, dv) in the input dtypes; dk/dv cover the full Skv,
     including the ancestor rows [0, q_off) (d_extra_k / d_extra_v).
     """
@@ -312,16 +313,13 @@ def tree_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     # Δ_i = Σ_d do_id o_id, [B,H,1,S] — cheap elementwise reduce, XLA-side.
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)
              ).sum(-1).transpose(0, 2, 1)[:, :, None, :]
-    # one shared prefetch array for both kernels (same blocks, same skip)
-    skip = skip_scalars(kv_last, B, S // block_q, Skv // block_k,
-                        block_q, block_k, pos_q, pos_k, window)
     qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
     doh = do.transpose(0, 2, 1, 3)
-    args = (qh, kh, vh, kl, lse, delta, doh, scale, skip, block_q, block_k,
-            q_off, window, pq, pk, interpret)
+    args = (qh, kh, vh, kl, lse, delta, doh, scale)
+    rest = (block_q, block_k, q_off, window, pq, pk, interpret)
     with jax.named_scope("tree_attn_dq"):
-        dq = _bwd_dq(*args)
+        dq = _bwd_dq(*args, tables[0], *rest)
     with jax.named_scope("tree_attn_dkv"):
-        dk, dv = _bwd_dkv(*args)
+        dk, dv = _bwd_dkv(*args, tables[1], *rest)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.ops import LIVE_TABLES
 from repro.models.attention import (attention, init_attention,
                                     project_cross_kv)
 from repro.models.layers import (embed, init_embedding, init_lm_head,
@@ -282,8 +283,12 @@ def _scan_group(cfg: ModelConfig, stacked: dict, kind: str, x: jax.Array,
     if cfg.remat == "full":
         # activation checkpointing: recompute the layer in the backward
         # pass instead of saving its internals (per-chunk attention
-        # probabilities etc. dominate temp memory otherwise — §Perf)
-        body = jax.checkpoint(body)
+        # probabilities etc. dominate temp memory otherwise — §Perf);
+        # the attention kernels' live-block tables (a few KiB, the same
+        # in every layer) are kept rather than rebuilt in each layer
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(
+                LIVE_TABLES))
 
     (x, aux), caps = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), (stacked, gw or {}))

@@ -67,6 +67,8 @@ class PackedExec:
     inputs: dict                 # jnp-ready model inputs (prepare_batch)
     tokens: int = 0              # host-side unique-token count (logging)
     cells: int = 0               # materialized row cells (B × S)
+    attn_blocks_live: int = 0    # attention block pairs the kernels run
+    attn_blocks_dense: int = 0   # all pairs of their dense grid
 
 
 @dataclass

@@ -75,6 +75,7 @@ from repro.core.plan_cost import (DEFAULT_WEIGHTS, CompileCacheSim,
                                   round_to_multiple, score_packing)
 from repro.core.tree import TrajectoryTree, serialize_tree
 from repro.data.loader import LoaderConfig, StepBatch, tree_stream
+from repro.kernels.ops import block_counts
 from repro.models.model import needs_chunks, prepare_batch
 
 
@@ -460,9 +461,12 @@ class PlannedStep:
         packed = None
         if sb.inputs is not None:
             B, S = sb.tb.tokens.shape
+            window = cfg.attn.window if cfg.attn is not None else None
+            live, dense = block_counts(sb.tb.kv_last, sb.tb.pos_ids, window)
             packed = PackedExec(inputs=sb.inputs,
                                 tokens=int(sb.tb.valid.sum()),
-                                cells=B * S)
+                                cells=B * S, attn_blocks_live=live,
+                                attn_blocks_dense=dense)
         partition = None
         if self.oversized:
             partition = build_partition_plan(

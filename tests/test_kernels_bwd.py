@@ -18,6 +18,7 @@ from repro.core.tree import serialize_tree
 from repro.data.synthetic import trees_for_batch
 from repro.kernels.ops import tree_attention
 from repro.kernels.ref import tree_attention_ref, tree_attention_ref_ext
+from repro.kernels.tree_attention import live_tables
 from repro.kernels.tree_attention import tree_attention as raw_fwd
 from repro.kernels.tree_attention_bwd import tree_attention_bwd
 
@@ -105,6 +106,49 @@ def test_bwd_padding_rows_zero_grad_and_finite():
     np.testing.assert_allclose(np.asarray(dq[0, 16:]), 0.0, atol=1e-6)
     np.testing.assert_allclose(np.asarray(dk[0, 16:]), 0.0, atol=1e-6)
     np.testing.assert_allclose(np.asarray(dv[0, 16:]), 0.0, atol=1e-6)
+
+
+def test_empty_row_and_sparse_tree_row_vs_ref():
+    """A batch whose first row is empty (all padding) and whose second
+    holds many small trees, so that under 15 % of the block pairs are
+    live: the kernels run only those (and one write-only entry for each
+    block with none), and forward and gradients still match the oracle;
+    the empty row's outputs and gradients are zero, and all are finite."""
+    from repro.kernels.tree_attention import block_live_mask
+    rng = np.random.default_rng(53)
+    B, S, H, Kh, hd, blk = 2, 512, 4, 2, 16, 32
+    trees = trees_for_batch(17, n_trees=40, kind="random",
+                            seg_len_range=(1, 4), max_depth=3)
+    sers, used = [], 0
+    for t in trees:
+        ser = serialize_tree(t)
+        if used + ser.n <= S:
+            sers.append(ser)
+            used += ser.n
+    tb = pack_trees(sers, S, batch_size=1)
+    kv_last = np.concatenate([np.full((1, S), -1, np.int32),
+                              np.asarray(tb.kv_last, np.int32)])
+    live = block_live_mask(kv_last, S, blk, blk)
+    assert not live[0].any() and live.mean() < 0.15, live.mean()
+    kv_last = jnp.asarray(kv_last)
+    q = _rand(rng, (B, S, H, hd))
+    k = _rand(rng, (B, S, Kh, hd))
+    v = _rand(rng, (B, S, Kh, hd))
+    do = _rand(rng, (B, S, H, hd))
+    scale = hd ** -0.5
+    fn = lambda q_, k_, v_: tree_attention(q_, k_, v_, kv_last, scale,
+                                           blk, blk)
+    ref = lambda q_, k_, v_: tree_attention_ref(q_, k_, v_, kv_last, scale)
+    o = fn(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(o[0]), 0.0)
+    g, gr = _grads(fn, q, k, v, do), _grads(ref, q, k, v, do)
+    for name, a, b in zip(("dq", "dk", "dv"), g, gr):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(np.asarray(a[0]), 0.0, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
 
 
 def test_bwd_pure_causal_matches_plain_flash_grads():
@@ -246,10 +290,12 @@ def test_bwd_direct_entry_point_matches_custom_vjp():
     v = _rand(rng, (B, S, Kh, hd))
     do = _rand(rng, (B, S, H, hd))
     scale = hd ** -0.5
+    tables = live_tables(kv_last, S, 32, 32)
     o, lse = raw_fwd(q, k, v, kv_last, scale, block_q=32, block_k=32,
-                     save_residuals=True, interpret=True)
+                     save_residuals=True, interpret=True, table=tables[0])
     dq, dk, dv = tree_attention_bwd(q, k, v, kv_last, o, lse, do, scale,
-                                    block_q=32, block_k=32, interpret=True)
+                                    block_q=32, block_k=32, interpret=True,
+                                    tables=tables)
     g = _grads(lambda q_, k_, v_:
                tree_attention(q_, k_, v_, kv_last, scale, 32, 32),
                q, k, v, do)

@@ -341,6 +341,51 @@ def test_planner_matches_two_branch(family, impl):
     _check_planner_equivalence(family, impl)
 
 
+@pytest.mark.parametrize("window", [None, 48])
+def test_plan_counts_attention_blocks(window):
+    """A packed plan counts the block pairs the attention kernels run
+    (``attn_blocks_live``) and their dense grid (``attn_blocks_dense``):
+    the sums of ``block_live_mask`` over its rows at the kernels' block
+    size, windowed where the model is."""
+    from dataclasses import replace
+
+    from repro.kernels.tree_attention import block_live_mask
+    cfg = tiny_cfg("dense")
+    cfg = replace(cfg, attn=replace(cfg.attn, window=window))
+    lc = _lc(seq_len=512, trees_per_batch=12,
+             gen_kwargs=dict(turn_len_range=(8, 40), num_turns=3))
+    seen = 0
+    for ps in plan_stream(cfg, lc, 3):
+        packed = ps.execution_plan().packed
+        if packed is None:
+            continue
+        tb = ps.step_batch().tb
+        live = block_live_mask(tb.kv_last, 512, 128, 128, pos_q=tb.pos_ids,
+                               pos_k=tb.pos_ids, window=window)
+        assert packed.attn_blocks_live == int(live.sum())
+        assert packed.attn_blocks_dense == live.size == len(tb.kv_last) * 16
+        assert 0 < packed.attn_blocks_live < packed.attn_blocks_dense
+        seen += 1
+    assert seen
+
+
+def test_cell_pool_live_block_share():
+    """The benchmark cell ``qwen15_tree_sft`` plans 96 packed steps of
+    2 rows × 2048 from its seed; its kernels run under a tenth of the
+    dense grid's block pairs there."""
+    from bench.run import Run, load_cell
+    cell = load_cell("qwen15_tree_sft")
+    run = Run(cell["c"], cell["traffic"], 2147911117, 1)
+    live = dense = steps = 0
+    for ps in plan_stream(run.cfg, run.lc, iter(run.ptrees), run.pc):
+        packed = ps.execution_plan().packed
+        live += packed.attn_blocks_live
+        dense += packed.attn_blocks_dense
+        steps += 1
+    assert steps == 96 and dense == steps * 2 * 16 * 16
+    assert round(live / dense, 3) == 0.098
+
+
 def test_planner_streams_deterministic():
     """Every consumer of the planner (``plans``, the deprecated loader
     wrappers) sees the same schedule — one deterministic plan stream."""

@@ -11,7 +11,9 @@ and a sliding window.  One more compiles the whole differentiable op
 (``kernels/ops.tree_attention``, padding included) at an ancestor length
 that is not a block multiple, and another compiles it on a four-chip
 data-parallel mesh, where XLA would refuse to partition the kernel
-itself.
+itself.  The kernels run a data-dependent grid over the live-block
+table, so these compiles also show that the TPU compiler takes a dynamic
+grid bound.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
@@ -25,7 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.tree_attention import head_major, skip_scalars
+from repro.kernels.tree_attention import head_major, live_tables
 from repro.kernels.tree_attention import tree_attention as fwd_kernel
 from repro.kernels.tree_attention_bwd import _bwd_dkv, _bwd_dq
 
@@ -94,9 +96,12 @@ def test_forward_compiles_for_v5e(one_chip, layout):
     args, _ = _shapes(one_chip, q_off, window)
 
     def f(q, k, v, kv_last, pos_q=None, pos_k=None):
+        tables = live_tables(kv_last, S, BLOCK, BLOCK, q_off=q_off,
+                             pos_q=pos_q, pos_k=pos_k, window=window)
         return fwd_kernel(q, k, v, kv_last, HD ** -0.5, block_q=BLOCK,
                           block_k=BLOCK, q_off=q_off, window=window,
-                          pos_q=pos_q, pos_k=pos_k, save_residuals=True)
+                          pos_q=pos_q, pos_k=pos_k, save_residuals=True,
+                          table=tables[0])
     _assert_kernel(f, args)
 
 
@@ -105,18 +110,18 @@ def test_forward_compiles_for_v5e(one_chip, layout):
 def test_backward_compiles_for_v5e(one_chip, layout, kernel):
     q_off, window = LAYOUTS[layout]
     body = _bwd_dq if kernel == "dq" else _bwd_dkv
-    Skv = q_off + S
 
     def f(q, k, v, kv_last, *rest):
         pos_q = pos_k = None
         if window is not None:
             pos_q, pos_k, *rest = rest
         lse, delta, do = rest
-        skip = skip_scalars(kv_last, B, S // BLOCK, Skv // BLOCK, BLOCK,
-                            BLOCK, pos_q, pos_k, window)
+        tables = live_tables(kv_last, S, BLOCK, BLOCK, q_off=q_off,
+                             pos_q=pos_q, pos_k=pos_k, window=window)
+        tab = tables[0] if kernel == "dq" else tables[1]
         qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, pos_q, pos_k)
         return body(qh, kh, vh, kl, lse, delta, do.transpose(0, 2, 1, 3),
-                    HD ** -0.5, skip, BLOCK, BLOCK, q_off, window, pq, pk,
+                    HD ** -0.5, tab, BLOCK, BLOCK, q_off, window, pq, pk,
                     False)
     args, sds = _shapes(one_chip, q_off, window)
     args += [sds((B, H, 1, S), jnp.float32),           # lse
