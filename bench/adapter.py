@@ -1,15 +1,17 @@
 """What the benchmark needs to drive the program, and nothing more.
 
-- ``load_config`` / ``program_config``: a configuration file
-  (``bench/configs/<name>.json``, the published ``config.json`` keys plus
-  the program's own settings) as the program's ``ModelConfig``;
+- ``load_config``: a configuration file (``bench/configs/<name>.json``,
+  the published ``config.json`` keys plus the program's own settings);
 - ``init_weights``: the weights, made on the device from the seed in one
   jitted call, in bfloat16 (the type they are trained in), in the
   benchmark's own layout, which the reference reads as well;
-- ``to_program`` / ``from_program`` / ``entry_norms``: that layout as the
-  program's parameter tree and back, and the norm of each entry (one per
-  layer and tensor), which is what the check compares;
+- ``entry_norms``: the norm of each entry of that layout (one per layer
+  and tensor), which is what the check compares;
 - ``program_tree``: a generated tree as the program's tree type.
+
+What depends on the model's layers (its sizes, the program's
+``ModelConfig``, the layout and its mapping to the program's parameter
+tree) is the family's: ``bench/families/``.
 """
 from __future__ import annotations
 
@@ -18,9 +20,9 @@ import os
 
 import numpy as np
 
+from bench import families
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LAYER_KEYS = ("ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo", "ln2",
-              "wg", "wu", "wd")
 
 
 def load_json(*parts: str) -> dict:
@@ -30,47 +32,6 @@ def load_json(*parts: str) -> dict:
 
 def load_config(name: str) -> dict:
     return load_json("bench", "configs", f"{name}.json")
-
-
-def dims(c: dict) -> dict:
-    """The sizes the reference and the work counts read."""
-    H, Kh = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim", c["hidden_size"] // H)
-    m = c["program"]["vocab_pad_multiple"]
-    V = c["vocab_size"]
-    return dict(L=c["num_hidden_layers"], D=c["hidden_size"],
-                F=c["intermediate_size"], H=H, Kh=Kh, hd=hd, V=V,
-                Vp=-(-V // m) * m, tied=bool(c["tie_word_embeddings"]),
-                theta=float(c["rope_theta"]), eps=float(c["rms_norm_eps"]),
-                std=float(c["initializer_range"]))
-
-
-def program_config(c: dict, name: str = "bench"):
-    from repro.configs.base import AttnCfg, ModelConfig
-    d, p = dims(c), c["program"]
-    assert c["hidden_act"] == "silu" and p["mlp_activation"] == "swiglu"
-    return ModelConfig(
-        name=name, family=p["family"], n_layers=d["L"], d_model=d["D"],
-        d_ff=d["F"], vocab_size=d["V"],
-        attn=AttnCfg(n_heads=d["H"], n_kv_heads=d["Kh"], head_dim=d["hd"],
-                     qkv_bias=p["qkv_bias"], rope_theta=d["theta"]),
-        mlp_activation=p["mlp_activation"], norm_eps=d["eps"],
-        tie_embeddings=d["tied"], dtype=p["dtype"],
-        vocab_pad_multiple=p["vocab_pad_multiple"], remat=p["remat"],
-        source=c["source"])
-
-
-def shapes(c: dict) -> dict:
-    d = dims(c)
-    L, D, F, Vp = d["L"], d["D"], d["F"], d["Vp"]
-    q, kv = d["H"] * d["hd"], d["Kh"] * d["hd"]
-    layer = dict(ln1=(L, D), wq=(L, D, q), bq=(L, q), wk=(L, D, kv),
-                 bk=(L, kv), wv=(L, D, kv), bv=(L, kv), wo=(L, q, D),
-                 ln2=(L, D), wg=(L, D, F), wu=(L, D, F), wd=(L, F, D))
-    out = dict(embed=(Vp, D), final_norm=(D,), layers=layer)
-    if not d["tied"]:
-        out["lm_head"] = (D, Vp)
-    return out
 
 
 def seed_key(seed: int):
@@ -88,10 +49,12 @@ def _init(c: dict, key):
     RMSNorm scales 1 + normal(0, std), in the configuration's type."""
     import jax
     import jax.numpy as jnp
-    std = dims(c)["std"]
+    fam = families.load(c)
+    std = fam.dims(c)["std"]
     dt = jnp.bfloat16 if c["program"]["dtype"] == "bfloat16" else jnp.float32
+    shapes = fam.shapes(c)
     flat = []
-    for name, shp in shapes(c).items():
+    for name, shp in shapes.items():
         if name == "layers":
             flat += [(k, s) for k, s in shp.items()]
         else:
@@ -100,11 +63,11 @@ def _init(c: dict, key):
     vals = {}
     for (name, shp), k in zip(flat, keys):
         x = jax.random.normal(k, shp, jnp.float32) * std
-        if name in ("ln1", "ln2", "final_norm"):
+        if name in fam.SCALES or name == "final_norm":
             x = x + 1.0
         vals[name] = x.astype(dt)
     w = {k: vals[k] for k in ("embed", "final_norm", "lm_head") if k in vals}
-    w["layers"] = {k: vals[k] for k in LAYER_KEYS}
+    w["layers"] = {k: vals[k] for k in shapes["layers"]}
     return w
 
 
@@ -147,13 +110,14 @@ def init_weights(c: dict, seed: int, program: bool = False, mesh=None):
     import jax
     key = (json.dumps(c, sort_keys=True), program, mesh)
     if key not in _INIT:
+        fam = families.load(c)
         out = None
         if mesh is not None and mesh.size > 1:
             out = jax.sharding.NamedSharding(mesh,
                                              jax.sharding.PartitionSpec())
         _INIT[key] = jax.jit(
-            lambda k: to_program(c, _init(c, k)) if program else _init(c, k),
-            out_shardings=out)
+            lambda k: fam.to_program(c, _init(c, k)) if program
+            else _init(c, k), out_shardings=out)
     return _INIT[key](seed_key(seed))
 
 

@@ -7,14 +7,16 @@ predicted from the path's token t-1 and weighted ``trained / (K · T)``,
 K the tree's paths and T the step's trees.  Paths are laid into rows of
 ``R`` tokens only to keep a fixed shape; a path never sees another.
 
-The forward is a plain float32 ``jax.numpy`` transformer of the published
-description: RMSNorm, QKV bias, rotary positions (half-split, θ from the
-configuration), grouped-query attention, SwiGLU, LM head (tied or not)
-over the published vocabulary, whatever rows the weights are padded to.
-Every matrix product runs at ``Precision.HIGHEST``.  The optimizer is
+The forward is plain float32 ``jax.numpy`` of the published description:
+the embedding, the family's layers (``body`` of
+``bench/families/<family>.py``), the final RMSNorm and the LM head (tied
+or not) over the published vocabulary, whatever rows the weights are
+padded to.  Every matrix product runs at ``Precision.HIGHEST`` through
+``ein``, which the families' layers use too.  The optimizer is
 AdamW with global-norm clipping and the configuration's learning-rate
 schedule; weights are kept in bfloat16 between steps, as the
-configuration states, and decay applies to matrices only.
+configuration states, and decay applies to the family's ``DECAY``
+entries (its matrices).
 
 ``precision="fp8"`` is the control: the same computation with the
 operands of every matrix product rounded to float8 (e4m3 forward, e5m2
@@ -29,10 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.adapter import dims, entry_norms, flatten_norms, init_weights
+from bench import families
+from bench.adapter import entry_norms, flatten_norms, init_weights
 
 HI = jax.lax.Precision.HIGHEST
-DECAY = ("embed", "lm_head", "wq", "wk", "wv", "wo", "wg", "wu", "wd")
 
 
 # ---------------------------------------------------------------------------
@@ -117,7 +119,7 @@ def _ein8_bwd(spec, res, g):
 _ein8.defvjp(_ein8_fwd, _ein8_bwd)
 
 
-def _ein(spec, a, b, fp8: bool):
+def ein(spec, a, b, fp8: bool):
     if fp8:
         return _ein8(spec, a, b)
     return jnp.einsum(spec, a, b, precision=HI)
@@ -127,11 +129,20 @@ def _ein(spec, a, b, fp8: bool):
 # Forward and loss of one row
 # ---------------------------------------------------------------------------
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def visible(row):
+    """[R, R]: query i sees key j of its own path at or before it; padding
+    (seg -1) sees only itself, so that no row is empty."""
+    seg = row["seg"]
+    i = jnp.arange(seg.shape[0])
+    return (((seg[:, None] == seg[None, :]) & (i[None, :] <= i[:, None])
+             & (seg[None, :] >= 0)) | (i[:, None] == i[None, :]))
+
+
+def rope(x, pos, theta):
     """x [R, heads, hd]; rotate_half rotary embedding."""
     half = x.shape[-1] // 2
     inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
@@ -142,36 +153,13 @@ def _rope(x, pos, theta):
 
 
 def row_loss(c: dict, fp8: bool, w: dict, row: dict, chunk: int = 512):
-    d = dims(c)
-    H, Kh, hd, eps = d["H"], d["Kh"], d["hd"], d["eps"]
-    tok, seg, pos = row["tokens"], row["seg"], row["pos"]
+    fam = families.load(c)
+    d = fam.dims(c)
+    tok = row["tokens"]
     R = tok.shape[0]
     f32 = lambda a: a.astype(jnp.float32)
-    i = jnp.arange(R)
-    # padding (seg -1) sees only itself, so that no row is empty
-    vis = (((seg[:, None] == seg[None, :]) & (i[None, :] <= i[:, None])
-            & (seg[None, :] >= 0)) | (i[:, None] == i[None, :]))
-
-    def layer(x, lw):
-        lw = jax.tree.map(f32, lw)
-        h = _rms(x, lw["ln1"], eps)
-        q = (_ein("rd,de->re", h, lw["wq"], fp8) + lw["bq"]).reshape(R, H, hd)
-        k = (_ein("rd,de->re", h, lw["wk"], fp8) + lw["bk"]).reshape(R, Kh, hd)
-        v = (_ein("rd,de->re", h, lw["wv"], fp8) + lw["bv"]).reshape(R, Kh, hd)
-        q, k = _rope(q, pos, d["theta"]), _rope(k, pos, d["theta"])
-        k, v = jnp.repeat(k, H // Kh, axis=1), jnp.repeat(v, H // Kh, axis=1)
-        s = _ein("ihd,jhd->hij", q, k, fp8) * hd ** -0.5
-        p = jax.nn.softmax(jnp.where(vis[None], s, -1e30), axis=-1)
-        o = _ein("hij,jhd->ihd", p, v, fp8).reshape(R, H * hd)
-        x = x + _ein("re,ed->rd", o, lw["wo"], fp8)
-        h = _rms(x, lw["ln2"], eps)
-        m = jax.nn.silu(_ein("rd,df->rf", h, lw["wg"], fp8)) \
-            * _ein("rd,df->rf", h, lw["wu"], fp8)
-        return x + _ein("rf,fd->rd", m, lw["wd"], fp8), None
-
-    x = f32(w["embed"])[tok]
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, w["layers"])
-    x = _rms(x, f32(w["final_norm"]), eps)
+    x, aux = fam.body(c, fp8, w, f32(w["embed"])[tok], row)
+    x = rms(x, f32(w["final_norm"]), d["eps"])
     # the published vocabulary only: rows the weights carry past it
     # (padding to a multiple) are no tokens, so no probability falls on them
     head = f32(w["embed"]).T if d["tied"] else f32(w["lm_head"])
@@ -185,7 +173,7 @@ def row_loss(c: dict, fp8: bool, w: dict, row: dict, chunk: int = 512):
 
     @jax.checkpoint
     def piece(hc, tc, wc):
-        lg = _ein("rd,dv->rv", hc, head, fp8)
+        lg = ein("rd,dv->rv", hc, head, fp8)
         lse = jax.nn.logsumexp(lg, axis=-1)
         lab = jnp.take_along_axis(lg, tc[:, None], axis=-1)[:, 0]
         return jnp.sum(wc * (lse - lab))
@@ -195,7 +183,7 @@ def row_loss(c: dict, fp8: bool, w: dict, row: dict, chunk: int = 512):
     parts = jax.lax.map(lambda a: piece(*a), (
         h_prev.reshape(n, chunk, -1), tgt.reshape(n, chunk),
         wt.reshape(n, chunk)))
-    return jnp.sum(parts)
+    return jnp.sum(parts) + aux
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +226,7 @@ def lr_at(opt: dict, step: int) -> float:
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3, 4))
 def _adamw(hyper, w, g, mu, nu, lr, step):
-    b1, b2, eps, wd, clip = hyper
+    b1, b2, eps, wd, clip, decay = hyper
     leaves = jax.tree.leaves(g)
     gn = jnp.sqrt(sum(jnp.sum(jnp.square(a)) for a in leaves))
     scale = jnp.minimum(1.0, clip / jnp.maximum(gn, 1e-9))
@@ -251,7 +239,7 @@ def _adamw(hyper, w, g, mu, nu, lr, step):
         v = b2 * v + (1 - b2) * gg * gg
         delta = (m / c1) / (jnp.sqrt(v / c2) + eps)
         pf = p.astype(jnp.float32)
-        if name in DECAY:
+        if name in decay:
             delta = delta + wd * pf
         return (pf - lr * delta).astype(p.dtype), m, v
 
@@ -272,7 +260,8 @@ def run(c: dict, seed: int, steps_trees: list, opt: dict, R: int,
     w0 = init_weights(c, seed) if weights is None else weights
     w, mu, nu = w0, f["zeros"](w0), f["zeros"](w0)
     hyper = (opt.get("b1", 0.9), opt.get("b2", 0.95), opt.get("eps", 1e-8),
-             opt.get("weight_decay", 0.1), opt.get("grad_clip", 1.0))
+             opt.get("weight_decay", 0.1), opt.get("grad_clip", 1.0),
+             families.load(c).DECAY)
     losses, gnorms, g0 = [], [], None
     for s, trees in enumerate(steps_trees):
         acc, loss = f["zeros"](w0), jnp.zeros((), jnp.float32)

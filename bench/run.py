@@ -2,9 +2,10 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names a configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<traffic>.json``); the metrics are read by
-``bench/metrics/<name>.py``.  The run drives the path users run:
+The cell names a configuration (``bench/configs/<config>.json``), whose
+``program.family`` names the model code (``bench/families/<family>.py``),
+and a traffic mix (``bench/traffic/<traffic>.json``); the metrics are
+read by ``bench/metrics/<name>.py``.  The run drives the path users run:
 ``repro.train.planner.plans`` → ``ExecutionPlan`` →
 ``TreeTrainEngine.step``, with the arguments ``repro.launch.train``
 builds.
@@ -23,9 +24,10 @@ the per-layer metrics are printed instead of the end-to-end ones.  Then
 the program's state is freed and the reference (``bench/reference.py``)
 trains the checked steps; ``bench/check.py`` compares.
 
-The last line of standard output is one JSON object.  Without a TPU, with
-another number of chips than the cell asks for, or without the program
-beside ``bench/``, the run exits non-zero and prints no result.
+The last line of standard output is one JSON object.  With a
+configuration its family refuses or has no module for, without a TPU,
+with another number of chips than the cell asks for, or without the
+program beside ``bench/``, the run exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -77,6 +79,7 @@ class Refused(Exception):
 
 
 def load_cell(name: str) -> dict:
+    from bench import families
     from bench.adapter import load_config, load_json
     path = os.path.join(ROOT, "BENCHMARK.json")
     if not os.path.exists(path):
@@ -88,7 +91,12 @@ def load_cell(name: str) -> dict:
     lim = os.path.join(ROOT, "bench", "limits", f"{name}.json")
     limits = load_json("bench", "limits", f"{name}.json") \
         if os.path.exists(lim) else None
-    return dict(bench=bench, wl=wl, c=load_config(wl["config"]),
+    c = load_config(wl["config"])
+    try:
+        families.load(c)
+    except ValueError as e:
+        raise Refused(f"configuration {wl['config']!r}: {e}") from None
+    return dict(bench=bench, wl=wl, c=c,
                 traffic=load_json("bench", "traffic", f"{wl['traffic']}.json"),
                 limits=limits)
 
@@ -125,7 +133,8 @@ class Run:
         import jax
         import numpy as np
 
-        from bench.adapter import dims, program_config, program_tree
+        from bench import families
+        from bench.adapter import program_tree
         from bench.gen import make_pool
         from repro.data.loader import LoaderConfig
         from repro.kernels.ops import platform_impl
@@ -133,8 +142,9 @@ class Run:
         from repro.train.optimizer import OptimizerConfig
         from repro.train.planner import PlannerConfig
         self.c, self.traffic, self.seed, self.chips = c, traffic, seed, chips
-        self.d = dims(c)
-        self.cfg = program_config(c, name)
+        self.fam = families.load(c)
+        self.d = self.fam.dims(c)
+        self.cfg = self.fam.program_config(c, name)
         # the launcher's host mesh over the cell's chips (all of the
         # machine's devices on the chip, which ``main`` checks)
         desc = host_descriptor(chips)
@@ -181,8 +191,7 @@ class Run:
         """Weights, optimizer state, warm-up and the checked steps.
         Returns the program's side of the check."""
         jax = self.jax
-        from bench.adapter import (entry_norms, flatten_norms, from_program,
-                                   init_weights)
+        from bench.adapter import entry_norms, flatten_norms, init_weights
         from repro import sharding as sh
         from repro.train.engine import TreeTrainEngine
         from repro.train.optimizer import init_opt_state
@@ -195,6 +204,7 @@ class Run:
         self.pipe = plans(self.cfg, self.lc, itertools.cycle(self.ptrees),
                           self.pc)
         self.it = iter(self.pipe)
+        from_program = self.fam.from_program
         norms = jax.jit(lambda t: entry_norms(from_program(t)))
         prog = {"loss": [], "grad": None, "change": None, "ok": True}
         b1, clip = self.opt_cfg.betas[0], self.opt_cfg.grad_clip
@@ -234,7 +244,6 @@ class Run:
         """The next plan, the trees it trains (as generated) and their
         work.  Not ``ok`` where the plan dropped a tree or trains one
         that the stream did not hold as it is (a grafted forest)."""
-        from bench.work import step_work
         ps = next(self.it)
         plan = ps.execution_plan()
         entries = [f.tree for f in ps.fits] + [o.tree for o in ps.oversized]
@@ -242,7 +251,7 @@ class Run:
         ok = (None not in trees and len(trees) == plan.num_trees
               and plan.dropped == 0)
         trees = [t for t in trees if t is not None]
-        return plan, trees, step_work(trees, self.d), ok
+        return plan, trees, self.fam.step_work(trees, self.d), ok
 
     # -- the window -----------------------------------------------------------
     def window(self, seconds: float, min_steps: int = 0,

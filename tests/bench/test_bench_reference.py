@@ -65,11 +65,13 @@ def test_weight_layout_is_the_programs(c):
     """The benchmark's weights fill exactly the program's parameter tree."""
     import jax
 
-    from bench.adapter import _init, program_config, to_program
+    from bench import families
+    from bench.adapter import _init
     from repro.models.model import init_params
-    cfg = program_config(c)
+    fam = families.load(c)
+    cfg = fam.program_config(c)
     want = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
-    got = jax.eval_shape(lambda: to_program(c, _init(c, jax.random.key(0))))
+    got = jax.eval_shape(lambda: fam.to_program(c, _init(c, jax.random.key(0))))
     assert jax.tree.map(lambda a: (a.shape, a.dtype), got) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), want)
 
@@ -84,11 +86,13 @@ def test_reference_spans_the_published_vocabulary(c):
     import jax.numpy as jnp
     import numpy as np
 
-    from bench.adapter import dims, init_weights
+    from bench import families
+    from bench.adapter import init_weights
     from bench.gen import Node
     from bench.reference import row_loss, step_rows
     c = dict(c, vocab_size=509)
-    V, Vp = dims(c)["V"], dims(c)["Vp"]
+    d = families.load(c).dims(c)
+    V, Vp = d["V"], d["Vp"]
     assert Vp > V
     rng = np.random.default_rng(2)
     tree = Node(12, [Node(7), Node(9)])

@@ -1,10 +1,11 @@
-"""bench/work.py against a hand count on a three-leaf tree."""
+"""bench/work.py, and the dense family's work count, against a hand
+count on a three-leaf tree."""
 import numpy as np
 
 import bench_smoke  # noqa: F401  (puts the repository on sys.path)
-from bench.adapter import dims
+from bench import families
 from bench.gen import Node
-from bench.work import matmul_params, step_work, tree_counts
+from bench.work import tree_counts
 
 
 def three_leaf_tree():
@@ -37,12 +38,13 @@ def test_pairs_match_the_dense_mask():
 
 def test_step_work_formula():
     c = bench_smoke.SMOKE_GQA
-    d = dims(c)
-    w = step_work([three_leaf_tree(), three_leaf_tree()], d)
+    fam = families.load(c)
+    d = fam.dims(c)
+    w = fam.step_work([three_leaf_tree(), three_leaf_tree()], d)
     H, Kh, hd, L, D, F, V = (d[k] for k in ("H", "Kh", "hd", "L", "D", "F",
                                             "V"))
     n_mm = L * (D * (2 * H * hd + 2 * Kh * hd) + 3 * D * F) + D * V
-    assert matmul_params(d) == n_mm
+    assert fam.matmul_params(d) == n_mm
     assert w["tokens"] == 24 and w["pairs"] == 112
     assert w["attn_flops"] == 12 * H * hd * L * 112
     assert w["flops"] == 6 * n_mm * 24 + w["attn_flops"]
@@ -51,8 +53,12 @@ def test_step_work_formula():
 
 def test_published_matmul_parameters():
     from bench.adapter import load_config
+
+    def matmul_params(name):
+        c = load_config(name)
+        fam = families.load(c)
+        return fam.matmul_params(fam.dims(c))
     # 24 layers of 12 845 056 plus the 155 582 464 head; 10 layers of
     # 46 792 704 plus the 233 373 696 tied head
-    assert matmul_params(dims(load_config("qwen1.5-0.5b"))) == 463_863_808
-    assert matmul_params(dims(load_config("qwen2-1.5b-depthcut"))) \
-        == 701_300_736
+    assert matmul_params("qwen1.5-0.5b") == 463_863_808
+    assert matmul_params("qwen2-1.5b-depthcut") == 701_300_736
