@@ -21,9 +21,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Hashable, Iterable, Optional
 
-from repro.core.plan_cost import (CompileCacheSim, packed_signature, pow2,
-                                  round_to_multiple, wave_signature,
-                                  wave_signature_of)
+from repro.core.plan_cost import (CUT_FLOOR, CompileCacheSim,
+                                  packed_signature, pow2,
+                                  round_to_multiple, wave_path_floor,
+                                  wave_signature, wave_signature_of)
 
 __all__ = ["wave_signature_of", "step_signatures", "SignatureUniverse",
            "lint_signatures", "synthetic_source", "template_source"]
@@ -117,8 +118,17 @@ class SignatureUniverse:
         b = R
         while b <= self.max_wave_rows:
             rows_opts, b = rows_opts + 1, b * 2
-        return 1 + (rows_opts * nopts(anc_cap, 8) * nopts(ncut_cap)
-                    * nopts(plen_cap) * nopts(extra_cap))
+        anc_lo, plen_lo = self._floors()
+        return 1 + (rows_opts * nopts(anc_cap, anc_lo)
+                    * nopts(ncut_cap, CUT_FLOOR) * nopts(plen_cap, plen_lo)
+                    * nopts(extra_cap, CUT_FLOOR))
+
+    def _floors(self) -> tuple[int, int]:
+        """The least ancestor-pad and capture-path-pad buckets
+        (``core/gateway.build_partition_plan``; cut and boundary-extra
+        counts start at ``CUT_FLOOR``)."""
+        fl = wave_path_floor(self.seq_len)
+        return max(8, fl), min(fl, pow2(self.capacity))
 
     def _buckets(self, cap: int, lo: int = 1) -> list[int]:
         out, b = [0], lo
@@ -139,10 +149,9 @@ class SignatureUniverse:
           - ``ncut == 0`` ⟺ ``plen == 0`` ⟺ ``n_extra == 0`` — the
             capture plans drive both the path pad and the boundary-extra
             columns, so the three vanish together (a leaf wave);
-          - ``anc == 0 ⇒ ncut ≥ 1`` — a root wave comes from partitioning
-            an oversized tree (≥ 2 fragments), so its fragments always
-            cut to children; a wave with neither gateway nor cuts would
-            be a row-sized tree, which packs instead;
+          - ``anc == 0 ⇒ ncut ≥ 1`` — a root wave's fragments cut to
+            children, and a root wave of one-fragment trees takes inert
+            cuts (``core/gateway.build_partition_plan``);
           - ``n_extra ≤ ncut`` — per-row boundary extras are bucketed
             from per-row cut counts, never exceeding the wave total.
 
@@ -156,17 +165,18 @@ class SignatureUniverse:
             rows_list.append(b)
             b *= 2
         plen_cap = min(plen_cap, pow2(self.capacity))
+        anc_lo, plen_lo = self._floors()
         for rows in rows_list:
-            for anc in self._buckets(anc_cap, lo=8):
-                for ncut in self._buckets(ncut_cap):
+            for anc in self._buckets(anc_cap, lo=anc_lo):
+                for ncut in self._buckets(ncut_cap, lo=CUT_FLOOR):
                     if ncut == 0:
                         if anc > 0:     # leaf wave: gateway in, no cuts
                             sigs.append(wave_signature(
                                 rows, self.seq_len, anc, 0, 0, 0))
                         continue
-                    for plen in self._buckets(plen_cap)[1:]:
+                    for plen in self._buckets(plen_cap, lo=plen_lo)[1:]:
                         for n_extra in self._buckets(
-                                min(extra_cap, ncut))[1:]:
+                                min(extra_cap, ncut), lo=CUT_FLOOR)[1:]:
                             sigs.append(wave_signature(
                                 rows, self.seq_len, anc, ncut, plen,
                                 n_extra))

@@ -37,10 +37,12 @@ class MoECfg:
     top_k: int
     d_expert: int                       # per-expert FFN hidden dim
     num_shared_experts: int = 0
-    capacity_factor: float = 1.25
     first_dense_layers: int = 0         # leading dense layers (DeepSeek/Kimi style)
-    router_aux_weight: float = 0.01
-    router_z_weight: float = 1e-4
+    router_aux_weight: float = 0.01     # load-balance coefficient, per branch
+    router_z_weight: float = 1e-4       # router z-loss coefficient, per branch
+    # [lo, hi): the experts this device holds (expert parallelism); the
+    # router still scores all num_experts.  None: every expert.
+    held: Optional[tuple[int, int]] = None
 
 
 @dataclass(frozen=True)

@@ -10,8 +10,7 @@ def config() -> ModelConfig:
         n_layers=61, d_model=7168, d_ff=18432, vocab_size=163840,
         attn=AttnCfg(n_heads=64, n_kv_heads=8, head_dim=128),
         moe=MoECfg(num_experts=384, top_k=8, d_expert=2048,
-                   num_shared_experts=1, first_dense_layers=1,
-                   capacity_factor=1.25),
+                   num_shared_experts=1, first_dense_layers=1),
         mlp_activation="swiglu",
         source="arXiv:2501.kimi2",
     )
@@ -22,6 +21,5 @@ def smoke() -> ModelConfig:
         n_layers=2, d_model=64, d_ff=128, vocab_size=512,
         attn=AttnCfg(n_heads=4, n_kv_heads=2, head_dim=16),
         moe=MoECfg(num_experts=4, top_k=2, d_expert=32,
-                   num_shared_experts=1, first_dense_layers=1,
-                   capacity_factor=2.0),
+                   num_shared_experts=1, first_dense_layers=1),
         dtype="float32", vocab_pad_multiple=8, name="kimi-smoke")

@@ -11,7 +11,7 @@ def config() -> ModelConfig:
         attn=AttnCfg(n_heads=40, n_kv_heads=8, head_dim=128,
                      rope_theta=5e5),
         moe=MoECfg(num_experts=16, top_k=1, d_expert=8192,
-                   num_shared_experts=1, capacity_factor=1.25),
+                   num_shared_experts=1),
         mlp_activation="swiglu",
         source="hf:meta-llama/Llama-4-Scout-17B-16E",
     )
@@ -22,5 +22,5 @@ def smoke() -> ModelConfig:
         n_layers=2, d_model=64, d_ff=128, vocab_size=512,
         attn=AttnCfg(n_heads=4, n_kv_heads=2, head_dim=16),
         moe=MoECfg(num_experts=4, top_k=1, d_expert=64,
-                   num_shared_experts=1, capacity_factor=2.0),
+                   num_shared_experts=1),
         dtype="float32", vocab_pad_multiple=8, name="llama4-smoke")

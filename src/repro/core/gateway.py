@@ -42,10 +42,11 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.packing import pack_partition_waves
 from repro.core.partition import TreePartition, partition_tree
-from repro.core.plan_cost import balanced_row_order, pow2
+from repro.core.plan_cost import (CUT_FLOOR, balanced_row_order, pow2,
+                                  wave_path_floor)
 from repro.core.tree import TrajectoryTree
 from repro.models.layers import prev_powers
-from repro.models.model import max_conv_taps, needs_chunks
+from repro.models.model import has_router_loss, max_conv_taps, needs_chunks
 from repro.models.transformer import partition_loss
 
 
@@ -66,6 +67,8 @@ def make_part_batch(cfg: ModelConfig, part: TreePartition,
         "valid": jnp.asarray(ser.valid[None]),
         "anc_pos": jnp.asarray(anc_pos[None].astype(np.int32)),
     }
+    if has_router_loss(cfg):
+        b["aux_weight"] = jnp.asarray(ser.aux_weight[None])
     if needs_chunks(cfg):
         b["chunk_parent"] = jnp.asarray(
             ser.chunk_parent_map(chunk_size)[None])
@@ -134,6 +137,10 @@ def assemble_child_gw(cfg: ModelConfig, gw_in: Optional[dict], caps: dict,
                 t: (cap[t] if prev is None else
                     jnp.concatenate([prev[t], cap[t]], axis=2))
                 for t in ("k", "v")}
+        if "moe" in group_caps:
+            # the path sums at the cut already hold this partition's own
+            # gateway sums (models/moe.router_loss)
+            cg["moe"] = {"state": group_caps["moe"][cut_name]["state"]}
         if "ssm" in group_caps:
             cap = group_caps["ssm"][cut_name]
             prev = gw_g.get("ssm")
@@ -180,6 +187,11 @@ def route_child_cot(cfg: ModelConfig, gw_in: Optional[dict], caps: dict,
                         + cot[:, :, :A_in].astype(jnp.float32))
                 cc = cot_caps[gkey]["attn"][cut_name][t]
                 cot_caps[gkey]["attn"][cut_name][t] = cc + cot[:, :, A_in:]
+        if "moe" in cg:
+            cot_caps[gkey]["moe"][cut_name]["state"] = jax.tree.map(
+                lambda a, b: a + b.astype(a.dtype),
+                cot_caps[gkey]["moe"][cut_name]["state"],
+                cg["moe"]["state"])
         if "ssm" in cg:
             cap = group_caps["ssm"][cut_name]
             prev = gw_g.get("ssm")
@@ -466,15 +478,18 @@ def _stack_gw_rows(rows: list[dict], A_max: int, Bb: int,
     return out
 
 
-def _wave_capspecs(cfg: ModelConfig, cuts: list, taps: int) -> dict:
-    """Bucketed, front-padded capture plans for one wave (runtime arrays).
+def _wave_capspecs(cfg: ModelConfig, cuts: list, taps: int,
+                   lo: int = 1, pad: bool = False) -> dict:
+    """Bucketed, front-padded capture plans for one wave (runtime arrays):
+    the path pad at least ``lo``, at least ``CUT_FLOOR`` cuts, none for a
+    wave without cuts unless ``pad``.
 
     Padded entries index position 0; their captures are trimmed before any
     use and receive zero cotangents, so they are inert."""
-    if not cuts:
+    if not cuts and not pad:
         return {}
-    plen_b = _pow2(max(len(c.path_idx) for c in cuts))
-    ncut_b = _pow2(len(cuts))
+    plen_b = _pow2(max((len(c.path_idx) for c in cuts), default=0), lo=lo)
+    ncut_b = _pow2(len(cuts), lo=CUT_FLOOR)
     specs = {}
     for i in range(ncut_b):
         if i < len(cuts):
@@ -520,6 +535,10 @@ def _cut_caps_view(cfg: ModelConfig, caps: dict, cname: str, r: int,
                     "shift": c["shift"][:, r:r + 1]}}
             elif kind == "cm":
                 h[kind] = {cname: {"shift": c["shift"][:, r:r + 1]}}
+            elif kind == "moe":
+                h[kind] = {cname: {
+                    "state": jax.tree.map(lambda a: a[:, r:r + 1],
+                                          c["state"])}}
         if h:
             out[gkey] = h
     return out
@@ -620,6 +639,10 @@ def build_partition_plan(
     seq_len = capacity if seq_len is None else seq_len
     assert capacity <= seq_len, (capacity, seq_len)
     taps = max(1, max_conv_taps(cfg))
+    # short capture paths and ancestor runs share one bucket (a path
+    # never exceeds the capacity)
+    plen_lo = min(wave_path_floor(seq_len), _pow2(capacity))
+    anc_lo = max(8, wave_path_floor(seq_len))
     info: dict[str, Any] = {"num_trees": len(trees)}
     if not trees:
         return PartitionPlan(waves=[], num_trees=0, info=info)
@@ -664,12 +687,21 @@ def build_partition_plan(
             "prev_idx": prev_np,
             "valid": _pad_rows(a["valid"], Bb, False),
         }
+        if has_router_loss(cfg):
+            batch["aux_weight"] = _pad_rows(a["aux_weight"], Bb, 0)
         if chunk_size is not None:
             batch["chunk_parent"] = _pad_rows(a["chunk_parent"], Bb, -1)
             batch["prev_pows"] = prev_powers(prev_np, taps)
-        if wv.cuts:
+        # waves are depth-homogeneous: either all root fragments (no
+        # gateway) or all gateway-bearing; parents may sit several waves
+        # back once a too-wide depth level is split under max_rows
+        has_gw = forest[wv.slots[0].tree][wv.slots[0].pid].parent_pid >= 0
+        # a root wave that cuts nothing (trees of one fragment) takes inert
+        # cuts and boundary extras, and so the program of one that cuts
+        root_pad = not has_gw and not wv.cuts
+        if wv.cuts or root_pad:
             Eb = _pow2(max(sum(1 for c in wv.cuts if c.row == r)
-                           for r in range(B)))
+                           for r in range(B)), lo=CUT_FLOOR)
             pos = np.zeros((Bb, Eb), np.int32)
             lab = np.zeros((Bb, Eb), np.int32)
             wgt = np.zeros((Bb, Eb), np.float32)
@@ -683,12 +715,8 @@ def build_partition_plan(
             batch["extra_pos"] = pos
             batch["extra_label"] = lab
             batch["extra_weight"] = wgt
-        capspecs = _wave_capspecs(cfg, wv.cuts, taps)
-
-        # waves are depth-homogeneous: either all root fragments (no
-        # gateway) or all gateway-bearing; parents may sit several waves
-        # back once a too-wide depth level is split under max_rows
-        has_gw = forest[wv.slots[0].tree][wv.slots[0].pid].parent_pid >= 0
+        capspecs = _wave_capspecs(cfg, wv.cuts, taps, lo=plen_lo,
+                                  pad=root_pad)
         parents: list[GatewayRef] = []
         A_max = 0
         anc_pos_rows: list[np.ndarray] = \
@@ -707,10 +735,11 @@ def build_partition_plan(
                 ).astype(np.int32)
                 assert len(anc_pos_rows[sl.row]) == \
                     forest[sl.tree][sl.pid].anc_len
-            # lo=8: ancestor buckets stay TPU-sublane-aligned so the fused
-            # pallas kernels get an MXU-friendly front-padded KV extension
-            # (the chunked path is indifferent; padded slots are masked)
-            A_max = _pow2(max(len(p) for p in anc_pos_rows), lo=8)
+            # at least 8: ancestor buckets stay TPU-sublane-aligned so the
+            # fused pallas kernels get an MXU-friendly front-padded KV
+            # extension (the chunked path is indifferent; padded slots are
+            # masked)
+            A_max = _pow2(max(len(p) for p in anc_pos_rows), lo=anc_lo)
             anc_pos = np.zeros((Bb, A_max), np.int32)
             anc_valid = np.zeros((Bb, A_max), bool)
             for r, p in enumerate(anc_pos_rows):
@@ -770,7 +799,7 @@ def packed_partitioned_value_and_grad(
 
     Returns ``(loss_sum, grads (float32), info)``; divide by the number of
     trees to match ``loss_and_metrics``'s mean-over-trees normalizer."""
-    from repro.train.engine import run_partition_plan
+    from repro.train.engine import NUM_SCALARS, run_partition_plan
 
     plan = build_partition_plan(cfg, trees, capacity, seq_len=seq_len,
                                 loss_mode=loss_mode, max_rows=max_rows)
@@ -778,12 +807,12 @@ def packed_partitioned_value_and_grad(
                              params)
     if not plan.waves:
         return 0.0, grads_acc, plan.info
-    scal = jnp.zeros((3,), jnp.float32)
+    scal = jnp.zeros((NUM_SCALARS,), jnp.float32)
     grads_acc, scal = run_partition_plan(
         cfg, params, plan, grads_acc, scal, impl=impl,
         loss_scale=jnp.ones((), jnp.float32), donate=False)
     # one host sync point for the scalars (loss reporting + per-token nll)
-    total_loss, nll_sum, weight_sum = np.asarray(scal)
+    total_loss, nll_sum, weight_sum = np.asarray(scal)[:3]
     info = dict(plan.info)
     info["weight_sum"] = float(weight_sum)
     info["nll_sum"] = float(nll_sum)

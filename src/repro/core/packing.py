@@ -42,6 +42,8 @@ class TreeBatch:
     num_trees: int = 1        # loss normalizer (mean over trees)
     extra_embeds: Optional[np.ndarray] = None  # f32 [B, T_src, D] frontend stub
     row_trees: Optional[np.ndarray] = None     # i32 [B] trees per row
+    aux_weight: Optional[np.ndarray] = None    # f32 [B, S] λ of branches
+    #                                            ending at each token
 
     @property
     def shape(self) -> tuple[int, int]:
@@ -58,7 +60,8 @@ class TreeBatch:
                          self.kv_last[b:b + 1], self.weight[b:b + 1],
                          self.prev_idx[b:b + 1], self.valid[b:b + 1],
                          sl(self.chunk_parent), max(n, 1),
-                         sl(self.extra_embeds), sl(self.row_trees))
+                         sl(self.extra_embeds), sl(self.row_trees),
+                         sl(self.aux_weight))
 
 
 def _empty_row(S: int) -> dict[str, np.ndarray]:
@@ -69,6 +72,7 @@ def _empty_row(S: int) -> dict[str, np.ndarray]:
         weight=np.zeros(S, np.float32),
         prev_idx=np.full(S, -1, np.int32),
         valid=np.zeros(S, bool),
+        aux_weight=np.zeros(S, np.float32),
     )
 
 
@@ -138,7 +142,8 @@ def materialize_tree_rows(
     count = (lambda i: 1) if tree_counts is None \
         else (lambda i: int(tree_counts[i]))
     cols = {k: [] for k in
-            ("tokens", "pos_ids", "kv_last", "weight", "prev_idx", "valid")}
+            ("tokens", "pos_ids", "kv_last", "weight", "prev_idx", "valid",
+             "aux_weight")}
     chunk_rows: list[np.ndarray] = []
     C = None if chunk_size is None else S // chunk_size
 
@@ -156,6 +161,7 @@ def materialize_tree_rows(
             row["prev_idx"][sl] = np.where(t.prev_idx < 0, -1,
                                            t.prev_idx + off)
             row["valid"][sl] = t.valid
+            row["aux_weight"][sl] = t.aux_weight
             if C is not None:
                 assert off % chunk_size == 0 and t.n % chunk_size == 0, \
                     "SSM packing requires chunk-aligned trees"
@@ -179,6 +185,7 @@ def materialize_tree_rows(
         num_trees=sum(count(i) for r in rows for i in r),
         row_trees=np.asarray([sum(count(i) for i in r) for r in rows],
                              np.int32),
+        aux_weight=np.stack(cols["aux_weight"]),
     )
 
 
@@ -225,15 +232,18 @@ def pack_linear_paths(
         K = len(paths)
         for p in paths:
             q = dict(p)
+            # lam: the branch's λ, which its router loss is weighted by
             if loss_mode == "sep_avg":
-                w = p["advantage"] / K
+                w, lam = p["advantage"] / K, 1.0 / K
             elif loss_mode == "uniform":
-                w = p["advantage"]
+                w, lam = p["advantage"], 1.0
             elif loss_mode == "rl":
                 w = p["advantage"] * p.get("branch_adv", 1.0) / K
+                lam = p.get("branch_adv", 1.0) / K
             else:
                 raise ValueError(loss_mode)
             q["_w"] = np.where(p["trained"], w, 0.0).astype(np.float32)
+            q["_lam"] = lam
             q["_tree"] = ti
             flat.append(q)
 
@@ -267,7 +277,8 @@ def pack_linear_paths(
     S = seq_len
     C = None if chunk_size is None else S // chunk_size
     out = {k: [] for k in
-           ("tokens", "pos_ids", "kv_last", "weight", "prev_idx", "valid")}
+           ("tokens", "pos_ids", "kv_last", "weight", "prev_idx", "valid",
+            "aux_weight")}
     chunk_rows = []
     for r in rows:
         row = _empty_row(S)
@@ -286,6 +297,7 @@ def pack_linear_paths(
             pv[0] = -1
             row["prev_idx"][sl] = pv
             row["valid"][sl] = True
+            row["aux_weight"][off + n - 1] = p["_lam"]
             if C is not None:
                 c0, c1 = off // chunk_size, (off + na) // chunk_size
                 for c in range(c0, c1):
@@ -307,6 +319,7 @@ def pack_linear_paths(
         num_trees=len(trees_paths),
         row_trees=np.asarray(
             [len({flat[i]["_tree"] for i in r}) for r in rows], np.int32),
+        aux_weight=np.stack(out["aux_weight"]),
     )
 
 
@@ -391,7 +404,7 @@ def pack_partition_waves(
         C = None if chunk_size is None else S // chunk_size
         cols = {k: np.stack([_empty_row(S)[k] for _ in range(B)])
                 for k in ("tokens", "pos_ids", "kv_last", "weight",
-                          "prev_idx", "valid")}
+                          "prev_idx", "valid", "aux_weight")}
         cp = None if C is None else np.full((B, C), -1, np.int32)
         cuts: list[PackedCut] = []
         for pl in placements:
@@ -407,6 +420,7 @@ def pack_partition_waves(
             cols["prev_idx"][pl.row, sl] = np.where(
                 ser.prev_idx < 0, ser.prev_idx, ser.prev_idx + off)
             cols["valid"][pl.row, sl] = ser.valid
+            cols["aux_weight"][pl.row, sl] = ser.aux_weight
             if C is not None:
                 assert off % chunk_size == 0 and ser.n % chunk_size == 0, \
                     "SSM wave packing requires chunk-aligned partitions"

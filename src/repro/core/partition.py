@@ -177,11 +177,14 @@ def partition_tree(
         # pruned copy: descend until cut nodes; record cut children
         cut_children: list[tuple[TreeNode, TreeNode]] = []  # (pruned_anc, orig_child)
         lam_local: dict[int, float] = {}
+        leaves: set[int] = set()         # pruned nodes that end a branch
 
         def prune(n: TreeNode) -> TreeNode:
             m = TreeNode(tokens=n.tokens, trained=n.trained,
                          advantage=n.advantage, branch_adv=n.branch_adv)
             lam_local[id(m)] = lam_map[id(n)]
+            if not n.children:
+                leaves.add(id(m))
             for c in n.children:
                 if id(c) in cut:
                     cut_children.append((m, c))
@@ -192,7 +195,7 @@ def partition_tree(
         proot = prune(root)
         psub = TrajectoryTree(root=proot)
         ser = serialize_tree(psub, chunk_size=chunk_size, lam_map=lam_local,
-                             depth_pos0=anc_len,
+                             leaf_ids=leaves, depth_pos0=anc_len,
                              root_prev=-2 if parent_pid >= 0 or anc_len > 0
                              else -1)
         part = TreePartition(pid=pid, parent_pid=parent_pid, ser=ser,

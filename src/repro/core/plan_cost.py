@@ -59,6 +59,19 @@ def pow2(n: int, lo: int = 1) -> int:
     return b
 
 
+# the least bucket of a wave's cut count and boundary extras a row: one
+# cut shares the program of two
+CUT_FLOOR = 2
+
+
+def wave_path_floor(seq_len: int) -> int:
+    """The least bucket of a wave's ancestor pad and capture-path pad: a
+    sixteenth of a row.  Every distinct wave shape compiles the model's
+    forward and backward once; runs shorter than this cost a row little
+    to pad, so they share one program."""
+    return pow2(max(seq_len // 16, 1))
+
+
 def round_to_multiple(n: int, m: int) -> int:
     """Smallest multiple of m ≥ n (replica-balanced row counts)."""
     if m <= 1:

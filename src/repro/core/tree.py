@@ -18,6 +18,8 @@ sequence *equivalent* to running every path independently:
     branching node gather the same parent row — their losses (and gradients)
     accumulate there exactly as the per-branch baseline would.
   - ``node_id[t]``, ``chunk_parent`` : SSM chunk-grid metadata (§3.2 SSM).
+  - ``aux_weight[t]`` : Σ of λ over the branches whose last token is t —
+    where a branch's own per-branch terms (the MoE router loss) sit.
 
 All host-side, numpy only; the jitted model consumes the arrays.
 """
@@ -148,6 +150,7 @@ class SerializedTree:
     node_start: np.ndarray    # i32 [num_nodes] DFS start offset of node segment
     node_end: np.ndarray      # i32 [num_nodes] end offset (exclusive, incl. pad)
     num_paths: int            # K
+    aux_weight: Optional[np.ndarray] = None  # f32 [N] λ of branches ending at t
 
     @property
     def n(self) -> int:
@@ -246,6 +249,7 @@ def serialize_tree(
     lam_map: Optional[dict[int, float]] = None,
     depth_pos0: int = 0,
     root_prev: int = -1,
+    leaf_ids: Optional[set] = None,
 ) -> SerializedTree:
     """DFS-serialize ``tree``; every token appears exactly once (Eq. 8).
 
@@ -265,7 +269,10 @@ def serialize_tree(
       depth_pos0 : depth position of the first token (= #ancestor tokens);
       root_prev  : prev_idx of the very first token; −2 means "gateway
                    context slot 0" (the immediate ancestor relayed through
-                   the partition boundary — see models/layers.gather_prev).
+                   the partition boundary — see models/layers.gather_prev);
+      leaf_ids   : ids of the nodes that end a branch (a partition's pruned
+                   subtree keeps only its true leaves); default: the nodes
+                   without children.
     """
     g = _leaf_counts(tree.root)
     K = g[id(tree.root)]
@@ -281,6 +288,7 @@ def serialize_tree(
     node_parent: list[int] = []
     node_start: list[int] = []
     node_end: list[int] = []
+    ends: list[tuple[int, float]] = []   # (last token, λ) of each branch
 
     cursor = 0  # DFS token offset
 
@@ -335,6 +343,8 @@ def serialize_tree(
         cursor += L + P
 
         my_last_tok = start + L - 1 if L > 0 else parent_last_tok
+        if (not node.children) if leaf_ids is None else id(node) in leaf_ids:
+            ends.append((my_last_tok, lam))
         for c in node.children:
             rec(c, depth_pos + L, my_nid, my_last_tok)
         subtree_end = cursor
@@ -369,6 +379,10 @@ def serialize_tree(
         k[real] = node_sub_end[i] - 1
         kv_last[seg] = k
 
+    aux_weight = np.zeros(n_total, np.float32)
+    for t, lam in ends:
+        if t >= 0:
+            aux_weight[t] += lam
     return SerializedTree(
         tokens=np.concatenate(toks),
         pos_ids=np.concatenate(pos),
@@ -381,6 +395,7 @@ def serialize_tree(
         node_start=np.asarray(node_start, np.int32),
         node_end=np.asarray(node_end, np.int32),
         num_paths=K,
+        aux_weight=aux_weight,
     )
 
 

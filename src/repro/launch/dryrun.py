@@ -39,8 +39,9 @@ from repro.configs import (ARCH_IDS, get_config,     # noqa: E402
 from repro.configs.base import INPUT_SHAPES, ModelConfig  # noqa: E402
 from repro.launch.mesh import data_axes, make_production_mesh  # noqa: E402
 from repro.models.layers import logits_from_hidden   # noqa: E402
-from repro.models.model import (loss_and_metrics,    # noqa: E402
-                                max_conv_taps, needs_chunks)
+from repro.models.model import (has_router_loss,     # noqa: E402
+                                loss_and_metrics, max_conv_taps,
+                                needs_chunks)
 from repro.models import transformer as tf           # noqa: E402
 from repro.serve import decode as serve              # noqa: E402
 from repro.train.optimizer import (OptimizerConfig,  # noqa: E402
@@ -66,6 +67,8 @@ def train_batch_specs(cfg: ModelConfig, B: int, S: int) -> dict:
         "prev_idx": SDS((B, S), jnp.int32),
         "valid": SDS((B, S), jnp.bool_),
     }
+    if has_router_loss(cfg):
+        d["aux_weight"] = SDS((B, S), jnp.float32)
     if needs_chunks(cfg):
         d["chunk_parent"] = SDS((B, S // cfg.ssm.chunk_size), jnp.int32)
         d["prev_pows"] = SDS((B, S, max(1, max_conv_taps(cfg))), jnp.int32)

@@ -13,7 +13,14 @@ from repro.models.transformer import (forward, init_params, layer_groups,
                                       loss_and_metrics)
 
 __all__ = ["init_params", "forward", "loss_and_metrics", "prepare_batch",
-           "needs_chunks", "max_conv_taps", "layer_groups"]
+           "needs_chunks", "max_conv_taps", "layer_groups",
+           "has_router_loss"]
+
+
+def has_router_loss(cfg: ModelConfig) -> bool:
+    """Whether the model takes the per-branch ``aux_weight`` column (its
+    router loss is summed per branch, ``models/moe.router_loss``)."""
+    return cfg.moe is not None
 
 
 def needs_chunks(cfg: ModelConfig) -> bool:
@@ -48,6 +55,8 @@ def prepare_batch(cfg: ModelConfig, tb: TreeBatch,
         "valid": jnp.asarray(tb.valid),
         "num_trees": tb.num_trees if num_trees is None else num_trees,
     }
+    if has_router_loss(cfg):
+        d["aux_weight"] = jnp.asarray(tb.aux_weight)
     if needs_chunks(cfg):
         assert tb.chunk_parent is not None, \
             f"{cfg.name} needs chunk-aligned serialization (SSM family)"

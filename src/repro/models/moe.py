@@ -1,44 +1,69 @@
-"""Mixture-of-Experts layer: top-k router, capacity-based scatter dispatch,
-optional shared experts, load-balance + z auxiliary losses.
+"""Mixture-of-Experts layer: a full-width router, dropless dispatch to the
+experts this device holds, grouped matrix products, and the router loss
+per branch.
 
-Expert-parallel: every expert-indexed tensor ([E, ...]) is sharded over the
-"model" mesh axis; the dispatch/combine reshards ([N,D]→[E,C,D] and back)
-are where GSPMD inserts the all-to-all — the same communication pattern as
-Megatron/DeepSeek expert parallelism, derived instead of hand-written.
+Expert parallelism: ``MoECfg.held = (lo, hi)`` names the experts whose
+weights this layer carries (all of them by default).  The router always
+scores every expert — softmax, top-k, renormalised over the k — so
+routing is what the whole model would route; a
+token's slot whose expert is held is computed here, a slot whose expert
+is not held is another device's share and contributes nothing here.
+The shares of all devices add up to the whole layer.
 
-Scatter/gather dispatch is O(N) memory (no [N,E,C] one-hots), which is what
-makes kimi-k2's 384 experts lowerable.  On real TPU the expert GEMMs would
-use a megablox/ragged-dot kernel; the dispatch math is identical.
+No token is dropped: every held slot is sorted by expert into one buffer
+of N·k rows (the worst case, every slot held), and the held experts run
+over it as grouped matrix products whose work follows the rows actually
+routed to them (``kernels/grouped_matmul`` on a TPU; ``lax.ragged_dot``
+elsewhere — the platform chooses, as it does for attention).  Results go
+back to token order and are summed, each weighted by its slot's routing
+probability.
 
-Tree Training interaction (paper §5): routing is per-token, so computing
-each unique token once routes it once — identical to what every per-branch
-pass would compute for the shared prefix.  No adaptation needed beyond the
-attention/SSM fixes; the router sees DFS rows transparently.
+Router loss (``router_loss``): per-branch training computes, for each
+root-to-leaf branch b of T_b tokens, the load-balance term
+E · Σ_e (c_be / T_b) · (P_be / T_b) — c_be the branch's top-k slots routed
+to expert e, P_be its summed router probability of e — and the z term
+Σ_t lse_t² / T_b.  Both are sums along the branch's own path, so tree
+training keeps per-node quantities and gathers them along each path: the
+counts by path sums over the token chain (``prev_idx``), and the
+gradient of Σ_b u_b · P_b (u_b the branch's coefficient) as the sum of
+u over the branch ends in each token's subtree, which in DFS order is
+the token's own range ``[t, kv_last[t]]``.  Each branch is weighted by
+its leaf's λ (``core/tree.serialize_tree``'s ``aux_weight``), the weight
+its own tokens' loss carries, so a prefix shared by k branches counts k
+times, as in per-branch training.  Across a partition boundary the path
+sums travel in the gateway like attention's ancestor KV.
 """
 from __future__ import annotations
 
+from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoECfg
+from repro.kernels.grouped_matmul import gmm
 from repro.models.layers import _dense_init
+
+
+def held_range(cfg: MoECfg) -> tuple[int, int]:
+    return cfg.held if cfg.held is not None else (0, cfg.num_experts)
 
 
 def init_moe(key, cfg: MoECfg, d_model: int, activation: str,
              dtype=jnp.float32) -> dict:
     ks = jax.random.split(key, 6)
     E, F = cfg.num_experts, cfg.d_expert
+    lo, hi = held_range(cfg)
+    H = hi - lo
     p = {
         "router": _dense_init(ks[0], (d_model, E), scale=0.02,
                               dtype=jnp.float32),
-        "wo": _dense_init(ks[3], (E, F, d_model), dtype=dtype),
+        "wo": _dense_init(ks[3], (H, F, d_model), dtype=dtype),
     }
     if activation == "swiglu":
-        p["wi_gate"] = _dense_init(ks[1], (E, d_model, F), dtype=dtype)
-        p["wi_up"] = _dense_init(ks[2], (E, d_model, F), dtype=dtype)
-    else:
-        p["wi_up"] = _dense_init(ks[2], (E, d_model, F), dtype=dtype)
+        p["wi_gate"] = _dense_init(ks[1], (H, d_model, F), dtype=dtype)
+    p["wi_up"] = _dense_init(ks[2], (H, d_model, F), dtype=dtype)
     if cfg.num_shared_experts:
         Fs = F * cfg.num_shared_experts
         if activation == "swiglu":
@@ -49,76 +74,209 @@ def init_moe(key, cfg: MoECfg, d_model: int, activation: str,
     return p
 
 
-def _act(p: dict, x: jax.Array, activation: str, prefix: str = "") -> jax.Array:
+def _act(h_gate, h_up, activation: str):
     if activation == "swiglu":
-        return jax.nn.silu(x @ p[prefix + "wi_gate"]) * (x @ p[prefix + "wi_up"])
-    h = x @ p[prefix + "wi_up"]
+        return jax.nn.silu(h_gate) * h_up
     if activation == "squared_relu":
-        r = jax.nn.relu(h)
+        r = jax.nn.relu(h_up)
         return r * r
-    return jax.nn.relu(h)
+    return jax.nn.relu(h_up)
 
+
+def platform_matmul() -> str:
+    """The grouped product this platform runs: the Pallas kernels on one
+    TPU; ``lax.ragged_dot`` elsewhere and on a mesh of several devices,
+    which XLA partitions (it cannot partition a Pallas call)."""
+    from repro import sharding as sh
+    mesh = sh.current_mesh()
+    if jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1):
+        return "pallas"
+    return "ragged"
+
+
+def _grouped(kind: str):
+    if kind == "ragged":
+        return jax.lax.ragged_dot
+    interpret = kind == "interpret"
+    return lambda x, w, gs: gmm(x, w, gs, interpret)
+
+
+# ---------------------------------------------------------------------------
+# Row permutations whose transposes are gathers by the inverse
+# permutation, not scatter-adds with repeated indices
+# ---------------------------------------------------------------------------
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k: int):
+    """x [N, D] → [N·k, D]: row r is the token of slot ``order[r]``."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return _dispatch(x, order, inv, k), (order, inv)
+
+
+def _dispatch_bwd(k, res, g):
+    order, inv = res
+    n = g.shape[0] // k
+    return g[inv].reshape(n, k, -1).sum(1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(a, idx, inv):
+    """a[idx], ``idx`` a permutation whose inverse is ``inv``."""
+    return a[idx]
+
+
+def _permute_fwd(a, idx, inv):
+    return a[idx], (idx, inv)
+
+
+def _permute_bwd(res, g):
+    idx, inv = res
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
 
 def moe(params: dict, cfg: MoECfg, x: jax.Array, valid: jax.Array,
         activation: str) -> tuple[jax.Array, dict]:
-    """x: [B, S, D]; valid: [B, S] bool.  Returns (y, aux_losses)."""
+    """x: [B, S, D]; valid: [B, S] bool.  Returns ``(y, route)``: the
+    held experts' share of the layer, and per token the router's
+    quantities that ``router_loss`` takes (``cnt`` [B, S, E]: the token's
+    top-k slots per expert, over every expert; ``feat`` [B, S, E+1]: its
+    router probabilities and squared log-sum-exp; zero on invalid
+    tokens) plus ``held_rows``, the slots this layer computed."""
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.top_k
+    lo, hi = held_range(cfg)
+    H = hi - lo
     N = B * S
     xf = x.reshape(N, D)
     vmask = valid.reshape(N)
 
-    logits = (xf @ params["router"]).astype(jnp.float32)      # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                    # [N, K]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_route"):
+        # float32 at full precision: which experts a token reaches must
+        # not turn on the activations' rounding
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)   # [N, E]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        top_p, top_e = jax.lax.top_k(probs, K)                  # [N, K]
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-20)
+        held = (top_e >= lo) & (top_e < hi) & vmask[:, None]    # [N, K]
+        gid = jnp.where(held, top_e - lo, H).reshape(N * K)
+        order = jnp.argsort(gid, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * K, dtype=jnp.int32), unique_indices=True)
+        sizes = jax.nn.one_hot(gid, H, dtype=jnp.int32).sum(0)
+        xs = _dispatch(xf, order, inv, K)                       # [N·K, D]
 
-    C = int(max(1, round(N * K / E * cfg.capacity_factor)))
-    # position of each (token, slot) within its expert queue
-    oh = jax.nn.one_hot(top_e, E, dtype=jnp.int32)            # [N, K, E]
-    oh = oh * vmask[:, None, None].astype(jnp.int32)          # pads don't queue
-    pos = jnp.cumsum(oh.reshape(N * K, E), axis=0) - 1        # [N·K, E]
-    pos = jnp.take_along_axis(pos, top_e.reshape(N * K, 1), axis=1)[:, 0]
-    e_flat = top_e.reshape(N * K)
-    keep = (pos >= 0) & (pos < C) & jnp.repeat(vmask, K)
-    pos_c = jnp.where(keep, pos, C)                           # C = drop slot
+    with jax.named_scope("moe_experts"):
+        mm = _grouped(platform_matmul())
+        gate = mm(xs, params["wi_gate"], sizes) \
+            if "wi_gate" in params else None
+        h = _act(gate, mm(xs, params["wi_up"], sizes), activation)
+        ys = mm(h.astype(xs.dtype), params["wo"], sizes)        # [N·K, D]
 
-    # dispatch: [E, C+1, D] (last row is the spill bucket)
-    xb = jnp.zeros((E, C + 1, D), x.dtype)
-    src = jnp.repeat(xf, K, axis=0)                           # [N·K, D]
-    xb = xb.at[e_flat, pos_c].add(src, mode="drop")
-    xb = xb[:, :C]
-
-    # expert FFN (einsum over stacked experts)
-    if "wi_gate" in params:
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xb, params["wi_gate"])) \
-            * jnp.einsum("ecd,edf->ecf", xb, params["wi_up"])
-    else:
-        h = jnp.einsum("ecd,edf->ecf", xb, params["wi_up"])
-        if activation == "squared_relu":
-            r = jax.nn.relu(h)
-            h = r * r
-        else:
-            h = jax.nn.relu(h)
-    yb = jnp.einsum("ecf,efd->ecd", h, params["wo"])          # [E, C, D]
-
-    # combine
-    yb = jnp.concatenate([yb, jnp.zeros((E, 1, D), yb.dtype)], axis=1)
-    gathered = yb[e_flat, pos_c]                              # [N·K, D]
-    w = jnp.where(keep, top_p.reshape(N * K), 0.0).astype(x.dtype)
-    y = (gathered * w[:, None]).reshape(N, K, D).sum(axis=1)
+    with jax.named_scope("moe_route"):
+        w = jnp.where(held, top_p, 0.0).astype(x.dtype)
+        y = (_permute(ys, inv, order).reshape(N, K, D)
+             * w[..., None]).sum(axis=1)
 
     if "shared_wi_up" in params:
-        y = y + _act(params, xf, activation, "shared_") @ params["shared_wo"]
+        sg = xf @ params["shared_wi_gate"] \
+            if "shared_wi_gate" in params else None
+        y = y + _act(sg, xf @ params["shared_wi_up"], activation) \
+            @ params["shared_wo"]
 
-    # aux losses (over valid tokens)
-    nv = jnp.maximum(vmask.sum(), 1).astype(jnp.float32)
-    frac = (oh.sum(1).astype(jnp.float32) * vmask[:, None]).sum(0) / (nv * K)
-    pmean = (probs * vmask[:, None]).sum(0) / nv
-    aux = {
-        "load_balance": E * jnp.sum(frac * pmean) * cfg.router_aux_weight,
-        "router_z": (jnp.where(vmask,
-                               jax.nn.logsumexp(logits, -1) ** 2, 0.0).sum()
-                     / nv) * cfg.router_z_weight,
-    }
-    return y.reshape(B, S, D), aux
+    vf = vmask.astype(jnp.float32)[:, None]
+    cnt = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(1) * vf
+    feat = jnp.concatenate([probs, (lse * lse)[:, None]], -1) * vf
+    route = {"cnt": jax.lax.stop_gradient(cnt).reshape(B, S, E),
+             "feat": feat.reshape(B, S, E + 1),
+             "held_rows": jnp.sum(sizes)}
+    return y.reshape(B, S, D), route
+
+
+# ---------------------------------------------------------------------------
+# Router loss per branch
+# ---------------------------------------------------------------------------
+
+def path_sums(x: jax.Array, prev_idx: jax.Array) -> jax.Array:
+    """x [B, S, C] → Σ over each token's path (itself and every token
+    before it on its branch, following ``prev_idx``; a negative index
+    ends the path), by pointer doubling: log2 S rounds of gathers."""
+    S = x.shape[1]
+    ptr = prev_idx
+    for _ in range(max(1, (S - 1).bit_length())):
+        ok = ptr >= 0
+        safe = jnp.maximum(ptr, 0)
+        x = x + jnp.where(ok[..., None],
+                          jnp.take_along_axis(x, safe[..., None], axis=1),
+                          0.0)
+        ptr = jnp.where(ok, jnp.take_along_axis(ptr, safe, axis=1), -1)
+    return x
+
+
+def router_loss(cfg: MoECfg, route: dict, meta: dict,
+                gw: Optional[dict] = None,
+                cap_idx: Optional[dict] = None
+                ) -> tuple[jax.Array, dict]:
+    """The layer's router loss summed over the branches that end in this
+    batch, each weighted by ``meta["aux_weight"]`` at its last token, and
+    the path sums at each cut's last path token (``cap_idx``: cut name →
+    that partition's path token indices) for the child partitions.
+
+    ``gw`` (a partition's gateway): ``cnt`` [B, E] and ``feat`` [B, E+1]
+    summed over the ancestors in earlier partitions; every token of a
+    gateway row descends from them."""
+    cnt, feat = route["cnt"], route["feat"]
+    B, S, E = cnt.shape
+    prev, kv_last = meta["prev_idx"], meta["kv_last"]
+    valid = meta["valid"]
+    aw = meta["aux_weight"].astype(jnp.float32)
+    gcnt = None if gw is None else gw["cnt"].astype(jnp.float32)
+    gfeat = None if gw is None else gw["feat"]
+
+    # counts along each path (integers: exact in any order); a gateway
+    # row's root token takes its ancestors' counts
+    base = cnt
+    if gcnt is not None:
+        base = base + jnp.where((prev == -2)[..., None], gcnt[:, None], 0.0)
+    c_path = jax.lax.stop_gradient(path_sums(base, prev))
+    T = (meta["pos_ids"] + 1).astype(jnp.float32)[..., None]
+    # each branch end's coefficient on its path's summed features
+    u = aw[..., None] * jnp.concatenate(
+        [cfg.router_aux_weight * E * c_path / (T * T),
+         jnp.broadcast_to(cfg.router_z_weight / T, (B, S, 1))], -1)
+    # Σ of u over the ends in each token's subtree [t, kv_last[t]]
+    U = jnp.cumsum(u, axis=1)
+    t = jnp.arange(S)
+    hi = jnp.take_along_axis(U, jnp.maximum(kv_last, 0)[..., None], axis=1)
+    lo = jnp.pad(U, ((0, 0), (1, 0), (0, 0)))[:, :-1]
+    live = (valid & (kv_last >= t[None]))[..., None]
+    G = jax.lax.stop_gradient(jnp.where(live, hi - lo, 0.0))
+    aux = jnp.sum(feat * G)
+    if gfeat is not None:
+        aux = aux + jnp.sum(gfeat * jax.lax.stop_gradient(u.sum(1)))
+
+    caps: dict = {}
+    for name, idx in (cap_idx or {}).items():
+        c = idx[-1]
+        on_path = (t <= c)[None] & (kv_last >= c) & valid      # [B, S]
+        fc = jnp.einsum("bs,bsc->bc", on_path.astype(jnp.float32), feat,
+                        precision=jax.lax.Precision.HIGHEST)
+        if gfeat is not None:
+            fc = fc + gfeat
+        caps[name] = {"state": {"cnt": c_path[:, c], "feat": fc}}
+    return aux, caps

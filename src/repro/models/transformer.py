@@ -21,7 +21,7 @@ from repro.models.attention import (attention, init_attention,
 from repro.models.layers import (embed, init_embedding, init_lm_head,
                                  init_mlp, init_rmsnorm, logits_from_hidden,
                                  mlp, rmsnorm, _dense_init)
-from repro.models.moe import init_moe, moe
+from repro.models.moe import init_moe, moe, router_loss
 from repro.models.ssm.gdn import gdn, init_gdn
 from repro.models.ssm.mamba2 import init_mamba2, mamba2
 from repro.models.ssm.rwkv6 import (init_rwkv6_channelmix,
@@ -32,6 +32,25 @@ from repro.sharding import shard_activation, shard_logits
 
 def _dtype(cfg: ModelConfig):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+
+
+# a layer's auxiliary outputs, summed over the layers: the router loss
+# (per branch, ``models/moe.router_loss``) and the slots the held experts
+# computed (``moe_held_rows``)
+N_AUX = 2
+
+
+def _no_aux() -> jax.Array:
+    return jnp.zeros((N_AUX,), jnp.float32)
+
+
+def _vocab_logits(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
+    """The head's rows past ``vocab_size`` (padding to a multiple) are no
+    tokens: no probability falls on them."""
+    if cfg.padded_vocab == cfg.vocab_size:
+        return logits
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return jnp.where(col < cfg.vocab_size, logits, -jnp.inf)
 
 
 # ---------------------------------------------------------------------------
@@ -90,7 +109,7 @@ def _init_layer(cfg: ModelConfig, key, kind: str) -> dict:
 def _apply_layer(cfg: ModelConfig, p: dict, kind: str, x: jax.Array,
                  meta: dict, impl: str, gw=None, capspec=None
                  ) -> tuple[jax.Array, jax.Array, dict]:
-    """Returns (x, aux_loss_scalar, captures).
+    """Returns (x, aux [N_AUX], captures).
 
     gw: per-layer partition-gateway inputs (ancestor KV / SSM state / conv
     and shift contexts) — None outside partition mode.
@@ -98,7 +117,7 @@ def _apply_layer(cfg: ModelConfig, p: dict, kind: str, x: jax.Array,
     {path_idx, cut_chunk, conv_pos, shift_pos} (numpy index arrays).
     """
     eps = cfg.norm_eps
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     caps: dict = {}
     gw = gw or {}
     if kind in ("dense", "moe", "encoder"):
@@ -119,9 +138,18 @@ def _apply_layer(cfg: ModelConfig, p: dict, kind: str, x: jax.Array,
         x = shard_activation(x + a)
         h = rmsnorm(p["ln2"], x, eps)
         if kind == "moe":
-            m, auxd = moe(p["moe"], cfg.moe, h, meta["valid"],
-                          cfg.mlp_activation)
-            aux = aux + sum(auxd.values())
+            m, route = moe(p["moe"], cfg.moe, h, meta["valid"],
+                           cfg.mlp_activation)
+            rl = jnp.zeros((), jnp.float32)
+            if "aux_weight" in meta:
+                gm = gw.get("moe")
+                rl, caps_m = router_loss(
+                    cfg.moe, route, meta,
+                    None if gm is None else gm["state"], cap_idx)
+                if cap_idx is not None:
+                    caps["moe"] = caps_m
+            aux = aux + jnp.stack([rl, route["held_rows"].astype(
+                jnp.float32)])
         else:
             m = mlp(p["mlp"], h, cfg.mlp_activation)
         x = shard_activation(x + m)
@@ -291,7 +319,7 @@ def _scan_group(cfg: ModelConfig, stacked: dict, kind: str, x: jax.Array,
                 LIVE_TABLES))
 
     (x, aux), caps = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (stacked, gw or {}))
+        body, (x, _no_aux()), (stacked, gw or {}))
     if capspec is None:
         return x, aux
     return x, aux, caps
@@ -320,7 +348,7 @@ def _mm_prefix_meta(cfg: ModelConfig, batch: dict) -> dict:
 
 def forward(cfg: ModelConfig, params: dict, batch: dict,
             impl: str = "ref") -> tuple[jax.Array, jax.Array]:
-    """Returns (hidden [B, S_text, D] post-final-norm, aux_loss)."""
+    """Returns (hidden [B, S_text, D] post-final-norm, aux [N_AUX])."""
     group_kinds = [g[0] for g in layer_groups(cfg)]
     dt = _dtype(cfg)
     F = 0
@@ -334,7 +362,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
         meta = dict(pos_ids=batch["pos_ids"], kv_last=batch["kv_last"],
                     prev_idx=batch["prev_idx"], valid=batch["valid"])
         x = embed(params["embed"], batch["tokens"])
-    for k in ("chunk_parent", "prev_pows"):
+    for k in ("chunk_parent", "prev_pows", "aux_weight"):
         if k in batch:
             meta[k] = batch[k]
     x = shard_activation(x)
@@ -353,7 +381,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict,
         meta["enc_out"] = rmsnorm(params["enc_norm"], enc_x, cfg.norm_eps)
         meta["enc_valid"] = enc_valid
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     if cfg.family == "hybrid":
         x, aux = _hybrid_forward(cfg, params, x, meta, impl)
     else:
@@ -374,7 +402,7 @@ def _hybrid_forward(cfg: ModelConfig, params: dict, x: jax.Array,
     stacked = params["layer_stacks"][0]
     L = cfg.n_layers
     k = cfg.hybrid.attn_every
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     i = 0
     while i < L:
         j = min(i + k, L)
@@ -422,12 +450,13 @@ def partition_forward(cfg: ModelConfig, params: dict, batch: dict,
     groups = layer_groups(cfg)
     meta = dict(pos_ids=batch["pos_ids"], kv_last=batch["kv_last"],
                 prev_idx=batch["prev_idx"], valid=batch["valid"])
-    for k in ("chunk_parent", "prev_pows", "anc_pos", "anc_valid"):
+    for k in ("chunk_parent", "prev_pows", "anc_pos", "anc_valid",
+              "aux_weight"):
         if k in batch:
             meta[k] = batch[k]
     x = shard_activation(embed(params["embed"], batch["tokens"]))
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux()
     caps_all: dict = {}
     gw_in = gw_in or {}
     if cfg.family == "hybrid":
@@ -495,8 +524,8 @@ def partition_loss(cfg: ModelConfig, params: dict, batch: dict, gw_in,
     h_prev = jnp.take_along_axis(hidden, jnp.maximum(prev, 0)[..., None],
                                  axis=1)
     with jax.named_scope("lm_head"):
-        logits = shard_logits(logits_from_hidden(params["embed"], head,
-                                                 h_prev))
+        logits = _vocab_logits(cfg, shard_logits(logits_from_hidden(
+            params["embed"], head, h_prev)))
         lse = jax.nn.logsumexp(logits, axis=-1)
         lab = jnp.take_along_axis(logits, batch["tokens"][..., None],
                                   axis=-1)
@@ -507,7 +536,8 @@ def partition_loss(cfg: ModelConfig, params: dict, batch: dict, gw_in,
         h_b = jnp.take_along_axis(hidden, batch["extra_pos"][..., None],
                                   axis=1)
         with jax.named_scope("lm_head"):
-            lg = logits_from_hidden(params["embed"], head, h_b)
+            lg = _vocab_logits(cfg, logits_from_hidden(params["embed"],
+                                                       head, h_b))
             lse_b = jax.nn.logsumexp(lg, axis=-1)
             lab_b = jnp.take_along_axis(lg, batch["extra_label"][..., None],
                                         axis=-1)[..., 0]
@@ -518,8 +548,8 @@ def partition_loss(cfg: ModelConfig, params: dict, batch: dict, gw_in,
                   if "extra_pos" in batch else 0.0),
                # token CE only (no router/z aux) — the drivers aggregate
                # this into a per-token nll comparable to token_nll_mean
-               "nll_sum": loss}
-    return (loss + aux, caps), metrics
+               "nll_sum": loss, "moe_held_rows": aux[1]}
+    return (loss + aux[0], caps), metrics
 
 
 # ---------------------------------------------------------------------------
@@ -528,7 +558,8 @@ def partition_loss(cfg: ModelConfig, params: dict, batch: dict, gw_in,
 
 def loss_and_metrics(cfg: ModelConfig, params: dict, batch: dict,
                      impl: str = "ref") -> tuple[jax.Array, dict]:
-    """Tree loss (Eq. 4): Σ_t λ_t · CE(logits[prev(t)], token_t) / #trees."""
+    """Tree loss (Eq. 4): (Σ_t λ_t · CE(logits[prev(t)], token_t) + the
+    router loss of the branches) / #trees."""
     hidden, aux = forward(cfg, params, batch, impl)
     prev = batch["prev_idx"]
     w = jnp.where(prev >= 0, batch["weight"], 0.0)
@@ -537,15 +568,16 @@ def loss_and_metrics(cfg: ModelConfig, params: dict, batch: dict,
     head = params.get("lm_head")
     with jax.named_scope("lm_head"):
         logits = logits_from_hidden(params["embed"], head, h_prev)
-        logits = shard_logits(logits)
+        logits = _vocab_logits(cfg, shard_logits(logits))
         lse = jax.nn.logsumexp(logits, axis=-1)
         lab = jnp.take_along_axis(logits, batch["tokens"][..., None].astype(
             jnp.int32), axis=-1)[..., 0]
         nll = lse - lab
     denom = jnp.asarray(batch.get("num_trees", 1), jnp.float32)
     loss = jnp.sum(w * nll) / denom
-    total = loss + aux
-    metrics = {"loss": loss, "aux_loss": aux,
+    total = loss + aux[0] / denom
+    metrics = {"loss": loss, "aux_loss": aux[0] / denom,
+               "moe_held_rows": aux[1],
                "weight_sum": jnp.sum(w),
                # un-normalized token CE (no MoE aux, no tree denominator):
                # the engine accumulates this on-device across microbatch

@@ -38,7 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import sharding as sh
 from repro.configs.base import ModelConfig
 from repro.core.gateway import (PartitionPlan, _cut_caps_view,
                                 _embed_cut_cot, _embed_gw_row_cot,
@@ -53,8 +55,9 @@ from repro.train.train_step import jitted_update
 
 logger = logging.getLogger(__name__)
 
-# the on-device scalar accumulator: [loss, nll_sum, weight_sum]
-NUM_SCALARS = 3
+# the on-device scalar accumulator: [loss, nll_sum, weight_sum,
+# moe_held_rows]
+NUM_SCALARS = 4
 
 
 # ---------------------------------------------------------------------------
@@ -129,15 +132,17 @@ def _packed_exec_fn(cfg: ModelConfig, impl: str, donate: bool,
     """Packed microbatch: fused fwd+bwd, grads accumulated into the
     donated fp32 buffer, scalars into the donated scalar vector.
 
-    ``with_acc=False`` is the single-execution fast path (no oversized
-    trees this step): the fp32 grads ARE the accumulator, so no separate
-    param-sized zero buffer is ever materialized (``0 + g ≡ g`` exactly,
-    bit-for-bit)."""
+    ``with_acc=False`` is what the engine runs: the fp32 grads ARE the
+    accumulator (the partition waves of the step add to it), so no
+    separate param-sized zero buffer is ever materialized (``0 + g ≡ g``
+    exactly, bit-for-bit).  ``with_acc=True`` is the form the static
+    audits (``repro.analysis``) check."""
     def scal_add(scal, loss, metrics):
         return scal + jnp.stack(
             [loss.astype(jnp.float32),
              metrics["nll_sum"].astype(jnp.float32),
-             metrics["weight_sum"].astype(jnp.float32)])
+             metrics["weight_sum"].astype(jnp.float32),
+             metrics["moe_held_rows"].astype(jnp.float32)])
 
     if with_acc:
         def f(params, batch, acc, scal):
@@ -175,7 +180,8 @@ def _wave_exec_fns(cfg: ModelConfig, names: tuple, impl: str,
         scal = scal + jnp.stack(
             [loss.astype(jnp.float32) * scale,
              metrics["nll_sum"].astype(jnp.float32),
-             metrics["weight_sum"].astype(jnp.float32)])
+             metrics["weight_sum"].astype(jnp.float32),
+             metrics["moe_held_rows"].astype(jnp.float32)])
         return caps, scal
 
     def bwd(params, batch, gw, capspecs, cot, acc):
@@ -301,6 +307,21 @@ def run_partition_plan(
     return acc, scal
 
 
+def _on_mesh(tree):
+    """Commit the arrays no computation has placed yet (fresh weights, a
+    fresh optimizer state) to the active mesh, replicated, as the
+    update's outputs are: the first step then runs the programs every
+    later step runs instead of compiling its own.  The buffers stay
+    where they are."""
+    mesh = sh.current_mesh()
+    if mesh is None:
+        return tree
+    rep = NamedSharding(mesh, PartitionSpec())
+    return jax.tree.map(
+        lambda a: a if not isinstance(a, jax.Array) or a.committed
+        else jax.device_put(a, rep), tree)
+
+
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
@@ -326,7 +347,8 @@ class TreeTrainEngine:
     out-of-universe miss logs a warning naming why the planner escaped
     the enumerable bucket set."""
 
-    METRIC_NAMES = ("loss", "nll_sum", "weight_sum", "grad_norm", "lr")
+    METRIC_NAMES = ("loss", "nll_sum", "weight_sum", "moe_held_rows",
+                     "grad_norm", "lr")
 
     def __init__(self, cfg: ModelConfig,
                  opt_cfg: Optional[OptimizerConfig] = None, *,
@@ -389,8 +411,10 @@ class TreeTrainEngine:
     def accumulate(self, params, plan: ExecutionPlan):
         """Run the plan's executions; returns ``(grads, scal)`` — the
         fp32 gradient sum (normalized per tree) and the on-device
-        ``[loss, nll_sum, weight_sum]`` vector.  Loss semantics match the
-        pre-engine two-branch loop: mean over the step's trees."""
+        ``[loss, nll_sum, weight_sum, moe_held_rows]`` vector.  Loss
+        semantics match the pre-engine two-branch loop: mean over the
+        step's trees."""
+        params = _on_mesh(params)
         scal = jnp.zeros((NUM_SCALARS,), jnp.float32)
         n = max(plan.num_trees, 1)
         has_waves = plan.partition is not None and plan.partition.waves
@@ -399,17 +423,14 @@ class TreeTrainEngine:
             batch["num_trees"] = n
             B, S = batch["tokens"].shape
             psig = packed_signature(B, S)
+            # the grads ARE the accumulator, which the partition waves
+            # (if any) add to — no param-sized zero buffer, and one
+            # packed program whether or not the step has waves
+            f = _packed_exec_fn(self.cfg, self.impl, self.donate,
+                                with_acc=False)
+            acc, scal = self._run("packed", psig, f, (params, batch, scal))
             if not has_waves:
-                # single-execution fast path: the grads ARE the
-                # accumulator — no param-sized zero buffer
-                f = _packed_exec_fn(self.cfg, self.impl, self.donate,
-                                    with_acc=False)
-                return self._run("packed", psig, f, (params, batch, scal))
-            acc = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
-                               params)
-            f = _packed_exec_fn(self.cfg, self.impl, self.donate)
-            acc, scal = self._run("packed+acc", psig, f,
-                                  (params, batch, acc, scal))
+                return acc, scal
         else:
             acc = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
                                params)
@@ -430,6 +451,7 @@ class TreeTrainEngine:
         assert self.opt_cfg is not None, \
             "TreeTrainEngine.step needs an OptimizerConfig"
         with TraceAnnotation("repro.engine.dispatch"):
+            params, opt_state = _on_mesh((params, opt_state))
             grads, scal = self.accumulate(params, plan)
             upd = jitted_update(self.opt_cfg, self.donate)
             params, opt_state, om = self._run("update", ("update",), upd,
@@ -460,6 +482,7 @@ class TreeTrainEngine:
         ``(params, opt_state)`` (donated inputs are consumed)."""
         assert self.opt_cfg is not None, \
             "TreeTrainEngine.warmup needs an OptimizerConfig"
+        params, opt_state = _on_mesh((params, opt_state))
         grads, _scal = self.accumulate(params, plan)
         upd = jitted_update(self.opt_cfg, self.donate)
         params, opt_state, _om = self._run("update", ("update",), upd,

@@ -363,7 +363,8 @@ def _permute_tb_rows(tb, order: Sequence[int]):
                      chunk_parent=sl(tb.chunk_parent),
                      num_trees=tb.num_trees,
                      extra_embeds=sl(tb.extra_embeds),
-                     row_trees=sl(tb.row_trees))
+                     row_trees=sl(tb.row_trees),
+                     aux_weight=sl(tb.aux_weight))
 
 
 # ---------------------------------------------------------------------------

@@ -50,11 +50,12 @@ from repro.analysis.signatures import SignatureUniverse
 from repro.configs.base import ModelConfig
 from repro.core.gateway import (_cut_caps_view, _names_sig, _slice_gw_row,
                                 _stack_gw_rows, assemble_child_gw)
-from repro.core.plan_cost import (CompileCacheSim, packed_signature, pow2,
-                                  round_to_multiple, wave_signature,
-                                  wave_signature_of)
+from repro.core.plan_cost import (CUT_FLOOR, CompileCacheSim,
+                                  packed_signature, pow2,
+                                  round_to_multiple, wave_path_floor,
+                                  wave_signature, wave_signature_of)
 from repro.data.loader import LoaderConfig
-from repro.models.model import max_conv_taps, needs_chunks
+from repro.models.model import has_router_loss, max_conv_taps, needs_chunks
 from repro.train.engine import (NUM_SCALARS, _packed_exec_fn,
                                 _wave_exec_fns)
 from repro.train.exec_cache import ExecutableCache, abstractify, exec_key
@@ -140,6 +141,8 @@ def abstract_packed_batch(cfg: ModelConfig, rows: int, seq_len: int
         "valid": _sds((rows, seq_len), jnp.bool_),
         "num_trees": 1,
     }
+    if has_router_loss(cfg):
+        b["aux_weight"] = _sds((rows, seq_len), f32)
     if needs_chunks(cfg):
         chunk = cfg.ssm.chunk_size
         k = max(1, max_conv_taps(cfg))
@@ -166,6 +169,8 @@ def _abstract_wave_batch(cfg: ModelConfig, rows: int, seq_len: int,
         "prev_idx": _sds((rows, seq_len), i32),
         "valid": _sds((rows, seq_len), jnp.bool_),
     }
+    if has_router_loss(cfg):
+        b["aux_weight"] = _sds((rows, seq_len), f32)
     if needs_chunks(cfg):
         chunk = cfg.ssm.chunk_size
         taps = max(1, max_conv_taps(cfg))
@@ -316,6 +321,8 @@ def universe_signatures(lc: LoaderConfig, pc, caps: Sequence[int]
     max_rows = pc.max_rows if pc.max_rows is not None else lc.batch_rows
     anc_cap, ncut_cap, plen_cap, extra_cap = caps
     plen_cap = min(plen_cap, pow2(lc.capacity or S))
+    anc_lo = max(8, wave_path_floor(S))
+    plen_lo = min(wave_path_floor(S), pow2(lc.capacity or S))
     sigs: list[Hashable] = [
         packed_signature(round_to_multiple(lc.batch_rows, R), S)]
 
@@ -327,16 +334,16 @@ def universe_signatures(lc: LoaderConfig, pc, caps: Sequence[int]
 
     for rows in pow2s(R, R * pow2(-(-max_rows // R))):
         # leaf waves: gateway in, no cuts → no capture paths, no extras
-        for anc in pow2s(8, anc_cap):
+        for anc in pow2s(anc_lo, anc_cap):
             sigs.append(wave_signature(rows, S, anc, 0, 0, 0))
-        for ncut in pow2s(1, ncut_cap):
-            for plen in pow2s(1, plen_cap):
-                for n_extra in pow2s(1, min(extra_cap, ncut)):
-                    # root waves (anc=0) always cut — a cut-less rootless
-                    # wave would be a row-sized tree, which packs instead
+        for ncut in pow2s(CUT_FLOOR, ncut_cap):
+            for plen in pow2s(plen_lo, plen_cap):
+                for n_extra in pow2s(CUT_FLOOR, min(extra_cap, ncut)):
+                    # root waves (anc=0) always cut: one-fragment trees
+                    # take inert cuts
                     sigs.append(wave_signature(rows, S, 0, ncut, plen,
                                                n_extra))
-                    for anc in pow2s(8, anc_cap):
+                    for anc in pow2s(anc_lo, anc_cap):
                         sigs.append(wave_signature(rows, S, anc, ncut,
                                                    plen, n_extra))
     return sigs
@@ -444,14 +451,10 @@ class AOTWarmupService:
         if sig[0] == "packed":
             _, rows, S = sig
             batch_a = abstract_packed_batch(self.cfg, rows, S)
-            out = [("packed",
-                    _packed_exec_fn(self.cfg, self.impl, self.donate,
-                                    with_acc=False),
-                    (self.params_a, batch_a, self.scal_a)),
-                   ("packed+acc",
-                    _packed_exec_fn(self.cfg, self.impl, self.donate),
-                    (self.params_a, batch_a, self.acc_a, self.scal_a))]
-            return out
+            return [("packed",
+                     _packed_exec_fn(self.cfg, self.impl, self.donate,
+                                     with_acc=False),
+                     (self.params_a, batch_a, self.scal_a))]
         io = abstract_wave_exec(self.cfg, sig, self.params_a,
                                 impl=self.impl, donate=self.donate)
         return [("wave.fwd", io["fwd"], io["fwd_args"]),
@@ -563,19 +566,10 @@ class AOTWarmupService:
                 B, S = plan.packed.inputs["tokens"].shape
                 sig = packed_signature(B, S)
                 sigs.append(sig)
-                has_waves = (plan.partition is not None
-                             and plan.partition.waves)
-                variants = ([("packed+acc",
-                              _packed_exec_fn(self.cfg, self.impl,
-                                              self.donate),
-                              (self.params_a, batch_a, self.acc_a,
-                               self.scal_a))]
-                            if has_waves else
-                            [("packed",
-                              _packed_exec_fn(self.cfg, self.impl,
-                                              self.donate,
-                                              with_acc=False),
-                              (self.params_a, batch_a, self.scal_a))])
+                variants = [("packed",
+                             _packed_exec_fn(self.cfg, self.impl,
+                                             self.donate, with_acc=False),
+                             (self.params_a, batch_a, self.scal_a))]
                 for variant, fn, args in variants:
                     try:
                         with self._mesh_scope():

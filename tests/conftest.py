@@ -26,8 +26,7 @@ def tiny_cfg(family: str, **kw) -> ModelConfig:
     elif family == "moe":
         base["attn"] = AttnCfg(n_heads=4, n_kv_heads=2, head_dim=8)
         base["moe"] = MoECfg(num_experts=4, top_k=2, d_expert=32,
-                             num_shared_experts=1, capacity_factor=4.0,
-                             first_dense_layers=1)
+                             num_shared_experts=1, first_dense_layers=1)
     elif family == "ssm_rwkv6":
         family = "ssm"
         base["ssm"] = SSMCfg(kind="rwkv6", head_dim=8, expand=1, chunk_size=8)

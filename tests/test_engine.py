@@ -147,6 +147,40 @@ def test_engine_one_host_sync_per_step():
     assert int(np.asarray(opt["step"])) == steps
 
 
+def test_engine_first_step_runs_the_later_steps_programs():
+    """Fresh weights and optimizer state are committed to the active mesh
+    as the update's outputs are: under a mesh, the packed program and the
+    update compile once over several steps, not again for step 2."""
+    import dataclasses
+
+    from jax.sharding import Mesh
+
+    from repro import sharding as sh
+    from repro.train.engine import _packed_exec_fn
+    cfg = dataclasses.replace(tiny_cfg("dense"), name="tiny-first-step")
+    lc = _lc(auto_partition=False)
+    # an optimizer configuration of its own: the update's jit is shared
+    # per configuration
+    opt_cfg = OptimizerConfig(lr=1.5e-3, warmup_steps=2, total_steps=4)
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    with sh.use_mesh(mesh):
+        params = init_params(cfg, jax.random.key(0))
+        opt = init_opt_state(params)
+        engine = TreeTrainEngine(cfg, opt_cfg, donate=False)
+        steps = 0
+        for ps in plans(cfg, lc, 4):
+            plan = ps.execution_plan()
+            if plan.packed is None or plan.partition is not None:
+                continue
+            params, opt, _ = engine.step(params, opt, plan)
+            steps += 1
+    assert steps >= 2
+    step_fn = _packed_exec_fn(cfg, "ref", False, with_acc=False)
+    assert step_fn._cache_size() == 1
+    assert jitted_update(opt_cfg, False)._cache_size() == 1
+
+
 def test_engine_rl_training_descends_on_grpo_trees():
     """The RL model-update workload end to end: grpo trees (non-uniform
     group-normalized advantages), loss_mode="rl", engine steps run and

@@ -43,6 +43,42 @@ def test_loss_equivalence(family):
     np.testing.assert_allclose(float(lt), float(ll), rtol=5e-6)
 
 
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_loss_ignores_vocab_padding(family):
+    """The head's rows past ``vocab_size`` (padding to a multiple) are no
+    tokens: packed and partitioned, the loss and its gradient are those
+    of the unpadded head, whatever the padded rows hold."""
+    from repro.core.gateway import partitioned_value_and_grad
+    tree = branching_tree(3, min_leaves=3)
+    tb = pack_trees([serialize_tree(tree)], 256)
+    out = {}
+    for pad in (1, 128):
+        cfg = tiny_cfg(family, vocab_pad_multiple=pad)
+        params = init_params(cfg, jax.random.key(0))
+        if pad == 1:
+            base = params
+        else:
+            # the unpadded weights in the first rows, noise past them
+            params["embed"]["table"] = params["embed"]["table"].at[
+                :cfg.vocab_size].set(base["embed"]["table"])
+            params["lm_head"]["w"] = params["lm_head"]["w"].at[
+                :, :cfg.vocab_size].set(base["lm_head"]["w"])
+            params["layer_stacks"] = base["layer_stacks"]
+            params["final_norm"] = base["final_norm"]
+        b = prepare_batch(cfg, tb)
+        loss, g = jax.value_and_grad(
+            lambda p: loss_and_metrics(cfg, p, b)[0])(params)
+        part, _, _ = partitioned_value_and_grad(cfg, params, tree, 64)
+        out[pad] = (float(loss), part,
+                    np.asarray(g["lm_head"]["w"][:, :cfg.vocab_size]),
+                    np.asarray(g["lm_head"]["w"][:, cfg.vocab_size:]))
+    assert out[1][0] == pytest.approx(out[128][0], rel=1e-6)
+    assert out[1][1] == pytest.approx(out[128][1], rel=1e-6)
+    np.testing.assert_allclose(out[128][2], out[1][2], rtol=1e-5,
+                               atol=1e-7)
+    assert not np.any(out[128][3])
+
+
 @pytest.mark.parametrize("family", ["dense", "ssm_mamba2", "ssm_rwkv6"])
 def test_grad_equivalence(family):
     """Eq. (5): ∂L_tree/∂θ = ∂L_sep_avg/∂θ (float32, App. B.8 tolerance)."""

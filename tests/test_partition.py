@@ -51,7 +51,6 @@ def test_partitioned_equals_whole_tree(family):
     cfg = tiny_cfg(family)
     if family == "moe":
         cfg = cfg.replace(moe=MoECfg(num_experts=4, top_k=2, d_expert=32,
-                                     capacity_factor=8.0,
                                      router_aux_weight=0.0,
                                      router_z_weight=0.0))
     chunk = cfg.ssm.chunk_size if needs_chunks(cfg) else None

@@ -15,6 +15,12 @@ itself.  The kernels run a data-dependent grid over the live-block
 table, so these compiles also show that the TPU compiler takes a dynamic
 grid bound.
 
+The same three kernels compile at Qwen3-30B-A3B's attention widths (H=32
+query heads over Kh=4 KV heads of hd=128: dk/dv reduces the group of 8
+inside the kernel), and the expert layer's grouped products
+(``expert_gmm`` forward and input gradient, ``expert_tgmm`` weight
+gradient) at its expert widths: D=2048, F=768, 16 held experts.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
 file.
@@ -168,3 +174,61 @@ def test_op_on_four_chip_mesh_compiles_for_v5e(topo, monkeypatch):
     with sh.use_mesh(mesh):
         _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)), args,
                        n_kernels=3)
+
+
+# Qwen3-30B-A3B: GQA 8:1 at hd 128; the expert layer's grouped products
+GQA = dict(H=32, Kh=4, hd=128)
+EXPERTS = dict(M=16384, D=2048, F=768, G=16)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_gqa_kernels_compile_for_v5e(one_chip, kernel):
+    H, Kh, hd = GQA["H"], GQA["Kh"], GQA["hd"]
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = [sds((B, S, H, hd), jnp.bfloat16),
+            sds((B, S, Kh, hd), jnp.bfloat16),
+            sds((B, S, Kh, hd), jnp.bfloat16),
+            sds((B, S), jnp.int32)]
+    if kernel == "fwd":
+        def f(q, k, v, kv_last):
+            tables = live_tables(kv_last, S, BLOCK, BLOCK)
+            return fwd_kernel(q, k, v, kv_last, hd ** -0.5, block_q=BLOCK,
+                              block_k=BLOCK, save_residuals=True,
+                              table=tables[0])
+    else:
+        body = _bwd_dq if kernel == "dq" else _bwd_dkv
+        args += [sds((B, H, 1, S), jnp.float32),
+                 sds((B, H, 1, S), jnp.float32),
+                 sds((B, S, H, hd), jnp.bfloat16)]
+
+        def f(q, k, v, kv_last, lse, delta, do):
+            tables = live_tables(kv_last, S, BLOCK, BLOCK)
+            tab = tables[0] if kernel == "dq" else tables[1]
+            qh, kh, vh, kl, pq, pk = head_major(q, k, v, kv_last, None,
+                                                None)
+            return body(qh, kh, vh, kl, lse, delta,
+                        do.transpose(0, 2, 1, 3), hd ** -0.5, tab, BLOCK,
+                        BLOCK, 0, None, pq, pk, False)
+    _assert_kernel(f, args)
+
+
+@pytest.mark.parametrize("kernel", ["expert_gmm", "expert_gmm_t",
+                                    "expert_tgmm"])
+def test_expert_products_compile_for_v5e(one_chip, kernel):
+    from repro.kernels.grouped_matmul import expert_gmm, expert_tgmm
+    M, D, F, G = (EXPERTS[k] for k in ("M", "D", "F", "G"))
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                             sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((G,), jnp.int32, sharding=one_chip)
+    if kernel == "expert_gmm":
+        fn, args = expert_gmm, [sds((M, D)), sds((G, D, F)), sizes]
+    elif kernel == "expert_gmm_t":
+        fn = lambda g, w, gs: expert_gmm(g, w, gs, transpose_rhs=True)
+        args = [sds((M, F)), sds((G, D, F)), sizes]
+    else:
+        fn = lambda x, g, gs: expert_tgmm(x, g, gs, num_groups=G)
+        args = [sds((M, D)), sds((M, F)), sizes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    name = "expert_tgmm" if kernel == "expert_tgmm" else "expert_gmm"
+    assert "tpu_custom_call" in text and name in text

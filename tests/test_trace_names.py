@@ -13,7 +13,7 @@ import pytest
 from conftest import tiny_cfg
 from repro.data.loader import LoaderConfig
 from repro.models.model import init_params
-from repro.train.engine import TreeTrainEngine, _packed_exec_fn
+from repro.train.engine import NUM_SCALARS, TreeTrainEngine, _packed_exec_fn
 from repro.train.optimizer import OptimizerConfig, init_opt_state
 from repro.train.planner import PlannerConfig, plans
 from repro.train.train_step import jitted_update
@@ -40,7 +40,8 @@ def step_op_names():
     params = init_params(cfg, jax.random.key(0))
     step = _packed_exec_fn(cfg, "pallas", False, with_acc=False)
     names = _op_names(step.lower(params, batch,
-                                 jnp.zeros((3,), jnp.float32)).compile())
+                                 jnp.zeros((NUM_SCALARS,), jnp.float32)
+                                 ).compile())
     grads = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     upd = jitted_update(OptimizerConfig()).lower(
         params, grads, init_opt_state(params)).compile()

@@ -75,6 +75,32 @@ def test_universe_signatures_match_enumeration():
     assert len(enum) <= universe.count(*caps)
 
 
+def test_wave_buckets_floor_at_a_sixteenth_of_the_row():
+    """Ancestor runs and capture paths shorter than a sixteenth of the row
+    share one bucket, so a stream's waves compile few programs; every
+    observed signature stays in the enumerated universe."""
+    from repro.core.plan_cost import wave_path_floor
+    lc = _lc(seq_len=256, capacity=128, trees_per_batch=3,
+             gen_kwargs=dict(turn_len_range=(20, 70), num_turns=3))
+    assert wave_path_floor(256) == 16 and wave_path_floor(2048) == 128
+    cfg = tiny_cfg("dense")
+    sigs = {s for ps in plans(cfg, lc, 6) for s in step_signatures(ps)
+            if s[0] == "wave"}
+    assert sigs, "no partitioned step in this stream; adjust seeds"
+    for _, _, _, anc, ncut, plen, _ in sigs:
+        assert anc == 0 or anc >= 16
+        assert plen == 0 or plen >= 16
+    assert any(anc for _, _, _, anc, *_ in sigs)
+    assert any(plen for *_, plen, _ in sigs)
+    universe = SignatureUniverse(
+        seq_len=lc.seq_len, batch_rows=lc.batch_rows, num_replicas=1,
+        max_rows=lc.batch_rows, capacity=lc.capacity)
+    caps = [max(s[i] for s in sigs) for i in (3, 4, 5, 6)]
+    enum = set(universe.enumerate_signatures(*caps))
+    assert enum == set(universe_signatures(lc, PlannerConfig(), caps))
+    assert {s for s in sigs if s[4]} <= enum     # root and cut waves
+
+
 def test_signature_list_priority():
     """Packed compiles first (every step needs it), then wave buckets in
     descending simulated-hit-frequency order — the hottest bucket is warm
@@ -83,8 +109,8 @@ def test_signature_list_priority():
     cfg = tiny_cfg("dense")
     params = init_params(cfg, jax.random.key(0))
     sim = CompileCacheSim()
-    hot = wave_signature(2, lc.seq_len, 8, 1, 8, 1)
-    cold = wave_signature(2, lc.seq_len, 8, 2, 8, 1)
+    hot = wave_signature(2, lc.seq_len, 8, 2, 8, 2)
+    cold = wave_signature(2, lc.seq_len, 8, 2, 16, 2)
     for _ in range(5):
         sim.commit([hot])
     sim.commit([cold])
