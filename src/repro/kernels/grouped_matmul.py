@@ -14,11 +14,17 @@ shared by two groups is visited once for each, its stores masked to the
 group's rows — so their work follows the rows routed to the held
 experts, not the worst-case buffer they are laid in (the grid's extent
 is a traced number, as in the tree-attention kernels).  Row tiles past
-the last group are never visited; ``gmm`` writes zeros there.  The tile
-plan follows the ``megablox`` kernels that ship with jax.
+the last group are never visited; ``expert_gmm`` writes zeros there
+unless told that nothing reads those rows (``zero_tail=False``: the
+expert layer, whose permutations read only the held rows,
+``kernels/moe_permute``).  With ``rows_out`` it writes its result one
+row to a layout tile (``moe_permute.rows_view``), the layout those
+permutations copy rows from.  The tile plan follows the ``megablox``
+kernels that ship with jax.
 
 ``gmm`` is the differentiable op (forward ``expert_gmm``; backward
-``expert_gmm`` against the transposed weights and ``expert_tgmm``).
+``gmm_grads``: ``expert_gmm`` against the transposed weights and
+``expert_tgmm``).
 """
 from __future__ import annotations
 
@@ -30,7 +36,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
+from repro.kernels.moe_permute import LANE, pad_rows, row_layout
 
 
 def _tile(dim: int, pref: int) -> int:
@@ -81,20 +87,23 @@ def _row_mask(meta, step, tm: int, width: int):
     return (rows >= offsets[g]) & (rows < offsets[g + 1])
 
 
-def _pad_rows(a, m: int):
-    return a if a.shape[0] == m else jnp.pad(a, ((0, m - a.shape[0]), (0, 0)))
-
-
-@partial(jax.jit, static_argnames=("transpose_rhs", "interpret"))
+@partial(jax.jit, static_argnames=("transpose_rhs", "interpret",
+                                   "zero_tail", "rows_out"))
 def expert_gmm(lhs, rhs, group_sizes, transpose_rhs: bool = False,
-               interpret: bool = False):
+               interpret: bool = False, zero_tail: bool = True,
+               rows_out: bool = False):
     """[M, K] rows by group @ rhs [G, K, N] (``transpose_rhs``: [G, N, K])
-    → [M, N] in ``lhs``'s type, zeros past the last group."""
+    → [M, N] in ``lhs``'s type, zeros past the last group (undefined
+    there without ``zero_tail``); ``rows_out``: [M, N/128, 128], one row
+    to a layout tile (``moe_permute.rows_view``)."""
     M, K = lhs.shape
     N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm = _row_tile(M)
     Mp = -(-M // tm) * tm
     tk, tn = _tile(K, 512), _tile(N, 512)
+    out_tile = row_layout(N)
+    if rows_out:
+        tn = N
     meta, steps = group_metadata(group_sizes, Mp, tm, visit_empty=False)
     tiles_k = K // tk
 
@@ -112,10 +121,12 @@ def expert_gmm(lhs, rhs, group_sizes, transpose_rhs: bool = False,
 
         @pl.when(k == tiles_k - 1)
         def _():
-            mask = _row_mask(meta, step, tm, tn)
-            out_ref[...] = jnp.where(mask, acc[...],
-                                     out_ref[...].astype(jnp.float32)
-                                     ).astype(out_ref.dtype)
+            # (the row layout is reached in the output's type: a float32
+            # relayout lowers to many times the instructions)
+            prev = out_ref[...].reshape(tm, tn)
+            out = jnp.where(_row_mask(meta, step, tm, tn),
+                            acc[...].astype(out_ref.dtype), prev)
+            out_ref[...] = out.reshape(out_ref.shape)
 
     def lhs_map(n, step, k, meta):
         return meta[2][step], k
@@ -125,27 +136,31 @@ def expert_gmm(lhs, rhs, group_sizes, transpose_rhs: bool = False,
         return (g, n, k) if transpose_rhs else (g, k, n)
 
     def out_map(n, step, k, meta):
-        return meta[2][step], n
+        return (meta[2][step], n, 0) if rows_out else (meta[2][step], n)
 
     rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
+    out_cols = out_tile if rows_out else (N,)
+    out_block = (tm, *out_tile) if rows_out else (tm, tn)
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((Mp, N), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, *out_cols), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             in_specs=[pl.BlockSpec((tm, tk), lhs_map),
                       pl.BlockSpec(rhs_block, rhs_map)],
-            out_specs=pl.BlockSpec((tm, tn), out_map),
+            out_specs=pl.BlockSpec(out_block, out_map),
             grid=(N // tn, steps, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="expert_gmm",
-    )(meta, _pad_rows(lhs, Mp), rhs)
-    rows = lax.broadcasted_iota(jnp.int32, (Mp, 1), 0)
-    out = jnp.where(rows < meta[0][-1], out, 0)
-    return out[:M]
+    )(meta, pad_rows(lhs, Mp), rhs)
+    if zero_tail:
+        rows = lax.broadcasted_iota(jnp.int32, (Mp,) + (1,) * len(out_cols),
+                                    0)
+        out = jnp.where(rows < meta[0][-1], out, 0)
+    return out[:M] if Mp != M else out
 
 
 @partial(jax.jit, static_argnames=("num_groups", "interpret"))
@@ -209,7 +224,7 @@ def expert_tgmm(lhs, rhs, group_sizes, num_groups: int,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="expert_tgmm",
-    )(meta, _pad_rows(lhs, Mp), _pad_rows(rhs, Mp))
+    )(meta, pad_rows(lhs, Mp), pad_rows(rhs, Mp))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -222,14 +237,23 @@ def _gmm_fwd(lhs, rhs, group_sizes, interpret):
     return gmm(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
 
 
-def _gmm_bwd(interpret, res, g):
-    lhs, rhs, group_sizes = res
+def gmm_grads(lhs, rhs, group_sizes, g, interpret: bool = False,
+              zero_tail: bool = True, rows_out: bool = False):
+    """The cotangents of ``expert_gmm(lhs, rhs, group_sizes)`` for its
+    output's cotangent ``g``: (d_lhs, d_rhs); d_lhs as ``expert_gmm``
+    writes it under ``zero_tail`` and ``rows_out``."""
     g = g.astype(lhs.dtype)
     d_lhs = expert_gmm(g, rhs, group_sizes, transpose_rhs=True,
-                       interpret=interpret)
+                       interpret=interpret, zero_tail=zero_tail,
+                       rows_out=rows_out)
     d_rhs = expert_tgmm(lhs, g, group_sizes, num_groups=rhs.shape[0],
                         interpret=interpret)
-    return d_lhs, d_rhs.astype(rhs.dtype), None
+    return d_lhs, d_rhs.astype(rhs.dtype)
+
+
+def _gmm_bwd(interpret, res, g):
+    lhs, rhs, group_sizes = res
+    return (*gmm_grads(lhs, rhs, group_sizes, g, interpret), None)
 
 
 gmm.defvjp(_gmm_fwd, _gmm_bwd)

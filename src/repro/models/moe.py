@@ -16,7 +16,11 @@ over it as grouped matrix products whose work follows the rows actually
 routed to them (``kernels/grouped_matmul`` on a TPU; ``lax.ragged_dot``
 elsewhere — the platform chooses, as it does for attention).  Results go
 back to token order and are summed, each weighted by its slot's routing
-probability.
+probability.  The held slots sort first, so the rows the experts compute
+are the buffer's prefix ``[0, live)``: on a TPU the permutations are
+Pallas kernels that move only those rows (``kernels/moe_permute``) and
+the products leave the rest of the buffer undefined; ``lax.ragged_dot``
+needs a dense buffer, and there XLA gathers every row.
 
 Router loss (``router_loss``): per-branch training computes, for each
 root-to-leaf branch b of T_b tokens, the load-balance term
@@ -35,14 +39,15 @@ sums travel in the gateway like attention's ancestor KV.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoECfg
-from repro.kernels.grouped_matmul import gmm
+from repro.kernels.grouped_matmul import expert_gmm, gmm_grads
+from repro.kernels.moe_permute import moe_combine, moe_gather
 from repro.models.layers import _dense_init
 
 
@@ -84,21 +89,15 @@ def _act(h_gate, h_up, activation: str):
 
 
 def platform_matmul() -> str:
-    """The grouped product this platform runs: the Pallas kernels on one
-    TPU; ``lax.ragged_dot`` elsewhere and on a mesh of several devices,
-    which XLA partitions (it cannot partition a Pallas call)."""
+    """The path this platform runs: the Pallas permutations and grouped
+    products on one TPU; ``lax.ragged_dot`` over the whole buffer
+    elsewhere and on a mesh of several devices, which XLA partitions (it
+    cannot partition a Pallas call)."""
     from repro import sharding as sh
     mesh = sh.current_mesh()
     if jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1):
         return "pallas"
     return "ragged"
-
-
-def _grouped(kind: str):
-    if kind == "ragged":
-        return jax.lax.ragged_dot
-    interpret = kind == "interpret"
-    return lambda x, w, gs: gmm(x, w, gs, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +143,76 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
 # ---------------------------------------------------------------------------
+# The Pallas path: the held rows only.  A permutation and the products next
+# to it are one op, so that the N·k-row buffers between them stay in the
+# layout the row copies need (``moe_permute.rows_view``) and no cotangent
+# of the whole buffer is relaid out.
+# ---------------------------------------------------------------------------
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_in(x, wi, order, inv, live, sizes, interpret: bool):
+    """x [N, D] → each ``xs @ wi[g]`` [N·k, F] over the held slots, xs
+    row r < live the token of slot ``order[r]``; rows past ``live``
+    undefined."""
+    return _held_in_fwd(x, wi, order, inv, live, sizes, interpret)[0]
+
+
+def _held_in_fwd(x, wi, order, inv, live, sizes, interpret):
+    with jax.named_scope("moe_route"):
+        xs = moe_gather(x, order // inv.shape[1], live, interpret=interpret)
+    with jax.named_scope("moe_experts"):
+        hs = tuple(expert_gmm(xs, w, sizes, interpret=interpret,
+                              zero_tail=False) for w in wi)
+    return hs, (xs, wi, inv, live, sizes)
+
+
+def _held_in_bwd(interpret, res, dhs):
+    xs, wi, inv, live, sizes = res
+    with jax.named_scope("moe_experts"):
+        grads = [gmm_grads(xs, w, sizes, dh, interpret, zero_tail=False,
+                           rows_out=True) for w, dh in zip(wi, dhs)]
+        dxs = reduce(jnp.add, (d for d, _ in grads))
+    with jax.named_scope("moe_route"):
+        dx = moe_combine(dxs, inv, live, interpret=interpret)
+    return dx, tuple(dw for _, dw in grads), None, None, None, None
+
+
+_held_in.defvjp(_held_in_fwd, _held_in_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _held_out(h, wo, w, order, inv, live, sizes, interpret: bool):
+    """h [N·k, F] → [N, D]: token t's ``Σ_j w[t, j] · (h @ wo)[inv[t, j]]``
+    over its held slots (``inv < live``)."""
+    return _held_out_fwd(h, wo, w, order, inv, live, sizes, interpret)[0]
+
+
+def _held_out_fwd(h, wo, w, order, inv, live, sizes, interpret):
+    with jax.named_scope("moe_experts"):
+        ys = expert_gmm(h, wo, sizes, interpret=interpret, zero_tail=False,
+                        rows_out=True)
+    with jax.named_scope("moe_route"):
+        y = moe_combine(ys, inv, live, w, interpret=interpret)
+    return y, (h, wo, ys, w, order, inv, live, sizes)
+
+
+def _held_out_bwd(interpret, res, dy):
+    h, wo, ys, w, order, inv, live, sizes = res
+    N, k = inv.shape
+    with jax.named_scope("moe_route"):
+        dys, dots = moe_gather(dy.astype(ys.dtype), order // k, live,
+                               scale=w.reshape(N * k)[order], dot=ys,
+                               interpret=interpret)
+        dw = jnp.where(inv < live, dots[inv], 0.0).astype(w.dtype)
+    with jax.named_scope("moe_experts"):
+        dh, dwo = gmm_grads(h, wo, sizes, dys, interpret, zero_tail=False)
+    return dh, dwo, dw, None, None, None, None
+
+
+_held_out.defvjp(_held_out_fwd, _held_out_bwd)
+
+
+# ---------------------------------------------------------------------------
 # The layer
 # ---------------------------------------------------------------------------
 
@@ -179,19 +248,32 @@ def moe(params: dict, cfg: MoECfg, x: jax.Array, valid: jax.Array,
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(N * K, dtype=jnp.int32), unique_indices=True)
         sizes = jax.nn.one_hot(gid, H, dtype=jnp.int32).sum(0)
-        xs = _dispatch(xf, order, inv, K)                       # [N·K, D]
+        live = jnp.sum(sizes)
 
-    with jax.named_scope("moe_experts"):
-        mm = _grouped(platform_matmul())
-        gate = mm(xs, params["wi_gate"], sizes) \
-            if "wi_gate" in params else None
-        h = _act(gate, mm(xs, params["wi_up"], sizes), activation)
-        ys = mm(h.astype(xs.dtype), params["wo"], sizes)        # [N·K, D]
-
-    with jax.named_scope("moe_route"):
-        w = jnp.where(held, top_p, 0.0).astype(x.dtype)
-        y = (_permute(ys, inv, order).reshape(N, K, D)
-             * w[..., None]).sum(axis=1)
+    kind = platform_matmul()
+    gated = "wi_gate" in params
+    if kind == "ragged":
+        mm = jax.lax.ragged_dot
+        with jax.named_scope("moe_route"):
+            xs = _dispatch(xf, order, inv, K)                   # [N·K, D]
+        with jax.named_scope("moe_experts"):
+            gate = mm(xs, params["wi_gate"], sizes) if gated else None
+            h = _act(gate, mm(xs, params["wi_up"], sizes), activation)
+            ys = mm(h.astype(xs.dtype), params["wo"], sizes)    # [N·K, D]
+        with jax.named_scope("moe_route"):
+            w = jnp.where(held, top_p, 0.0).astype(x.dtype)
+            y = (_permute(ys, inv, order).reshape(N, K, D)
+                 * w[..., None]).sum(axis=1)
+    else:
+        interpret = kind == "interpret"
+        wi = tuple(params[n] for n in ("wi_gate", "wi_up") if n in params)
+        slots = inv.reshape(N, K)           # each token's slots' rows
+        hs = _held_in(xf, wi, order, slots, live, sizes, interpret)
+        with jax.named_scope("moe_experts"):
+            h = _act(hs[0] if gated else None, hs[-1], activation)
+        y = _held_out(h.astype(x.dtype), params["wo"],
+                      jnp.where(held, top_p, 0.0), order, slots, live,
+                      sizes, interpret)
 
     if "shared_wi_up" in params:
         sg = xf @ params["shared_wi_gate"] \
@@ -204,7 +286,7 @@ def moe(params: dict, cfg: MoECfg, x: jax.Array, valid: jax.Array,
     feat = jnp.concatenate([probs, (lse * lse)[:, None]], -1) * vf
     route = {"cnt": jax.lax.stop_gradient(cnt).reshape(B, S, E),
              "feat": feat.reshape(B, S, E + 1),
-             "held_rows": jnp.sum(sizes)}
+             "held_rows": live}
     return y.reshape(B, S, D), route
 
 

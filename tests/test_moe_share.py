@@ -8,6 +8,9 @@
   (expert parallelism: each device holds a share, routes over all).
 - The Pallas grouped products (interpret mode) equal ``lax.ragged_dot``,
   forward and gradient, with empty groups and rows past the last group.
+- The layer's Pallas path (permutations over the held rows only, products
+  that leave the rest undefined) equals the plain path, and moves no
+  buffer row by an XLA gather.
 """
 import dataclasses
 
@@ -99,25 +102,98 @@ def test_shares_sum_to_the_layer():
     assert rows == cfg.top_k * int(valid.sum())
 
 
-def test_pallas_path_matches_plain_path(monkeypatch):
-    """The layer through the Pallas grouped products (interpret mode)
-    equals the CPU's ``lax.ragged_dot`` path, value and gradient."""
+def _few_reach(p, x):
+    """Expert 7 wins only the tokens whose inputs were flipped negative:
+    every other expert's logit is raised by the token's input sum."""
+    p = {**p, "router": p["router"].at[:, :7].add(1.0).at[:, 7].set(0.0)}
+    x = jnp.abs(x).at[0, :2].multiply(-1.0)
+    return p, x
+
+
+# name → (held experts, tokens (B, S), how the inputs are set up)
+PALLAS_CASES = {
+    "share": ((3, 7), (2, 24), None),
+    "every_slot": ((0, 8), (2, 24), None),
+    "few_reach": ((7, 8), (2, 24), _few_reach),
+    "wave": ((2, 6), (2, 320), None),
+    "decode": ((3, 7), (3, 1), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PALLAS_CASES))
+def test_pallas_path_matches_plain_path(monkeypatch, case):
+    """The layer through the Pallas permutations and grouped products
+    (interpret mode) equals the CPU's ``lax.ragged_dot`` path, value and
+    gradient, and computes the same held rows: every slot held, a held
+    range few tokens reach, a partition wave's rows, and decode's few
+    tokens (below one row tile)."""
+    held, (B, S), setup = PALLAS_CASES[case]
+    cfg = _cfg(held=held)
+    p = init_moe(jax.random.key(3), cfg, D, "swiglu")
+    x, valid = _inputs(2, B, S)
+    if setup is not None:
+        p, x = setup(p, x)
+
+    def loss(p, x):
+        y, route = moe(p, cfg, x, valid, "swiglu")
+        return jnp.sum(y * jnp.sin(y)), route["held_rows"]
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    (want, want_rows), want_g = grad(p, x)
+    assert moe_mod.platform_matmul() == "ragged"
+    monkeypatch.setattr(moe_mod, "platform_matmul", lambda: "interpret")
+    (got, got_rows), got_g = grad(p, x)
+    assert int(got_rows) == int(want_rows)
+    if case == "every_slot":
+        assert int(got_rows) == cfg.top_k * int(valid.sum())
+    if case == "few_reach":
+        assert 0 < int(got_rows) <= 2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(want_g), jax.tree.leaves(got_g)):
+        # the router's gradient sums every token's term: with many tokens
+        # float32 summation order reads against the leaf's scale
+        scale = 1.0 if case == "share" else max(1.0, float(jnp.abs(a).max()))
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-5, atol=1e-6 * scale)
+
+
+def _gathers(jaxpr, out=None):
+    """Every ``gather`` in a jaxpr and the jaxprs inside it, except those
+    inside a Pallas kernel."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "gather":
+            out.append(eqn)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _gathers(sub, out)
+    return out
+
+
+def test_pallas_path_gathers_no_buffer_rows(monkeypatch):
+    """On the Pallas path no XLA gather moves rows of the N·k-slot buffer,
+    in the forward or the backward; the plain path's do."""
     cfg = _cfg(held=(3, 7))
     p = init_moe(jax.random.key(3), cfg, D, "swiglu")
     x, valid = _inputs(2)
+    NK = x.shape[0] * x.shape[1] * cfg.top_k
 
-    def loss(p, x):
-        y, _ = moe(p, cfg, x, valid, "swiglu")
-        return jnp.sum(y * jnp.sin(y))
+    def buffer_gathers():
+        f = jax.value_and_grad(
+            lambda p, x: jnp.sum(moe(p, cfg, x, valid, "swiglu")[0]),
+            argnums=(0, 1))
+        return [e for e in _gathers(jax.make_jaxpr(f)(p, x).jaxpr)
+                if e.outvars[0].aval.ndim > 1
+                and e.outvars[0].aval.shape[0] == NK]
 
-    want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
-    assert moe_mod.platform_matmul() == "ragged"
+    assert buffer_gathers(), "the plain path's row gathers are not seen"
     monkeypatch.setattr(moe_mod, "platform_matmul", lambda: "interpret")
-    got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
-    for want, got in zip(want, got):
-        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
-            np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                       rtol=1e-5, atol=1e-6)
+    assert not buffer_gathers()
 
 
 @pytest.mark.parametrize("sizes", [[3, 0, 10, 5], [0, 0, 0, 0], [40, 0, 0, 1],

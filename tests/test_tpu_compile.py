@@ -19,7 +19,12 @@ The same three kernels compile at Qwen3-30B-A3B's attention widths (H=32
 query heads over Kh=4 KV heads of hd=128: dk/dv reduces the group of 8
 inside the kernel), and the expert layer's grouped products
 (``expert_gmm`` forward and input gradient, ``expert_tgmm`` weight
-gradient) at its expert widths: D=2048, F=768, 16 held experts.
+gradient) at its expert widths: D=2048, F=768, 16 held experts, N·k =
+131072 slots; so do its row permutations (``moe_gather`` plain and with
+a scale and per-row dots, ``moe_combine`` weighted and not) and the
+products that write the rows they copy from (``rows_out``).  The
+permutations copy one row per DMA, which the TPU compiler takes only
+from an array laid out one row to a tile (``moe_permute.rows_view``).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
@@ -231,4 +236,44 @@ def test_expert_products_compile_for_v5e(one_chip, kernel):
         args = [sds((M, D)), sds((M, F)), sizes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     name = "expert_tgmm" if kernel == "expert_tgmm" else "expert_gmm"
+    assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("kernel", ["moe_gather", "moe_gather_scaled",
+                                    "moe_combine", "moe_combine_weighted",
+                                    "expert_gmm_rows", "expert_gmm_t_rows"])
+def test_moe_permutations_compile_for_v5e(one_chip, kernel):
+    from repro.kernels.grouped_matmul import expert_gmm
+    from repro.kernels.moe_permute import moe_combine, moe_gather
+    D, F, G = (EXPERTS[k] for k in ("D", "F", "G"))
+    N, K = 8 * 2048, 8                             # tokens a step, slots
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    i32, f32 = jnp.int32, jnp.float32
+    rows = sds((N * K, D // 128, 128))             # one row to a tile
+    live = sds((), i32)
+    if kernel == "moe_gather":
+        fn = lambda x, tok, live: moe_gather(x, tok, live)
+        args = [sds((N, D)), sds((N * K,), i32), live]
+    elif kernel == "moe_gather_scaled":
+        fn = lambda dy, tok, live, s, ys: moe_gather(dy, tok, live,
+                                                     scale=s, dot=ys)
+        args = [sds((N, D)), sds((N * K,), i32), live, sds((N * K,), f32),
+                rows]
+    elif kernel == "moe_combine":
+        fn = lambda g, inv, live: moe_combine(g, inv, live)
+        args = [rows, sds((N, K), i32), live]
+    elif kernel == "moe_combine_weighted":
+        fn = lambda ys, inv, live, w: moe_combine(ys, inv, live, w)
+        args = [rows, sds((N, K), i32), live, sds((N, K), f32)]
+    elif kernel == "expert_gmm_rows":
+        fn = lambda h, w, gs: expert_gmm(h, w, gs, zero_tail=False,
+                                         rows_out=True)
+        args = [sds((N * K, F)), sds((G, F, D)), sds((G,), i32)]
+    else:
+        fn = lambda g, w, gs: expert_gmm(g, w, gs, transpose_rhs=True,
+                                         zero_tail=False, rows_out=True)
+        args = [sds((N * K, F)), sds((G, D, F)), sds((G,), i32)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    name = "_".join(kernel.split("_")[:2])
     assert "tpu_custom_call" in text and name in text

@@ -1,7 +1,9 @@
 """The names the profiler reads: the tree-attention kernels, the LM head and
 AdamW carry ``jax.named_scope`` names into the compiled step's op metadata,
-and the engine and planner write ``repro.*`` host spans into the profiler's
-own trace (CPU; the Pallas kernels run interpreted)."""
+as do the MoE layer's row permutations (``moe_gather`` / ``moe_combine``
+under ``moe_route``), and the engine and planner write ``repro.*`` host
+spans into the profiler's own trace (CPU; the Pallas kernels run
+interpreted)."""
 import glob
 import os
 import re
@@ -12,6 +14,7 @@ import pytest
 
 from conftest import tiny_cfg
 from repro.data.loader import LoaderConfig
+from repro.models import moe as moe_mod
 from repro.models.model import init_params
 from repro.train.engine import NUM_SCALARS, TreeTrainEngine, _packed_exec_fn
 from repro.train.optimizer import OptimizerConfig, init_opt_state
@@ -64,6 +67,36 @@ def test_remat_recomputation_carries_forward_scope(step_op_names):
     remat = [n for n in fwd if "rematted_computation" in n]
     assert remat, "the forward that remat recomputes has no name"
     assert len(remat) < len(fwd), "the first forward has no name"
+
+
+@pytest.fixture(scope="module")
+def moe_step_op_names():
+    """Op names of the compiled packed step of a MoE model (full remat) on
+    the layer's Pallas path."""
+    cfg = tiny_cfg("moe", remat="full")
+    ps = next(iter(plans(cfg, _lc(), 2, PlannerConfig(plan_workers=0))))
+    batch = dict(ps.execution_plan().packed.inputs)
+    batch["num_trees"] = 2
+    params = init_params(cfg, jax.random.key(0))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe_mod, "platform_matmul", lambda: "interpret")
+        step = _packed_exec_fn(cfg, "pallas", False, with_acc=False)
+        return _op_names(step.lower(params, batch,
+                                    jnp.zeros((NUM_SCALARS,), jnp.float32)
+                                    ).compile())
+
+
+@pytest.mark.parametrize("kernel", ["moe_gather", "moe_combine"])
+def test_moe_step_carries_permutation_names(moe_step_op_names, kernel):
+    """Forward and backward; the gather in the remat recomputation too
+    (the recomputed forward needs no combine: the layer's output is not
+    a residual of its own backward)."""
+    named = [n for n in _in_scope(moe_step_op_names, kernel)
+             if _in_scope([n], "moe_route")]
+    assert [n for n in named if "transpose(" not in n], "forward"
+    assert [n for n in named if "transpose(" in n], "backward"
+    if kernel == "moe_gather":
+        assert [n for n in named if "rematted_computation" in n], "remat"
 
 
 @pytest.fixture(scope="module", params=[1, 0], ids=["worker", "inline"])
